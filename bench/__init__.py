@@ -10,9 +10,6 @@ bench/main.py for the module map.
 from bench.common import (  # noqa: F401 — the package's public face
     NORTH_STAR_CHIPS,
     NORTH_STAR_MS,
-    TPU_RECORD_PATH,
-    attach_tpu_record,
     build_index,
     log,
-    probe_backend,
 )

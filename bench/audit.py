@@ -10,7 +10,7 @@ import json
 import os
 import time
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 INDEX = "aud"
 READ_PQL = [
@@ -257,7 +257,6 @@ def audit_smoke() -> int:
     (<= PILOSA_TPU_AUDIT_TAP_MAX_US, default 8us).  The QPS overhead
     A/B is recorded in the BENCH JSON and never asserted on a 2-core
     box."""
-    apply_platform()
     probe = audit_cost_probe()
     out = audit_gauntlet(
         n_clients=int(os.environ.get("PILOSA_TPU_AUDIT_CLIENTS",

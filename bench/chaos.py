@@ -8,7 +8,7 @@ import json
 import os
 import time
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 
 CHAOS_QUERIES = [
@@ -345,7 +345,6 @@ def chaos_smoke() -> int:
       victim was down (block repair > 0, write visible through the
       rejoined node).
     """
-    apply_platform()
     out = chaos_gauntlet(
         n_clients=int(os.environ.get("PILOSA_TPU_CHAOS_CLIENTS", "8")),
         duration_s=float(os.environ.get(

@@ -22,7 +22,7 @@ import threading
 import time
 import urllib.request
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 N_SHARDS = 24  # >=24 so jump-hash actually splits "t" across workers
 
@@ -377,7 +377,6 @@ def dax_smoke() -> int:
     incident bundle over HTTP.  Correctness-only gates (2-core-box
     rule): warmup walls, QPS, and latency are recorded, never
     asserted."""
-    apply_platform()
     out = dax_gauntlet(
         n_clients=int(os.environ.get("PILOSA_TPU_DAX_CLIENTS", "6")),
         burn_s=float(os.environ.get("PILOSA_TPU_DAX_BURN_S", "1.0")),

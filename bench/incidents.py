@@ -26,7 +26,7 @@ import os
 import threading
 import time
 
-from bench.common import apply_platform, build_index, log
+from bench.common import build_index, log
 
 
 def stamp_cost_probe(n: int = 20000, threads: int = 4) -> dict:
@@ -150,7 +150,6 @@ def incident_smoke() -> int:
     """check.sh gate (bench.py --incident-smoke)."""
     import tempfile
 
-    apply_platform()
     probe = stamp_cost_probe()
     with tempfile.TemporaryDirectory() as d:
         drill = incident_stall_drill(d)

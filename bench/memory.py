@@ -7,7 +7,7 @@ import json
 import os
 import time
 
-from bench.common import _MEM_QUERIES, apply_platform, build_index, log
+from bench.common import _MEM_QUERIES, build_index, log
 
 
 def memory_pressure_gauntlet(h, ratios=(0.5, 1.0, 2.0),
@@ -130,7 +130,6 @@ def memory_smoke() -> int:
     """
     import gc
 
-    apply_platform()
     from pilosa_tpu import memory
     from pilosa_tpu.executor.executor import Executor
     from pilosa_tpu.memory import pressure

@@ -25,7 +25,7 @@ import os
 import threading
 import time
 
-from bench.common import _pct, apply_platform, build_index, log
+from bench.common import _pct, build_index, log
 
 
 def build_events_index(h, n_shards: int = 3, seed: int = 11):
@@ -357,7 +357,6 @@ def ragged_smoke() -> int:
     asserted — scheduler noise on a shared 2-core box swamps them
     (the committed BENCH_r08 gauntlet run asserts the ratios).
     """
-    apply_platform()
     from pilosa_tpu.obs import metrics
 
     r0 = metrics.SERVING_DISPATCH.value(kind="ragged")

@@ -15,7 +15,7 @@ import os
 import threading
 import time
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 REB_QUERIES = [
     "Count(Row(f=1))",
@@ -381,7 +381,6 @@ def rebalance_smoke() -> int:
     on the recipient, no owner-invariant violation, and a clean
     drain.  Correctness-only gates (2-core-box rule): the p99 spike
     is recorded in the JSON, never asserted here."""
-    apply_platform()
     out = rebalance_gauntlet(
         n_clients=int(os.environ.get(
             "PILOSA_TPU_REBALANCE_CLIENTS", "8")),

@@ -11,7 +11,6 @@ import time
 from bench.common import (
     SERVING_QUERIES,
     _client_storm,
-    apply_platform,
     build_index,
     log,
 )
@@ -403,7 +402,6 @@ def overhead_smoke() -> int:
       peak probe or lock convoy on the dispatch path shows as
       1000x)
     """
-    apply_platform()
     h, _ = build_index(2, 4)
     out = tracing_overhead_gauntlet(h, n_clients=4, duration_s=0.6,
                                     rounds=3)

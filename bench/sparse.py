@@ -20,7 +20,7 @@ import os
 import statistics
 import time
 
-from bench.common import apply_platform, log
+from bench.common import log
 
 
 def build_sparse_index(n_shards: int, n_rows: int, width: int = None,
@@ -160,7 +160,6 @@ def sparse_smoke() -> int:
     """
     import gc
 
-    apply_platform()
     import numpy as np
 
     from pilosa_tpu.executor.executor import Executor

@@ -20,7 +20,7 @@ import os
 import threading
 import time
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 
 def _http(port, method, path, body=None, headers=None, timeout=30):
@@ -92,7 +92,6 @@ def sql_gauntlet(n_clients: int = 32, duration_s: float = 1.2,
     ``/sql``, pushdown-on vs host A/B on the same server, bit-exact
     hard-gated against a precomputed host answer key, with per-arm
     roofline windows and the /debug/queries fused-route evidence."""
-    apply_platform()
     from pilosa_tpu.models.holder import Holder
     from pilosa_tpu.obs import flight, roofline
     from pilosa_tpu.server.http import Server
@@ -246,7 +245,6 @@ def sql_smoke() -> int:
 
     QPS/latency ratios are recorded in the JSON, never asserted here
     (the committed gauntlet run carries the >=5x acceptance)."""
-    apply_platform()
     out = sql_gauntlet(
         n_clients=int(os.environ.get("PILOSA_TPU_SQL_CLIENTS", "8")),
         duration_s=float(os.environ.get("PILOSA_TPU_SQL_DURATION_S",

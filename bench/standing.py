@@ -7,7 +7,7 @@ import json
 import os
 import time
 
-from bench.common import _pct, apply_platform, log
+from bench.common import _pct, log
 
 INDEX = "sq"
 POLL_PQL = [
@@ -354,7 +354,6 @@ def standing_smoke() -> int:
     maintain <= PILOSA_TPU_STANDING_NOOP_MAX_US, default 200us);
     the poll latency ratio is reported but never gated on a small
     box."""
-    apply_platform()
     probe = standing_cost_probe()
     out = standing_gauntlet(
         n_pollers=int(os.environ.get(

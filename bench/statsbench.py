@@ -11,7 +11,7 @@ import os
 import tempfile
 import time
 
-from bench.common import apply_platform, log
+from bench.common import log
 
 
 def stats_cost_probe(n: int = 20000, threads: int = 4) -> dict:
@@ -200,7 +200,6 @@ def stats_smoke() -> int:
     - the admission A/B arms are bit-exact and the stats arm's
       misclassification rate does not exceed the static arm's
     """
-    apply_platform()
     from pilosa_tpu.obs import stats
 
     probe = stats_cost_probe()

@@ -8,7 +8,7 @@ import json
 import os
 import time
 
-from bench.common import _index_state, _pct, apply_platform, log
+from bench.common import _index_state, _pct, log
 
 
 def write_storm_gauntlet(n_readers: int = 32, n_writers: int = 4,
@@ -450,7 +450,6 @@ def write_smoke() -> int:
     failures); the read-latency ratio is reported but never gated on
     a small box (scheduler noise swamps it).
     """
-    apply_platform()
     out = write_storm_gauntlet(
         n_readers=int(os.environ.get("PILOSA_TPU_WRITE_READERS", "8")),
         n_writers=int(os.environ.get("PILOSA_TPU_WRITE_WRITERS", "2")),

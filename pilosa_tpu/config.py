@@ -76,11 +76,12 @@ class Config:
     stack_sparse_dense_frac: float = 0.5
     # HBM residency manager (pilosa_tpu/memory): one process-wide
     # device-byte budget shared by the tile-stack/jit/result caches.
-    # budget-bytes 0 = auto (device memory_stats minus headroom-frac,
-    # 8 GiB fallback on backends without stats).  paged turns stack
-    # cache entries into fixed page-bytes device pages (sub-stack
-    # eviction + patching); prefetch warms predicted pages from the
-    # flight recorder off the hot path; oom-retry / host-fallback are
+    # budget-bytes 0 = auto (device memory_stats minus headroom-frac;
+    # a CPU backend without stats gets 8 GiB, a TPU without them is
+    # an error).  paged turns stack cache entries into fixed
+    # page-bytes device pages (sub-stack eviction + patching);
+    # prefetch warms predicted pages from the flight recorder off
+    # the hot path; oom-retry / host-fallback are
     # the RESOURCE_EXHAUSTED backstop rungs.
     memory_budget_bytes: int = 0
     memory_headroom_frac: float = 0.1

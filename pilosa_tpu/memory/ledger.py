@@ -14,10 +14,10 @@ The budget resolves lazily on first pressure, in precedence order:
 explicit ``configure(budget_bytes=...)`` > the
 ``PILOSA_TPU_MEMORY_BUDGET_BYTES`` env var > the real device memory
 (``jax.local_devices()[0].memory_stats()``) minus a headroom fraction
-> an 8 GiB fallback (matching the pre-ledger ``TileStackCache``
-bound).  Lazy because eagerly touching ``jax.local_devices()`` at
-construction would initialize the backend from every Executor ctor —
-including ones that never touch a device.
+> on a backend that reports no limit, an 8 GiB constant for the CPU
+and an error for a TPU.  Lazy because eagerly touching
+``jax.local_devices()`` at construction would initialize the backend
+from every Executor ctor — including ones that never touch a device.
 
 Clients are held by WEAK reference: a garbage-collected cache (tests
 construct thousands of Executors) drops out of the accounting with its
@@ -168,17 +168,20 @@ class Ledger:
                     return n
             except ValueError:
                 pass
-        try:
-            import jax
-            stats = jax.local_devices()[0].memory_stats() or {}
-            limit = (stats.get("bytes_limit")
-                     or stats.get("bytes_reservable_limit"))
-            if limit:
-                return max(int(int(limit)
-                               * (1.0 - self.headroom_frac)), 1 << 20)
-        except Exception:
-            pass  # CPU backends report no stats — config fallback
-        return _FALLBACK_BUDGET
+        import jax
+        dev = jax.local_devices()[0]
+        stats = dev.memory_stats() or {}
+        limit = (stats.get("bytes_limit")
+                 or stats.get("bytes_reservable_limit"))
+        if limit:
+            return max(int(int(limit) * (1.0 - self.headroom_frac)),
+                       1 << 20)
+        if dev.platform == "tpu":
+            # a chip whose size is unknown is not budgeted by guess
+            raise RuntimeError(
+                f"{dev} reports no memory limit (memory_stats() = "
+                f"{stats!r}); set [memory] budget-bytes")
+        return _FALLBACK_BUDGET  # CPU backends report no stats
 
     # -- devices --------------------------------------------------------
 

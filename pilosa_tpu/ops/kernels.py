@@ -21,23 +21,20 @@ intermediate materialization.  Everything is wrapped so the jnp path
 (`ops.bitmap`/`ops.bsi`) stays the reference implementation; tests
 cross-check the two.
 
-Measured guidance (v5e-1, 954 shards x 2^20 cols): standalone these
-kernels match XLA within noise (~760 GB/s scan, ~93% of HBM peak —
-the op is bandwidth-bound, there is nothing left to schedule).  BUT a
-pallas_call is a fusion barrier: when the operand is produced by an
-upstream elementwise op (e.g. the bench's per-iteration perturbation),
-XLA fuses producer+scan into one pass while the kernel forces the
-intermediate through HBM (measured 6x slower).  Hence the dispatch
-rule in enabled(): kernels serve executor paths whose inputs are
-device-RESIDENT tiles (no producer to fuse); whole-pipeline jnp
+Speed against the XLA forms: not measured on today's code.  What
+holds by construction: the ops are bandwidth-bound streams, and a
+pallas_call is a fusion barrier — when the operand is produced by an
+upstream elementwise op XLA fuses producer and scan into one pass,
+while the kernel forces the intermediate through HBM.  Hence the
+dispatch rule in enabled(): kernels serve executor paths whose inputs
+are device-RESIDENT tiles (no producer to fuse); whole-pipeline jnp
 expressions stay with XLA.
 
-The exception is :func:`groupby_sum`, where the kernel is the DEFAULT
-on TPU: the XLA GroupBy scan must materialize gathered (C, S, W)
-combo masks and re-read them once per BSI plane, while the kernel's
-scalar-prefetch gather + plane-block reuse reads each operand stream
-approximately once (measured 4x faster at design scale, r03 — the
-schedule, not the arithmetic, is what XLA cannot reproduce).
+The exception is the GroupBy family, where a kernel is the DEFAULT on
+TPU: the XLA GroupBy scan must materialize gathered (C, S, W) combo
+masks and re-read them once per BSI plane, while :func:`groupby_sum`'s
+scalar-prefetch gather + plane-block reuse and the one-pass
+:func:`groupby_fused` read each operand stream about once.
 
 All kernels run in interpreter mode automatically off-TPU, so the same
 code path is exercised by the CPU test mesh (conftest.py).
@@ -65,17 +62,11 @@ def _interpret() -> bool:
 def enabled() -> bool:
     """Whether the executor should route hot ops through these kernels.
 
-    Default OFF: measured head-to-head on a real v5e chip at design
-    scale (954 shards, r03 A/B through the full engine), the XLA jnp
-    path matched or beat the Pallas route on every stacked plan shape
-    — the ops are pure HBM-bandwidth streams XLA already schedules
-    optimally, and the pallas_call boundary only adds dispatch
-    overhead (count_intersect net p50: 2.35 ms XLA vs 3.45 ms Pallas;
-    table in BENCH_TPU_NOTES.md).  The kernels stay as a measured,
-    env-selectable alternative: PILOSA_TPU_PALLAS=1 routes resident-
-    leaf plans through them (and exercises the interpret path in CPU
-    tests); off-TPU the interpreter would be far slower than XLA, so
-    callers fall back regardless unless forced.
+    Default OFF (an A/B against the XLA path is not measured on
+    today's code; ROADMAP C5): PILOSA_TPU_PALLAS=1 routes resident-
+    leaf plans through the kernels (and exercises the interpret path
+    in CPU tests); off-TPU the interpreter would be far slower than
+    XLA, so callers fall back regardless unless forced.
     """
     import os
     return os.environ.get("PILOSA_TPU_PALLAS") == "1"
@@ -423,8 +414,7 @@ def groupby_sum(stacks, sel, planes=None, signed=True):
     pattern) — the plane block loads once per (shard, word) tile and
     is reused by all C combos, so total HBM traffic is ~one read of
     each stack row per referencing combo plus ONE read of the planes,
-    instead of the XLA path's per-chunk re-materialization (measured
-    r03: 273 ms -> see BENCH_TPU_NOTES for the kernel number).
+    instead of the XLA path's per-chunk re-materialization.
     Per-combo totals accumulate across shard tiles in int32 (exact
     below ~2k shards; callers above that use the unreduced XLA path).
     """
@@ -537,7 +527,7 @@ def groupby_codes_xla(code_planes, valid, planes=None, n_codes: int = 1,
     every input word is read exactly once, independent of combo count.
     With ``minmax=True`` (requires planes) additionally returns the
     (4, G) [max_mag_pos, min_mag_pos, max_mag_neg, min_mag_neg] table
-    via scatter-max/min — the oracle for groupby_fused's presence-walk
+    via scatter-max/min — the oracle for groupby_fused's
     Min/Max (identities -1 / 1<<depth; see minmax_from_table).
     """
     depth = 0 if planes is None else planes.shape[1] - 2
@@ -608,13 +598,23 @@ def groupby_codes_xla(code_planes, valid, planes=None, n_codes: int = 1,
     return counts, nn, pos, neg, mm
 
 
+def _valid_operand(valid, bw: int):
+    """The (S, W) validity mask as a one-pass kernel operand, one
+    shard and `bw` words per grid step: (S, 1, W) with a (1, 1, bw)
+    block.  Mosaic wants a block's last two dims to be multiples of
+    (8, 128) or the whole array dims, which a (1, bw) block of (S, W)
+    is not."""
+    return (_pad_axis(valid, 1, bw)[:, None, :],
+            pl.BlockSpec((1, 1, bw), lambda s, w: (s, 0, w)))
+
+
 def _gc_onehot_kernel(cb: int, depth: int, signed: bool, k: int,
                       g_pad: int):
     """Kernel body factory for groupby_onehot: per (shard, word-block)
     grid step, decode the 32 bit positions of the block and accumulate
     payload.T @ one-hot MXU matmuls into the VMEM-resident (K, G)
-    table.  Per-step partial sums are <= 32 * BW < 2^24 so the f32
-    MXU accumulator is exact; cross-step accumulation is int32."""
+    table.  Each dot's partial sums are <= BW < 2^24 so the f32 MXU
+    accumulator is exact; accumulation across dots is int32."""
 
     def kernel(cp_ref, va_ref, *refs):
         pl_ref = refs[0] if depth else None
@@ -626,10 +626,12 @@ def _gc_onehot_kernel(cb: int, depth: int, signed: bool, k: int,
             out_ref[...] = jnp.zeros_like(out_ref)
 
         iota_g = jax.lax.broadcasted_iota(jnp.int32, (1, g_pad), 1)
-        acc = jnp.zeros_like(out_ref)
-        for j in range(32):
-            sh = jnp.uint32(j)
-            va = ((va_ref[0, :] >> sh) & 1).astype(jnp.int32)
+
+        def bit(j, carry):
+            # rolled, so Mosaic sizes its VMEM stack for one bit
+            # position's temporaries, not for all 32
+            sh = j.astype(jnp.uint32)
+            va = ((va_ref[0, 0, :] >> sh) & 1).astype(jnp.int32)
             code = jnp.zeros_like(va)
             for b in range(cb):
                 code = code | (
@@ -646,10 +648,12 @@ def _gc_onehot_kernel(cb: int, depth: int, signed: bool, k: int,
             # invalid columns carry all-zero payload (every row has a
             # `va` factor), so their arbitrary code contributes nothing
             onehot = (code[:, None] == iota_g).astype(jnp.float32)
-            acc += jnp.dot(payload, onehot,
-                           preferred_element_type=jnp.float32
-                           ).astype(jnp.int32)
-        out_ref[...] += acc
+            out_ref[...] += jnp.dot(payload, onehot,
+                                    preferred_element_type=jnp.float32
+                                    ).astype(jnp.int32)
+            return carry
+
+        jax.lax.fori_loop(0, 32, bit, 0)
     return kernel
 
 
@@ -680,12 +684,10 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
     # word block sized so the per-step (BW, G) one-hot stays ~2 MB f32
     bw = min(w_dim, max(128, (1 << 19) // g_pad))
     code_planes = _pad_axis(code_planes, 2, bw)
-    valid = _pad_axis(valid, 1, bw)
+    valid, valid_spec = _valid_operand(valid, bw)
     arrays = [code_planes, valid]
-    in_specs = [
-        pl.BlockSpec((1, cb, bw), lambda s, w: (s, 0, w)),
-        pl.BlockSpec((1, bw), lambda s, w: (s, w)),
-    ]
+    in_specs = [pl.BlockSpec((1, cb, bw), lambda s, w: (s, 0, w)),
+                valid_spec]
     if depth:
         planes = _pad_axis(planes, 2, bw)
         arrays.append(planes)
@@ -715,7 +717,7 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
 # ---------------------------------------------------------------------------
 #
 # Second-generation one-pass kernel (ISSUE 11).  groupby_onehot above
-# unrolls the 32 bit positions of each word block and pays one f32
+# walks the 32 bit positions of each word block and pays one f32
 # (K, BW) @ (BW, G) matmul PER BIT — 32 MXU launches per tile, with
 # f32 one-hot operands 4x the bytes they need.  groupby_fused flattens
 # bit-position chunks into the contraction axis and accumulates the
@@ -729,11 +731,9 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
 #   - the group-code histogram (counts),
 #   - validity counts (nn) and per-group BSI Sum sign-split plane
 #     partials (pos/neg) — identical layout to groupby_codes_xla,
-#   - optionally per-group Min/Max, via per-group plane-PRESENCE
-#     masks: an MSB->LSB candidate walk where "does any candidate in
-#     group g have magnitude bit p" is one int8 mat-vec against the
-#     same one-hot, and the per-column candidate narrowing gathers the
-#     presence bit back through the transposed one-hot,
+#   - optionally per-group Min/Max: each column's magnitude is
+#     rebuilt from its plane bits and reduced per group with one
+#     masked max/min over the same one-hot, on the VPU,
 #   - and (as a byproduct of the same tile walk) fused Range/Distinct
 #     over BSI planes: bsi_value_hist() below runs THIS kernel with
 #     the magnitude+sign planes as the code planes, so the dense
@@ -750,7 +750,7 @@ def _gb_fused_kernel(cb: int, depth: int, signed: bool, k: int,
     """Kernel body factory.  Per (shard, word-block) grid step the 32
     bit positions are processed in chunks of `bc`; each chunk is one
     flattened (bc*bw,) column axis shared by the int8 payload matmul
-    and (when requested) the Min/Max presence walks."""
+    and (when requested) the Min/Max masked reductions."""
 
     def kernel(cp_ref, va_ref, *refs):
         pl_ref = refs[0] if depth else None
@@ -763,32 +763,25 @@ def _gb_fused_kernel(cb: int, depth: int, signed: bool, k: int,
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
             if minmax:
-                big = jnp.int32(1 << depth)
-                ident = jnp.stack([
-                    jnp.full((g_pad,), -1, jnp.int32),
-                    jnp.full((g_pad,), big, jnp.int32),
-                    jnp.full((g_pad,), -1, jnp.int32),
-                    jnp.full((g_pad,), big, jnp.int32)])
-                mm_ref[...] = ident
+                row = jax.lax.broadcasted_iota(jnp.int32, (4, g_pad), 0)
+                mm_ref[...] = jnp.where(row % 2 == 0, -1, 1 << depth)
 
         iota_g = jax.lax.broadcasted_iota(jnp.int32, (1, g_pad), 1)
-        acc = jnp.zeros((k, g_pad), jnp.int32)
         big = 1 << depth
-        mxp = jnp.full((g_pad,), -1, jnp.int32)
-        mnp_ = jnp.full((g_pad,), big, jnp.int32)
-        mxn = jnp.full((g_pad,), -1, jnp.int32)
-        mnn = jnp.full((g_pad,), big, jnp.int32)
-        for c in range(0, 32, bc):
-            sh = (jax.lax.broadcasted_iota(jnp.uint32, (bc, 1), 0)
-                  + jnp.uint32(c))
 
-            def bits(w, sh=sh):
+        def chunk(c, carry):
+            # rolled, so Mosaic sizes its VMEM stack for one chunk's
+            # temporaries, not for all of them
+            sh = (jax.lax.broadcasted_iota(jnp.uint32, (bc, 1), 0)
+                  + (c * bc).astype(jnp.uint32))
+
+            def bits(w):
                 # (bw,) uint32 -> (bc*bw,) 0/1 int32 — positions
-                # [c, c+bc) of every word, flattened bit-major
+                # [c*bc, (c+1)*bc) of every word, flattened bit-major
                 return ((w[None, :] >> sh)
                         & jnp.uint32(1)).astype(jnp.int32).reshape(-1)
 
-            va = bits(va_ref[0])
+            va = bits(va_ref[0, 0])
             code = jnp.zeros_like(va)
             for b in range(cb):
                 code = code | (bits(cp_ref[0, b]) << b)
@@ -802,65 +795,34 @@ def _gb_fused_kernel(cb: int, depth: int, signed: bool, k: int,
             payload = jnp.stack(rows).astype(jnp.int8)   # (K, bc*bw)
             # invalid columns carry all-zero payload (every row has a
             # `va` factor), so their arbitrary code contributes 0
-            onehot = (code[:, None] == iota_g).astype(jnp.int8)
-            acc += jnp.dot(payload, onehot,
-                           preferred_element_type=jnp.int32)
+            hit = code[:, None] == iota_g                # (bc*bw, G)
+            out_ref[...] += jnp.dot(payload, hit.astype(jnp.int8),
+                                    preferred_element_type=jnp.int32)
             if minmax:
-                posm = ex * (1 - sg) if signed else ex
-                negm = ex * sg if signed else None
-
-                def gdot(col_vec):
-                    # per-group popcount of a 0/1 column mask: one
-                    # int8 mat-vec against the shared one-hot
-                    return jnp.dot(
-                        col_vec.astype(jnp.int8).reshape(1, -1),
-                        onehot,
-                        preferred_element_type=jnp.int32)[0]
-
-                def cdot(g_vec):
-                    # presence bit gathered back per column through
-                    # the transposed one-hot
-                    return jnp.dot(
-                        onehot, g_vec.astype(jnp.int8).reshape(-1, 1),
-                        preferred_element_type=jnp.int32)[:, 0]
-
-                def walk_max(candm):
-                    alive = gdot(candm)
-                    out = jnp.zeros((g_pad,), jnp.int32)
-                    cand = candm
-                    for p in range(depth - 1, -1, -1):
-                        pres = (gdot(cand * mag[p]) > 0)
-                        out = out | (pres.astype(jnp.int32) << p)
-                        pres_c = cdot(pres.astype(jnp.int32)) > 0
-                        cand = cand * jnp.where(pres_c, mag[p], 1)
-                    return jnp.where(alive > 0, out, -1)
-
-                def walk_min(candm):
-                    alive = gdot(candm)
-                    out = jnp.zeros((g_pad,), jnp.int32)
-                    cand = candm
-                    for p in range(depth - 1, -1, -1):
-                        cnt_all = gdot(cand)
-                        cnt_with = gdot(cand * mag[p])
-                        zpres = (cnt_all - cnt_with) > 0
-                        forced1 = jnp.logical_and(
-                            jnp.logical_not(zpres), cnt_all > 0)
-                        out = out | (forced1.astype(jnp.int32) << p)
-                        zp_c = cdot(zpres.astype(jnp.int32)) > 0
-                        cand = cand * jnp.where(zp_c, 1 - mag[p], 1)
-                    return jnp.where(alive > 0, out, big)
-
-                mxp = jnp.maximum(mxp, walk_max(posm))
-                mnp_ = jnp.minimum(mnp_, walk_min(posm))
+                # per-group Min/Max of the column magnitudes on the
+                # VPU: one masked max/min over the chunk's one-hot
+                # per side.  Invalid columns have ex == 0 and so
+                # carry the identity on every side.
+                val = mag[0]
+                for p in range(1, depth):
+                    val = val | (mag[p] << p)
+                sides = [(0, ex * (1 - sg) if signed else ex)]
                 if signed:
-                    mxn = jnp.maximum(mxn, walk_max(negm))
-                    mnn = jnp.minimum(mnn, walk_min(negm))
-        out_ref[...] += acc
-        if minmax:
-            cur = mm_ref[...]
-            mm_ref[...] = jnp.stack([
-                jnp.maximum(cur[0], mxp), jnp.minimum(cur[1], mnp_),
-                jnp.maximum(cur[2], mxn), jnp.minimum(cur[3], mnn)])
+                    sides.append((2, ex * sg))
+                for r, mask in sides:
+                    hi = jnp.where(mask == 1, val, -1)[:, None]
+                    lo = jnp.where(mask == 1, val, big)[:, None]
+                    mm_ref[r:r + 1, :] = jnp.maximum(
+                        mm_ref[r:r + 1, :],
+                        jnp.max(jnp.where(hit, hi, -1), axis=0,
+                                keepdims=True))
+                    mm_ref[r + 1:r + 2, :] = jnp.minimum(
+                        mm_ref[r + 1:r + 2, :],
+                        jnp.min(jnp.where(hit, lo, big), axis=0,
+                                keepdims=True))
+            return carry
+
+        jax.lax.fori_loop(0, 32 // bc, chunk, 0)
     return kernel
 
 
@@ -893,18 +855,20 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
     k = 1 if depth == 0 else 2 + (2 if signed else 1) * depth
     g_pad = max(-(-int(n_codes) // 128) * 128, 128)
     # word block + bit-chunk sized so the per-chunk int8 one-hot
-    # (bc*bw, G) stays ~2 MB; bc divides 32 so chunks tile the word
+    # (bc*bw, G) stays ~2 MB (Min/Max selects over it in int32, so a
+    # quarter of the columns); bc divides 32 so chunks tile the word
+    cols = (1 << 19) if minmax else (1 << 21)
     bw = max(128, min(2048, w_dim))
-    bc = max(1, min(32, (1 << 21) // (bw * g_pad)))
+    if minmax:
+        bw = max(128, min(bw, cols // g_pad))
+    bc = max(1, min(32, cols // (bw * g_pad)))
     while 32 % bc:
         bc -= 1
     code_planes = _pad_axis(code_planes, 2, bw)
-    valid = _pad_axis(valid, 1, bw)
+    valid, valid_spec = _valid_operand(valid, bw)
     arrays = [code_planes, valid]
-    in_specs = [
-        pl.BlockSpec((1, cb, bw), lambda s, w: (s, 0, w)),
-        pl.BlockSpec((1, bw), lambda s, w: (s, w)),
-    ]
+    in_specs = [pl.BlockSpec((1, cb, bw), lambda s, w: (s, 0, w)),
+                valid_spec]
     if depth:
         planes = _pad_axis(planes, 2, bw)
         arrays.append(planes)

@@ -53,17 +53,10 @@ def place_shards(mesh: Mesh, tiles, batch_axes: int = 0):
 
 
 def shard_map_nocheck(body, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the JAX API
-    rename: new JAX exports jax.shard_map(check_vma=...), 0.4.x has
-    jax.experimental.shard_map.shard_map(check_rep=...)."""
-    try:
-        from jax import shard_map as sm
-        kwargs = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        kwargs = {"check_rep": False}
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
+    """shard_map with replication checking off (the bodies call
+    Pallas kernels and reduce their partials with explicit psums)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def flat_spec(ndim: int, shard_axis: int = 0) -> P:

@@ -253,8 +253,7 @@ def test_multiprocess_sharded_ingest():
     """N importer PROCESSES over disjoint shard ranges into one
     server — the reference's IDK clone concurrency
     (idk/ingest.go:302 m.clone() per ingester).  Validates the
-    deployment shape on this host; the measured single-process rate
-    ladder lives in BENCH_TPU_NOTES.md."""
+    deployment shape on this host."""
     import multiprocessing as mp
 
     from pilosa_tpu.server import Server
