@@ -1,0 +1,244 @@
+"""The system under test, as an operator gets it, and the harness's view of it.
+
+Copied from ``chip_smoke.py`` (which ran on the chip in PR 21) and
+generalised only so far that a configuration file names the fields:
+device check, compile-cache placing, native build, ``build_server``
+with the default config, schema over HTTP, bulk load through
+``Fragment.import_row_words``, a small HTTP client, one ``/metrics.json``
+scrape as an object, and the compile counter.
+
+Only this module (and ``run.py``, which calls it) touches JAX and the
+program.  The load generator is a child process that imports neither.
+"""
+
+from __future__ import annotations
+
+import http.client
+import importlib.util
+import json
+import os
+import shutil
+import subprocess
+import sys
+from concurrent.futures import ThreadPoolExecutor
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(ROOT, "benchmark")
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class BenchError(SystemExit):
+    """The run cannot give a result: exit code 2, no result line."""
+
+    def __init__(self, what: str):
+        print(f"benchmark: {what}", file=sys.stderr, flush=True)
+        super().__init__(2)
+
+
+def load_module(kind: str, name: str):
+    """benchmark/<kind>/<name>.py, found by the name a data file gives."""
+    path = os.path.join(BENCH, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise BenchError(f"no {kind}/{name}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_json(*parts: str) -> dict:
+    path = os.path.join(BENCH, *parts)
+    if not os.path.isfile(path):
+        raise BenchError(f"no {os.path.join(*parts)}")
+    with open(path) as f:
+        return json.load(f)
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def device(chips: int, rehearse_cpu: bool) -> dict:
+    """Place the compile cache, then look for the chip.  No TPU, or
+    fewer chips than the cell asks for, ends the run with no result."""
+    from pilosa_tpu import compile_cache
+    compile_cache.place()
+    import jax
+    devs = jax.devices()
+    dev = devs[0]
+    if dev.platform != "tpu" and not rehearse_cpu:
+        raise BenchError(f"no TPU (jax.devices() = {devs}); the CPU "
+                         "rehearsal is asked for with --rehearse-cpu")
+    if len(devs) < chips:
+        raise BenchError(f"the cell needs {chips} chips, JAX sees {len(devs)}")
+    peaks = load_json("harness", "peaks.json")
+    if dev.platform == "tpu" and dev.device_kind not in peaks:
+        raise BenchError(f"device kind {dev.device_kind!r} is not in "
+                         "harness/peaks.json")
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": chips}
+
+
+def memory_peak_bytes(chips: int) -> int:
+    import jax
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in jax.devices()[:chips])
+
+
+def build_native() -> None:
+    """native/build/ from native/*.cc when it is not there yet (it is
+    in .gitignore, so a fresh checkout builds once and keeps it)."""
+    from pilosa_tpu.storage import native_ingest
+    if native_ingest.available() or shutil.which("g++") is None:
+        return
+    subprocess.run(["sh", os.path.join(ROOT, "native", "build.sh")],
+                   check=True, capture_output=True)
+
+
+class Compiles:
+    """Counts what JAX compiled and what its persistent cache served."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.n = self.hits = 0
+        self.secs = 0.0
+        mon.register_event_duration_secs_listener(self._dur)
+        mon.register_event_listener(self._event)
+
+    def _dur(self, event, secs, **_kw):
+        if event == COMPILE_EVENT:
+            self.n += 1
+            self.secs += secs
+
+    def _event(self, event, **_kw):
+        if event == CACHE_HIT_EVENT:
+            self.hits += 1
+
+    def snap(self) -> dict:
+        return {"n": self.n, "cache_hits": self.hits, "seconds": self.secs}
+
+
+# ---------------------------------------------------------------------------
+# HTTP client of the parent: schema, scrapes, warm-up askings
+# ---------------------------------------------------------------------------
+
+class Metrics:
+    """One /metrics.json scrape: {name: {labels: value}}."""
+
+    def __init__(self, scrape: dict):
+        self.scrape = scrape
+
+    def total(self, name: str, label: str = "") -> float:
+        """Sum of a counter's series whose labels contain `label`."""
+        return sum(v for k, v in self.scrape.get(name, {}).items()
+                   if label in k and not isinstance(v, dict))
+
+    def hist(self, name: str) -> dict:
+        """A histogram's {"count", "sum"} summed over its series."""
+        out = {"count": 0, "sum": 0.0}
+        for v in self.scrape.get(name, {}).values():
+            if isinstance(v, dict):
+                out["count"] += v["count"]
+                out["sum"] += v["sum"]
+        return out
+
+
+class Http:
+    def __init__(self, port: int, index: str, timeout: float = 900.0):
+        self.index = index
+        self.conn = http.client.HTTPConnection("127.0.0.1", port,
+                                               timeout=timeout)
+
+    def call(self, method: str, path: str, body=None):
+        data = None if body is None else json.dumps(body).encode()
+        self.conn.request(method, path, body=data,
+                          headers={"Content-Type": "application/json"})
+        resp = self.conn.getresponse()
+        raw = resp.read()
+        if resp.status != 200:
+            raise BenchError(f"{method} {path} -> {resp.status}: {raw[:300]!r}")
+        return json.loads(raw) if raw else None
+
+    def pql(self, q: str):
+        return self.call("POST", f"/index/{self.index}/query",
+                         {"query": q})["results"][0]
+
+    def metrics(self) -> Metrics:
+        return Metrics(self.call("GET", "/metrics.json"))
+
+    def flights(self, limit: int = 512) -> list[dict]:
+        return self.call("GET", f"/debug/queries?limit={limit}")["queries"]
+
+    def errors(self) -> list:
+        return self.call("GET", "/debug/errors") or []
+
+    def close(self) -> None:
+        self.conn.close()
+
+
+# ---------------------------------------------------------------------------
+# start and load
+# ---------------------------------------------------------------------------
+
+def start(config: dict):
+    """The server `pilosa-tpu server` starts, default config, on an
+    ephemeral port of 127.0.0.1; the configuration's schema over HTTP."""
+    from pilosa_tpu import config as cfgmod
+    from pilosa_tpu.cli.main import build_server
+    cfg = cfgmod.load(None, overrides={"bind": "127.0.0.1", "port": 0})
+    srv = build_server(cfg).start()
+    params = config["params"]
+    http_ = Http(srv.port, params["index"])
+    bsi = params["bsi"]
+    fields = [{"name": f["name"],
+               "options": {"type": "set", "cache_type": "none"}}
+              for f in (*params["plain"], *params["categorical"])]
+    fields.append({"name": bsi["name"], "options": {
+        "type": "int", "min": 0, "max": (1 << bsi["depth"]) - 1}})
+    http_.call("POST", "/schema", {"indexes": [
+        {"name": params["index"], "fields": fields}]})
+    got = http_.call("GET", "/schema")["indexes"][0]
+    if len(got["fields"]) != len(fields):
+        raise BenchError(f"schema came back as {got}")
+    serving = srv.api.executor.serving
+    if not (serving is not None and serving.batching
+            and serving.cache is not None):
+        raise BenchError("the serving plane is not on")
+    return srv, http_
+
+
+def load(srv, config: dict, generator, seed: int, shards: int,
+         skip_shards: frozenset = frozenset()):
+    """Generate every shard from the seed on all cores and bulk-load
+    it (Fragment.import_row_words, the restore path).  Returns the
+    summed reference tables.  Shards in `skip_shards` are generated
+    and counted by the reference but never reach the server: the
+    control's broken guarantee (an acknowledged import that no read
+    sees)."""
+    from pilosa_tpu.models.index import EXISTENCE_FIELD
+    from pilosa_tpu.models.view import VIEW_STANDARD
+    params = config["params"]
+    idx = srv.holder.index(params["index"])
+    idx._ensure_existence()     # every column exists, as in a restore
+    bsi_name = params["bsi"]["name"]
+    views = {name: f.view(f.bsi_view if name == bsi_name else VIEW_STANDARD,
+                          create=True) for name, f in idx.fields.items()}
+    tables = None
+    workers = max(1, min(12, os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers) as pool:
+        for lo in range(0, shards, 4 * workers):
+            chunk = range(lo, min(lo + 4 * workers, shards))
+            for shard, (rows, part) in zip(chunk, pool.map(
+                    lambda s: generator.make_shard(params, seed, s), chunk)):
+                tables = generator.add_tables(tables, part)
+                if shard in skip_shards:
+                    continue
+                # existence and the BSI not-null row: every column
+                rows[EXISTENCE_FIELD] = {0: rows[bsi_name][0]}
+                for f, frows in rows.items():
+                    frag = views[f].fragment(shard, create=True)
+                    for r, w in frows.items():
+                        frag.import_row_words(r, w)
+    return tables
