@@ -141,7 +141,6 @@ class Reference:
 
     def __init__(self, params: dict, tables: dict):
         self.lay = lay = Layout(params)
-        self.params = params
         j, v = 1 << lay.key_bits, 1 << lay.depth
         self.hist = tables["hist"].reshape(j, v)
         self.side = (tables["side"].reshape(-1, j, v)

@@ -3,7 +3,9 @@
 The parent (the only process on the chip, with the server in it) starts
 ``python loadgen.py`` and writes one JSON line to its stdin: port,
 index, traffic file's contents, seed and the runs to prepare
-(``[{"stream": 1, "seconds": 3}, {"stream": 0, "seconds": 40}]``).  The
+(``[{"stream": 1, "requests": 200}, {"stream": 0, "seconds": 40}]``: a
+run lasts so many seconds, or until each client has sent so many
+requests).  The
 child draws each run's schedule from the seed, opens one kept-open
 connection per client and prints ``{"ready": true}``.  For every later
 line ``{"run": i}`` it drives run ``i`` and prints two lines: a summary
@@ -34,6 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from harness import schedule  # noqa: E402
 
 REPLY_TIMEOUT_S = 120.0     # a minute past the longest window and more
+RUN_LIMIT_S = 300.0         # a run that is counted in requests ends here
 
 
 class Client(threading.Thread):
@@ -111,7 +114,8 @@ def drive(port: int, index: str, plans: list, seconds: float,
     summary = {
         "start": clock["start"], "end": clock["end"],
         "requests": len(records),
-        "exhausted": any(c.exhausted for c in clients),
+        "exhausted": any(c.exhausted for c in clients)
+        and seconds < RUN_LIMIT_S,
         "hung": sum(c.is_alive() for c in clients),
         "lateness_mean_ms": 1e3 * sum(gaps) / len(gaps) if gaps else 0.0,
         "lateness_max_ms": 1e3 * max(gaps) if gaps else 0.0,
@@ -124,8 +128,15 @@ def drive(port: int, index: str, plans: list, seconds: float,
 
 def main() -> int:
     spec = json.loads(sys.stdin.readline())
-    plans = [schedule.build(spec["traffic"], spec["seed"], run["stream"],
-                            run["seconds"]) for run in spec["runs"]]
+    plans = []
+    for run in spec["runs"]:
+        if "requests" in run:
+            run["seconds"] = RUN_LIMIT_S
+            plans.append(schedule.plans(spec["traffic"], spec["seed"],
+                                        run["stream"], run["requests"]))
+        else:
+            plans.append(schedule.build(spec["traffic"], spec["seed"],
+                                        run["stream"], run["seconds"]))
     print(json.dumps({"ready": True}), flush=True)
     for line in sys.stdin:
         cmd = json.loads(line)

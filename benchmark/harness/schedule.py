@@ -14,11 +14,19 @@ A traffic file (``benchmark/traffic/<mix>.json``) holds
   ``{"choice": [[weight, text], ...]}`` a text that may hold further
   placeholders, ``{"zipf": {"s": s, "values": [...]}}`` a value by a
   Zipf law over a ranking of the values that the seed fixes;
+- ``distinct``: ``true`` where no string may be sent twice in a run
+  (see ``plans``), so that neither the result cache nor the batcher's
+  promotion of recurring queries is what a run measures;
 - ``max_requests_per_client_per_s``: how long a schedule is made for a
   window of so many seconds (a client that runs out stops the run);
-- ``warmup``: ``sequential`` (ask every template once with every
-  option of the ``choice`` params it names, alone) and
-  ``concurrent_seconds`` (then run the closed loop itself that long).
+- ``warmup``: ``sequential`` and ``concurrent_requests`` (after the
+  sequential askings, run the closed loop itself until each client
+  has sent so many requests of the warm-up stream).
+  ``sequential`` is ``true`` (ask every template once with every
+  option of the ``choice`` params it names, alone) or a list that
+  names each program shape the mix reaches: ``{"t": template,
+  "fixed": {param: text}}`` (the other draws from the warm-up stream)
+  or ``{"t": template, "q": pql}`` (a literal).
 
 Stream 0 is the window, stream 1 the warm-up: the same templates with
 other draws.  Pure functions of (file, seed): no clock, no program.
@@ -74,23 +82,42 @@ def requests_per_client(traffic: dict, seconds: float) -> int:
     return block * max(1, math.ceil(n / block))
 
 
+def plans(traffic: dict, seed: int, stream: int, n: int) -> list:
+    """The first `n` requests of every client: [[{"t": template,
+    "q": pql}]].  Blocks are dealt to the clients in turn, so a longer
+    schedule only appends.  With ``"distinct": true`` in the traffic
+    file no string is sent twice in a run (nor one that the
+    sequential warm-up asked), as long as its parameters leave a new
+    one to draw: a template without parameters repeats."""
+    clients = range(traffic["clients"])
+    draws = [Draws(traffic, seed, stream, c) for c in clients]
+    block = [t for t in traffic["templates"] for _ in range(t["count"])]
+    seen = None
+    if traffic.get("distinct"):
+        seen = {w["q"] for w in warm_sequential(traffic, seed)}
+    out = [[] for _ in clients]
+    while len(out[0]) < n:
+        for c in clients:
+            draws[c].rnd.shuffle(block)
+            for t in block:
+                q = draws[c].fill(t["pql"])
+                for _retry in range(32):
+                    if seen is None or q not in seen:
+                        break
+                    q = draws[c].fill(t["pql"])
+                if seen is not None:
+                    seen.add(q)
+                out[c].append({"t": t["name"], "q": q})
+    return [o[:n] for o in out]
+
+
 def client_schedule(traffic: dict, seed: int, stream: int, client: int,
                     n: int) -> list[dict]:
-    """The first `n` requests of one client: [{"t": template, "q": pql}]."""
-    draws = Draws(traffic, seed, stream, client)
-    block = [t for t in traffic["templates"] for _ in range(t["count"])]
-    out = []
-    while len(out) < n:
-        draws.rnd.shuffle(block)
-        out.extend({"t": t["name"], "q": draws.fill(t["pql"])}
-                   for t in block)
-    return out[:n]
+    return plans(traffic, seed, stream, n)[client]
 
 
 def build(traffic: dict, seed: int, stream: int, seconds: float) -> list:
-    n = requests_per_client(traffic, seconds)
-    return [client_schedule(traffic, seed, stream, c, n)
-            for c in range(traffic["clients"])]
+    return plans(traffic, seed, stream, requests_per_client(traffic, seconds))
 
 
 def _choice_slots(traffic: dict, text: str, seen=()) -> list[str]:
@@ -113,6 +140,14 @@ def warm_sequential(traffic: dict, seed: int) -> list[dict]:
     warm-up stream."""
     draws = Draws(traffic, seed, WARMUP, -1)
     out = []
+    listed = traffic["warmup"].get("sequential")
+    if isinstance(listed, list):
+        by_name = {t["name"]: t for t in traffic["templates"]}
+        for item in listed:
+            q = draws.fill(item.get("q") or by_name[item["t"]]["pql"],
+                           item.get("fixed"))
+            out.append({"t": item["t"], "q": q})
+        return out
     for t in traffic["templates"]:
         slots = _choice_slots(traffic, t["pql"])
         options = [[text for _w, text in traffic["params"][s]["choice"]]

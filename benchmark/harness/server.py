@@ -66,6 +66,10 @@ def device(chips: int, rehearse_cpu: bool) -> dict:
     from pilosa_tpu import compile_cache
     compile_cache.place()
     import jax
+    # keep every program, also the ones that compile in under a second:
+    # a later run of the cell then finds all of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     devs = jax.devices()
     dev = devs[0]
     if dev.platform != "tpu" and not rehearse_cpu:

@@ -2,7 +2,7 @@
 
 All over *all* requests of the window: a request that failed, was shed
 or answered wrongly is not completed, and its latency is worse than
-any measured one (``FAILED``), so failures move the tail and never
+any measured one, so failures move the tail and never
 shorten it.
 """
 
@@ -10,8 +10,6 @@ from __future__ import annotations
 
 import math
 import statistics
-
-FAILED = math.inf
 
 
 def percentile(values: list[float], q: float) -> float:

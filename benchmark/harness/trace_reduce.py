@@ -67,25 +67,21 @@ def device_ops(events: list[tuple], chips: int) -> dict:
     return out
 
 
-def busy_seconds(ops: dict) -> float:
-    """Seconds in which an operation ran, averaged over the chips."""
-    if not ops:
-        return 0.0
-    per_chip = [sum(e - s for s, e in union([(s, e) for _n, s, e in evs]))
-                for evs in ops.values()]
-    return sum(per_chip) / len(per_chip) / 1e9
-
-
 def op_seconds(ops: dict, pattern: str) -> float:
     """Seconds of the operations whose name matches, averaged over the
-    chips: the union of their intervals, so nested events of one
-    kernel are not counted twice."""
+    chips: the union of their intervals, so overlapping or nested
+    events are not counted twice."""
     if not ops:
         return 0.0
     rx = re.compile(pattern)
     per_chip = [sum(e - s for s, e in union(
         [(s, e) for n, s, e in evs if rx.search(n)])) for evs in ops.values()]
     return sum(per_chip) / len(per_chip) / 1e9
+
+
+def busy_seconds(ops: dict) -> float:
+    """Seconds in which any operation ran, averaged over the chips."""
+    return op_seconds(ops, "")
 
 
 def top_ops(ops: dict, n: int = 10) -> list[list]:

@@ -13,12 +13,8 @@ def _moved(ctx, series) -> float:
     total = 0.0
     for name, label in series:
         for m, sign in ((ctx["m1"], 1), (ctx["m0"], -1)):
-            if label in ("count", "sum") and any(
-                    isinstance(v, dict)
-                    for v in m.scrape.get(name, {}).values()):
-                total += sign * m.hist(name)[label]
-            else:
-                total += sign * m.total(name, label)
+            total += sign * (m.hist(name)[label] if label in ("count", "sum")
+                             else m.total(name, label))
     return total
 
 

@@ -33,6 +33,7 @@ import time
 T_PROCESS = time.time()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -138,7 +139,7 @@ class FlightPoller(threading.Thread):
             self.poll()
 
 
-def main(argv=None) -> int:
+def parse(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -156,7 +157,19 @@ def main(argv=None) -> int:
                     help="have JAX name every program it compiles, on "
                          "standard error (to find what compile.in_window "
                          "counted)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def run_cell(args, rehearsal_counts: bool = False) -> dict:
+    """One run; the result line as a dict.  `rehearsal_counts` is for
+    the benchmark's own tests alone: it lets a CPU rehearsal's
+    `correct` stand as the comparison found it, so that a test can see
+    a planted fault turn it false."""
+    with contextlib.ExitStack() as cleanup:
+        return _run_cell(args, rehearsal_counts, cleanup)
+
+
+def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
     if args.rehearse_cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
         # what a TPU picks by itself: the kernels, here in interpret mode
@@ -173,7 +186,7 @@ def main(argv=None) -> int:
     params = config["params"]
     shards = params["rehearsal_shards"] if args.rehearse_cpu \
         else params["shards"]
-    warm_s = float(traffic["warmup"].get("concurrent_seconds", 0))
+    warm_n = int(traffic["warmup"].get("concurrent_requests", 0))
 
     device = server.device(cell["chips"], args.rehearse_cpu)
     if args.log_compiles:
@@ -186,7 +199,7 @@ def main(argv=None) -> int:
     trace_dir = None
     try:
         gen = LoadGen(srv.port, params["index"], traffic, args.seed, [
-            {"stream": schedule.WARMUP, "seconds": warm_s},
+            {"stream": schedule.WARMUP, "requests": warm_n},
             {"stream": schedule.WINDOW, "seconds": args.seconds}])
         t0 = time.time()
         skip = frozenset([shards - 1]) if args.control else frozenset()
@@ -200,9 +213,13 @@ def main(argv=None) -> int:
         asked = 0
         if traffic["warmup"].get("sequential"):
             for item in schedule.warm_sequential(traffic, args.seed):
+                t1, n1 = time.time(), comp.n
                 http_.pql(item["q"])
                 asked += 1
-        if warm_s > 0:
+                note(phase="warm_query", t=item["t"], q=item["q"][-40:],
+                     seconds=round(time.time() - t1, 2),
+                     compiles=comp.n - n1)
+        if warm_n > 0:
             gen.start_run(0)
             summary, _records = gen.finish_run()
             asked += summary["requests"]
@@ -216,6 +233,7 @@ def main(argv=None) -> int:
         if args.trace:
             import jax
             trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+            cleanup.callback(shutil.rmtree, trace_dir, ignore_errors=True)
             poller = FlightPoller(server.Http(srv.port, params["index"]))
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -247,9 +265,9 @@ def main(argv=None) -> int:
         http_.close()
         srv.close()
 
-    # the program's state is freed; now the reference
+    # the server is closed and the peak is read; now the reference,
+    # which is numpy on the host and touches no device
     t0 = time.time()
-    del srv
     reference = generator.Reference(params, tables)
     plans = schedule.build(traffic, args.seed, schedule.WINDOW, args.seconds)
     ok, wrong = check.judge(records, plans, reference)
@@ -273,11 +291,18 @@ def main(argv=None) -> int:
     if summary["exhausted"]:
         raise SystemExit("benchmark: a client ran out of schedule; raise "
                          "max_requests_per_client_per_s in the traffic file")
-    if args.rehearse_cpu or device["platform"] != "tpu":
+    if not rehearsal_counts and (args.rehearse_cpu
+                                 or device["platform"] != "tpu"):
         correct = False     # a rehearsal is never a result
+    slowest = sorted(range(len(records)), key=lambda i: -lat[i])[:3]
     note(phase="check", seconds=round(time.time() - t0, 2),
+         slowest=[{"t": records[i]["t"], "client": records[i]["client"],
+                   "ms": round(lat[i], 1),
+                   "sent_at_s": round(records[i]["send"] - summary["start"], 2)}
+                  for i in slowest],
          distinct=len({plans[r["client"]][r["seq"]]["q"] for r in records}),
-         wrong_examples=wrong)
+         wrong_examples=wrong,
+         server_errors=[str(e)[:600] for e in e1[e0:e0 + 3]])
 
     values = {
         "qps": stats.completed_per_s(records, ok, summary["start"],
@@ -297,7 +322,6 @@ def main(argv=None) -> int:
             for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                                   recursive=True):
                 shutil.copy(path, args.keep_trace)
-        shutil.rmtree(trace_dir, ignore_errors=True)
         note(phase="trace", planes=trace["planes"][:40])
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
@@ -337,7 +361,12 @@ def main(argv=None) -> int:
         "lateness_mean_ms", "lateness_max_ms", "requests")}
     result["compiles_in_window"] = c1["n"] - c0["n"]
     result["compared"] = compared
-    for row in compared:
+    return result
+
+
+def main(argv=None) -> int:
+    result = run_cell(parse(argv))
+    for row in result["compared"]:
         print(f"compared {row['name']}: {row['value']} "
               f"(limit {row['limit']}) {'ok' if row['ok'] else 'NOT OK'}",
               file=sys.stderr)
