@@ -1,6 +1,6 @@
 """Programs JAX compiled between the snapshot after warm-up and the
 end of the window (``Compiles`` of harness/server.py).  Should read 0;
-``run.py --log-compiles`` names them."""
+running with ``JAX_LOG_COMPILES=1`` names them."""
 
 
 def read(ctx: dict, args: dict):

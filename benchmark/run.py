@@ -150,13 +150,6 @@ def parse(argv=None):
     ap.add_argument("--control", choices=("stale-shard",), default=None,
                     help="break one stated guarantee; must come out "
                          "as not correct")
-    ap.add_argument("--keep-trace", default=None, metavar="DIR",
-                    help="copy the traced run's .xplane.pb into DIR "
-                         "(to look at a trace by hand)")
-    ap.add_argument("--log-compiles", action="store_true",
-                    help="have JAX name every program it compiles, on "
-                         "standard error (to find what compile.in_window "
-                         "counted)")
     return ap.parse_args(argv)
 
 
@@ -189,9 +182,6 @@ def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
     warm_n = int(traffic["warmup"].get("concurrent_requests", 0))
 
     device = server.device(cell["chips"], args.rehearse_cpu)
-    if args.log_compiles:
-        import jax
-        jax.config.update("jax_log_compiles", True)
     server.build_native()
     comp = server.Compiles()
     srv, http_ = server.start(config)
@@ -248,14 +238,22 @@ def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
             jax.profiler.stop_trace()
         summary, records = gen.finish_run()
         m1, c1, e1 = http_.metrics(), comp.snap(), http_.errors()
-        flights = []
         if poller is not None:
             poller.stop.set()
             poller.join(10)
             poller.poll()
-            flights = [r for r in poller.seen.values()
-                       if r["start"] >= summary["start"]]
+            flights = list(poller.seen.values())
             poller.http.close()
+        else:
+            flights = http_.flights()    # the ring's newest 512
+        flights = [r for r in flights if r["start"] >= summary["start"]]
+        # a stall names itself: the server's own record of its longest
+        slow = sorted(flights, key=lambda r: -r.get("duration_ms", 0.0))[:3]
+        note(phase="slowest_flights", flights=[
+            {"ms": r.get("duration_ms"), "route": r.get("route"),
+             "at_s": round(r["start"] - summary["start"], 2),
+             "phases": r.get("phases"), "q": str(r.get("query"))[:80]}
+            for r in slow])
         peak = server.memory_peak_bytes(cell["chips"])
         setup_s = summary["start"] - T_PROCESS
         note(phase="window", **summary)
@@ -317,11 +315,6 @@ def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
         from harness import trace_reduce
         trace = trace_reduce.reduce_dir(trace_dir, cell["chips"],
                                         traced[1] - traced[0])
-        if args.keep_trace:
-            os.makedirs(args.keep_trace, exist_ok=True)
-            for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                                  recursive=True):
-                shutil.copy(path, args.keep_trace)
         note(phase="trace", planes=trace["planes"][:40])
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
