@@ -32,7 +32,7 @@ from pilosa_tpu.executor.results import (
 from pilosa_tpu.models.holder import Holder
 from pilosa_tpu.models.index import EXISTENCE_FIELD
 from pilosa_tpu.models.schema import FieldOptions
-from pilosa_tpu.obs import metrics
+from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs.tracing import RecordingTracer, Tracer, start_span
 from pilosa_tpu.pql.parser import ParseError
 from pilosa_tpu.sql.lexer import SQLError
@@ -204,7 +204,11 @@ class API:
         t0 = time.time()
         from pilosa_tpu.pql import is_write_query
         fence_done = None
-        if is_write_query(pql):
+        # the first parse of the string (memoized: ServingLayer's own
+        # `pql.parse` stage then reads the cache)
+        with flight.stage("pql.parse"):
+            is_write = is_write_query(pql)
+        if is_write:
             self._check_writable()
             # online-resharding fence (ISSUE 14): a write to a MOVED
             # shard answers 410 + new owner, a write racing a FENCE
@@ -243,7 +247,8 @@ class API:
                 _tr.pop_thread_tracer(prev)
             if fence_done is not None:
                 fence_done()
-        resp = {"results": [serialize_result(r) for r in results]}
+        with flight.stage("result.encode"):
+            resp = {"results": [serialize_result(r) for r in results]}
         if profile and tracer.roots:
             resp["profile"] = [s.to_dict() for s in tracer.roots]
         self._record_history(index, pql, t0, tracer)

@@ -45,7 +45,6 @@ fused program ran.
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 
 import numpy as np
@@ -53,15 +52,14 @@ import numpy as np
 from pilosa_tpu.executor.stacked import (
     PageView,
     PlanBuilder,
-    _block,
     _compiled,
     _dispatch_kind,
+    dispatch_ready,
     raw_pages,
 )
 from pilosa_tpu.memory import encode, pressure
 from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs.monitor import capture_exception
-from pilosa_tpu.obs.tracing import Span, span_into
 from pilosa_tpu.ops import kernels
 
 
@@ -956,17 +954,13 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
                          repr(slot.call))
                         if canon is not None else None)
             target = riders[0] if riders else _ShimReq(slot)
-            acc = flight.Acc()
             for r in riders:
                 r.acc = flight.Acc()
-            if riders:
-                riders[0].acc = acc
-            prev = flight.push_acc(acc)
-            t0 = time.perf_counter()
+            # one build serves every rider of the slot: one stage,
+            # recorded (with the stack fetches inside it) into each
+            # rider's Acc and each traced rider's tree
             try:
-                with raw_pages(), span_into(target.ctx,
-                                            "serving.plan",
-                                            kind=slot.kind):
+                with raw_pages(), _plan_stage(riders, slot.kind):
                     built = layer._build_sub(b, target, shards)
             except Exception:
                 # unbuildable now (data/schema drift): the slot
@@ -976,12 +970,6 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
                 if slot_key is not None:
                     dead_keys.append(slot_key)
                 continue
-            finally:
-                flight.pop_acc(prev)
-                stack_t = sum(v for k, v in acc.phases.items()
-                              if k.startswith("stack_"))
-                acc.add_phase("plan_build", max(
-                    time.perf_counter() - t0 - stack_t, 0.0))
             if built is None:
                 # constant result: share it across riders (the
                 # result cache shares result objects the same way)
@@ -992,8 +980,10 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
                 continue
             entries.append((riders, built[0], built[1], slot_key))
         if entries:
+            group = [r for riders, _s, _d, _k in entries for r in riders]
             try:
-                prog.add_group(b, entries, owners=owners)
+                with _plan_stage(group):
+                    prog.add_group(b, entries, owners=owners)
             except RaggedUnbuildable:
                 # the group can't enter the mesh program (whole/host
                 # operand, unplaced pages): its riders degrade to the
@@ -1007,7 +997,9 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
         canon.drop(dead_keys)
     cacheable = canon is not None and not dead_keys
     try:
-        fin = prog.finalize()
+        with _plan_stage([r for _i, _s, pairs in work
+                          for _slot, riders in pairs for r in riders]):
+            fin = prog.finalize()
     except RaggedUnbuildable as e:
         # finalize-time mesh rejection (placement drift, topology
         # shrink): every rider of the batch degrades, no error
@@ -1034,25 +1026,54 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
         # no rider this batch — skip the dispatch but keep the built
         # program for the cache (the next batch serves from it)
         return payload
+    outs = _dispatch_served(eng, plan, leaves, params, served,
+                            meshinfo, program, n_groups)
+    if outs is not None:
+        _demux_served(served, outs)
+    return payload
+
+
+def _plan_stage(riders, kind: str = "ragged"):
+    """The `plan_build` stage of work the leader does once for
+    `riders` (a slot's sub-plan; the page tables and the program's
+    assembly), recorded into each of them."""
+    return flight.stage(
+        "plan_build", accs=[r.acc for r in riders] or [flight.Acc()],
+        ctx=[r.ctx for r in riders], kind=kind)
+
+
+def _dispatch_served(eng, plan, leaves, params, served, meshinfo,
+                     program: str, n_groups: int):
+    """The ONE fused dispatch of a batch, timed once as the stage
+    `kind` ('execute' | 'compile') for every rider it serves: the
+    same span in each rider's flight record and trace tree.  The ready
+    outputs, or None after marking the riders direct."""
     kern = (kernels.enabled() and not eng.host_only
             and plan[0] != "ragged_mesh")
-    sig = (repr(plan), kern)
-    kind = _dispatch_kind(sig, leaves, params)
+    # the program's signature is a repr of the whole plan and a shape
+    # key per page: milliseconds at 16k pages, and part of building it
+    with _plan_stage([r for r, _d, _e in served]):
+        sig = (repr(plan), kern)
+        kind = _dispatch_kind(sig, leaves, params)
     nsubs = len(plan[3]) if plan[0] == "ragged" else len(plan[6])
-    sp = Span("serving.dispatch")
-    sp.tags.update(batch=len(served), subqueries=nsubs,
-                   ragged=True, program=program, groups=n_groups,
-                   mesh=plan[0] == "ragged_mesh",
-                   compile=kind == "compile")
     oom0 = metrics.OOM_TOTAL.total(outcome="caught")
-    t0 = time.perf_counter()
+    st = flight.stage(
+        kind, accs=[r.acc for r, _d, _e in served],
+        ctx=[r.ctx for r, _d, _e in served],
+        batch=len(served), subqueries=nsubs, ragged=True,
+        program=program, groups=n_groups,
+        mesh=plan[0] == "ragged_mesh", compile=kind == "compile")
     try:
-        # same chaos seam + OOM backstop as the per-group dispatch
-        from pilosa_tpu.obs import faults
-        faults.fire("serving-dispatch")
-        fn = _compiled(plan, kern=kern, sig=sig)
-        outs = pressure.guarded(
-            lambda: _block(fn(tuple(leaves), tuple(params))))
+        with st:
+            # same chaos seam + OOM backstop as the per-group dispatch
+            from pilosa_tpu.obs import faults
+            faults.fire("serving-dispatch")
+            fn = _compiled(
+                plan, kern=kern, sig=sig,
+                name="plan_ragged_extras" if program == "extras"
+                and plan[0] == "ragged" else None)
+            outs = pressure.guarded(lambda: dispatch_ready(
+                fn, tuple(leaves), tuple(params)))
     except Exception as e:
         capture_exception(
             e, where="serving.ragged_dispatch", batch=len(served),
@@ -1060,31 +1081,25 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
                        if r.trace_id])
         for r, _d, _e in served:
             r.direct = True
-        return
-    finally:
-        sp.finish()
+        return None
     metrics.SERVING_DISPATCH.inc(
         kind="ragged_mesh" if plan[0] == "ragged_mesh" else "ragged")
-    dt = time.perf_counter() - t0
     if kind == "execute" and \
             metrics.OOM_TOTAL.total(outcome="caught") == oom0:
-        _note_roofline(plan, leaves, dt, meshinfo, served)
-    for r, _d, _e in served:
-        r.acc.add_phase(kind, dt)
-        if r.ctx is not None:
-            r.ctx.attach(sp.copy())
+        _note_roofline(plan, leaves, st.seconds, meshinfo, served)
+    return outs
+
+
+def _demux_served(served, outs) -> None:
     for r, demux, ext in served:
         out = outs[ext[1]] if ext[0] == "plain" else \
             outs[ext[1]][ext[2]]
-        t1 = time.perf_counter()
         try:
-            with span_into(r.ctx, "serving.demux"):
+            with flight.stage("demux", accs=[r.acc], ctx=r.ctx):
                 r.result = demux(out)
         except Exception:
             r.direct = True
             r.result = None
-        r.acc.add_phase("demux", time.perf_counter() - t1)
-    return payload
 
 
 def _serve_cached(layer, eng, cached, by_key, n_groups: int) -> None:
@@ -1108,52 +1123,7 @@ def _serve_cached(layer, eng, cached, by_key, n_groups: int) -> None:
                 served.append((r, demux, ext))
     if not served or plan is None:
         return
-    kern = (kernels.enabled() and not eng.host_only
-            and plan[0] != "ragged_mesh")
-    sig = (repr(plan), kern)
-    kind = _dispatch_kind(sig, leaves, params)
-    nsubs = len(plan[3]) if plan[0] == "ragged" else len(plan[6])
-    sp = Span("serving.dispatch")
-    sp.tags.update(batch=len(served), subqueries=nsubs,
-                   ragged=True, program="canonical-cached",
-                   groups=n_groups, mesh=plan[0] == "ragged_mesh",
-                   compile=kind == "compile")
-    oom0 = metrics.OOM_TOTAL.total(outcome="caught")
-    t0 = time.perf_counter()
-    try:
-        from pilosa_tpu.obs import faults
-        faults.fire("serving-dispatch")
-        fn = _compiled(plan, kern=kern, sig=sig)
-        outs = pressure.guarded(
-            lambda: _block(fn(tuple(leaves), tuple(params))))
-    except Exception as e:
-        capture_exception(
-            e, where="serving.ragged_dispatch", batch=len(served),
-            trace_ids=[r.trace_id for r, _d, _e in served
-                       if r.trace_id])
-        for r, _d, _e in served:
-            r.direct = True
-        return
-    finally:
-        sp.finish()
-    metrics.SERVING_DISPATCH.inc(
-        kind="ragged_mesh" if plan[0] == "ragged_mesh" else "ragged")
-    dt = time.perf_counter() - t0
-    if kind == "execute" and \
-            metrics.OOM_TOTAL.total(outcome="caught") == oom0:
-        _note_roofline(plan, leaves, dt, meshinfo, served)
-    for r, _d, _e in served:
-        r.acc.add_phase(kind, dt)
-        if r.ctx is not None:
-            r.ctx.attach(sp.copy())
-    for r, demux, ext in served:
-        out = outs[ext[1]] if ext[0] == "plain" else \
-            outs[ext[1]][ext[2]]
-        t1 = time.perf_counter()
-        try:
-            with span_into(r.ctx, "serving.demux"):
-                r.result = demux(out)
-        except Exception:
-            r.direct = True
-            r.result = None
-        r.acc.add_phase("demux", time.perf_counter() - t1)
+    outs = _dispatch_served(eng, plan, leaves, params, served,
+                            meshinfo, "canonical-cached", n_groups)
+    if outs is not None:
+        _demux_served(served, outs)

@@ -41,7 +41,7 @@ import time
 from collections import deque
 from dataclasses import dataclass
 
-from pilosa_tpu.obs import metrics
+from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.pql.ast import Query
 
 CLASS_POINT = "point"
@@ -420,7 +420,8 @@ class _HeavySlot:
         self.qos = qos
 
     def __enter__(self):
-        self.sched._acquire(self.qos)
+        with flight.stage("admission.wait"):
+            self.sched._acquire(self.qos)
         return self
 
     def __exit__(self, *exc):

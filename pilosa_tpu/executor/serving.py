@@ -47,9 +47,9 @@ from pilosa_tpu.executor.results import Pair, RowResult, ValCount
 from pilosa_tpu.executor.stacked import (
     PlanBuilder,
     Unstackable,
-    _block,
     _compiled,
     _dispatch_kind,
+    dispatch_ready,
 )
 from pilosa_tpu.models.index import EXISTENCE_FIELD
 from pilosa_tpu.obs import audit as _audit
@@ -57,12 +57,7 @@ from pilosa_tpu.obs import faults as _faults
 from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs import stats as _stats
 from pilosa_tpu.obs.monitor import capture_exception
-from pilosa_tpu.obs.tracing import (
-    Span,
-    capture_context,
-    span_into,
-    start_span,
-)
+from pilosa_tpu.obs.tracing import capture_context, start_span
 from pilosa_tpu.ops import kernels
 from pilosa_tpu.pql import parse
 from pilosa_tpu.pql.ast import Call, Query
@@ -666,11 +661,12 @@ class QueryBatcher:
                 self._leader = True
                 follower = False
         if follower:
-            req.event.wait()
+            with flight.stage("batch.wait"):
+                req.event.wait()
             return
         t_lead = time.perf_counter()
         deadline = t_lead + self.window_s
-        with self._cond:
+        with flight.stage("batch.wait"), self._cond:
             # continuous batching: dispatch IMMEDIATELY when the
             # device is idle (a lone request must not eat the window
             # as pure latency); wait out the admission window only
@@ -819,13 +815,25 @@ class ServingLayer:
 
     def execute(self, index: str, query, shards=None,
                 remote: bool = False, qos=None) -> list:
+        # the request envelope: joins the transport's (the HTTP
+        # handler opened one at the first byte) or, for callers with
+        # no transport in front, opens here — so the stages before the
+        # flight record begins (parse, admission) reach the record
+        with flight.request():
+            return self._execute(index, query, shards, remote, qos)
+
+    def _execute(self, index, query, shards, remote, qos) -> list:
         from pilosa_tpu.executor import sched as _sched
         ex = self.executor
         if remote:
             # node-to-node calls carry the _REMOTE contextvar, which a
             # leader thread would not inherit — serve them solo
             return ex.execute(index, query, shards, remote=True)
-        q = parse(query) if isinstance(query, str) else query
+        if isinstance(query, str):
+            with flight.stage("pql.parse"):
+                q = parse(query)
+        else:
+            q = query
         if any(c.name in _WRITE_CALLS for c in q.calls):
             try:
                 return ex.execute(index, q, shards)
@@ -861,21 +869,24 @@ class ServingLayer:
         # flight record actually consumes them
         key = None
         fp = None
-        if _stats.enabled() and not (
-                qos is not None and qos.priority in (
-                    _sched.CLASS_POINT, _sched.CLASS_HEAVY)):
-            key = (index, repr(q.calls),
-                   None if shards is None else tuple(sorted(shards)))
-            fp = _fingerprint(key)
-        cls = _sched.classify(q, qos, fingerprint=fp)
-        # a dead-on-arrival deadline sheds regardless of class — the
-        # client stopped waiting, executing would only burn device time
-        if (qos is not None and qos.deadline_s is not None
-                and time.monotonic() > qos.deadline_s):
-            metrics.ADMISSION_TOTAL.inc(**{"class": cls,
-                                           "outcome": "expired"})
-            raise _sched.ServingDeadlineExceeded(
-                "deadline expired before execution")
+        with flight.stage("admission.classify"):
+            if _stats.enabled() and not (
+                    qos is not None and qos.priority in (
+                        _sched.CLASS_POINT, _sched.CLASS_HEAVY)):
+                key = (index, repr(q.calls),
+                       None if shards is None
+                       else tuple(sorted(shards)))
+                fp = _fingerprint(key)
+            cls = _sched.classify(q, qos, fingerprint=fp)
+            # a dead-on-arrival deadline sheds regardless of class —
+            # the client stopped waiting, executing would only burn
+            # device time
+            if (qos is not None and qos.deadline_s is not None
+                    and time.monotonic() > qos.deadline_s):
+                metrics.ADMISSION_TOTAL.inc(**{"class": cls,
+                                               "outcome": "expired"})
+                raise _sched.ServingDeadlineExceeded(
+                    "deadline expired before execution")
         # span on the CALLER's thread so the long-query log keeps its
         # executor.Execute root even for fused/cached serves (the
         # direct fallback nests its own copy inside — the root name
@@ -924,25 +935,24 @@ class ServingLayer:
             # batcher's mid-flight consistency re-check, so compute it
             # even with the cache disabled
             fields = None
-            tc = time.perf_counter()
-            try:
-                fields = query_fields(idx, q)
-            except Uncacheable:
-                if self.cache is not None:
-                    metrics.RESULT_CACHE.inc(outcome="bypass")
-            # ONE snapshot walk serves the cache guard, batch
-            # admission, and the miss-path store protocol (the walk is
-            # O(fields x views x shards) Python — at 954 shards it
-            # must not run three times per query); explicit-shard
-            # queries snapshot only their subset, so writes elsewhere
-            # never stale them
-            sset = _shard_set(shards)
-            snap = (field_snapshot(idx, fields, sset)
-                    if fields is not None else None)
-            cache_res = _MISS
-            if self.cache is not None and fields is not None:
-                cache_res = self.cache.get(idx, key, cur_snap=snap)
-            flight.note_phase("cache_lookup", time.perf_counter() - tc)
+            with flight.stage("cache_lookup"):
+                try:
+                    fields = query_fields(idx, q)
+                except Uncacheable:
+                    if self.cache is not None:
+                        metrics.RESULT_CACHE.inc(outcome="bypass")
+                # ONE snapshot walk serves the cache guard, batch
+                # admission, and the miss-path store protocol (the
+                # walk is O(fields x views x shards) Python — at 954
+                # shards it must not run three times per query);
+                # explicit-shard queries snapshot only their subset,
+                # so writes elsewhere never stale them
+                sset = _shard_set(shards)
+                snap = (field_snapshot(idx, fields, sset)
+                        if fields is not None else None)
+                cache_res = _MISS
+                if self.cache is not None and fields is not None:
+                    cache_res = self.cache.get(idx, key, cur_snap=snap)
             if cache_res is not _MISS:
                 route = "cached"
                 metrics.RESULT_CACHE.inc(outcome="hit")
@@ -987,9 +997,8 @@ class ServingLayer:
                 req.ctx = capture_context()
                 if fl is not None:
                     req.trace_id = fl["trace_id"]
-                tb = time.perf_counter()
-                self.batcher.run(req)
-                flight.note_phase("batch", time.perf_counter() - tb)
+                with flight.stage("batch"):
+                    self.batcher.run(req)
                 if req.error is not None:
                     raise req.error
                 if req.result is not None and not req.direct:
@@ -1123,21 +1132,28 @@ class ServingLayer:
         # marked direct and each CALLER thread re-executes its own
         # query after its event fires (parallel, like batching off).
         for r in batch:
-            if (not r.direct and r.error is None and r.result is not None
-                    and r.fields is not None
-                    and field_snapshot(r.idx, r.fields,
-                                       _shard_set(r.shards))
-                    != r.snapshot):
-                # a write landed while the batch was in flight: the
-                # fused result may span versions — re-execute solo
-                r.direct = True
-                r.result = None
-            if r.result is not None and not r.direct and \
-                    r.error is None and r.fields is not None and \
-                    self.cache is not None:
-                self.cache.put(r.key, r.fields, r.snapshot, r.result,
-                               cost_ms=self._recompute_cost(r.key,
-                                                            r.acc))
+            # the result cache's store side, staged per rider like its
+            # lookup side: the version walk is O(fields x views x
+            # shards) Python while every follower is still parked
+            with flight.stage("cache_store",
+                              accs=[r.acc or flight.Acc()]):
+                if (not r.direct and r.error is None
+                        and r.result is not None
+                        and r.fields is not None
+                        and field_snapshot(r.idx, r.fields,
+                                           _shard_set(r.shards))
+                        != r.snapshot):
+                    # a write landed while the batch was in flight:
+                    # the fused result may span versions — re-execute
+                    # solo
+                    r.direct = True
+                    r.result = None
+                if r.result is not None and not r.direct and \
+                        r.error is None and r.fields is not None and \
+                        self.cache is not None:
+                    self.cache.put(
+                        r.key, r.fields, r.snapshot, r.result,
+                        cost_ms=self._recompute_cost(r.key, r.acc))
 
     def _run_group(self, reqs: list[_Req]) -> None:
         ex = self.executor
@@ -1157,22 +1173,14 @@ class ServingLayer:
             # per-request attribution ON the leader thread: stack
             # fetches/uploads inside the build accumulate into THIS
             # request's Acc, and spans graft into its TraceContext
-            r.acc = acc = flight.Acc()
-            prev = flight.push_acc(acc)
-            t0 = time.perf_counter()
+            r.acc = flight.Acc()
             try:
-                with span_into(r.ctx, "serving.plan",
-                               kind=r.kind):
+                with flight.stage("plan_build", accs=[r.acc],
+                                  ctx=r.ctx, kind=r.kind):
                     built = self._build_sub(b, r, shards)
             except Exception:
                 r.direct = True
                 continue
-            finally:
-                flight.pop_acc(prev)
-                stack_t = sum(v for k, v in acc.phases.items()
-                              if k.startswith("stack_"))
-                acc.add_phase("plan_build", max(
-                    time.perf_counter() - t0 - stack_t, 0.0))
             if built is None:
                 continue  # constant result already set on r
             sub, demux = built
@@ -1189,25 +1197,27 @@ class ServingLayer:
         kern = kernels.enabled() and not eng.host_only
         sig = (repr(plan), kern)  # multi-KB at high occupancy: once
         kind = _dispatch_kind(sig, b.leaves, b.params)
-        sp = Span("serving.dispatch")
-        sp.tags.update(batch=len(pend), subqueries=len(subs),
-                       compile=kind == "compile")
-        t0 = time.perf_counter()
         try:
-            # chaos seam: an armed "serving-dispatch" fault fails the
-            # fused program exactly like a device-side error, driving
-            # every rider onto the per-caller direct fallback
-            from pilosa_tpu.obs import faults
-            faults.fire("serving-dispatch")
-            fn = _compiled(plan, kern=kern, sig=sig)
-            # OOM backstop: RESOURCE_EXHAUSTED on the fused program
-            # evicts via the ledger + retries once; a persistent OOM
-            # falls through to the per-rider direct path, where each
-            # solo dispatch carries its own host-fallback ladder —
-            # the batch degrades, no rider's query fails
-            from pilosa_tpu.memory import pressure
-            outs = pressure.guarded(
-                lambda: _block(fn(tuple(b.leaves), tuple(b.params))))
+            with flight.stage(kind, accs=[r.acc for r in pend],
+                              ctx=[r.ctx for r in pend],
+                              batch=len(pend), subqueries=len(subs),
+                              compile=kind == "compile"):
+                # chaos seam: an armed "serving-dispatch" fault fails
+                # the fused program exactly like a device-side error,
+                # driving every rider onto the per-caller direct
+                # fallback
+                from pilosa_tpu.obs import faults
+                faults.fire("serving-dispatch")
+                fn = _compiled(plan, kern=kern, sig=sig)
+                # OOM backstop: RESOURCE_EXHAUSTED on the fused
+                # program evicts via the ledger + retries once; a
+                # persistent OOM falls through to the per-rider direct
+                # path, where each solo dispatch carries its own
+                # host-fallback ladder — the batch degrades, no
+                # rider's query fails
+                from pilosa_tpu.memory import pressure
+                outs = pressure.guarded(lambda: dispatch_ready(
+                    fn, tuple(b.leaves), tuple(b.params)))
         except Exception as e:
             # the fused program failing is a leader-side event the
             # affected callers never see (they silently fall back) —
@@ -1218,23 +1228,14 @@ class ServingLayer:
             for r in pend:
                 r.direct = True
             return
-        finally:
-            sp.finish()
         metrics.SERVING_DISPATCH.inc(kind="group")
-        dt = time.perf_counter() - t0
-        for r in pend:
-            r.acc.add_phase(kind, dt)
-            if r.ctx is not None:
-                r.ctx.attach(sp.copy())
         for r, demux, out in zip(pend, demuxes, outs):
-            t1 = time.perf_counter()
             try:
-                with span_into(r.ctx, "serving.demux"):
+                with flight.stage("demux", accs=[r.acc], ctx=r.ctx):
                     r.result = demux(out)
             except Exception:
                 r.direct = True
                 r.result = None
-            r.acc.add_phase("demux", time.perf_counter() - t1)
 
     def _build_sub(self, b: PlanBuilder, r: _Req, shards: list[int]):
         """(subplan, demux) for one request, or None after setting a
@@ -1466,7 +1467,7 @@ class ServingLayer:
             return None
         cost = _stats.est_recompute_ms(_fingerprint(key))
         if cost is None and acc is not None:
-            cost = sum(acc.phases.values()) * 1e3
+            cost = acc.root_s * 1e3
         return cost
 
 

@@ -59,7 +59,6 @@ from pilosa_tpu.memory.pages import PagedStack, StackRecipe, page_lanes_for
 from pilosa_tpu.models import timeq
 from pilosa_tpu.models.view import VIEW_STANDARD
 from pilosa_tpu.obs import flight, metrics, roofline, stats
-from pilosa_tpu.obs.tracing import start_span
 from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.ops import bsi as bsi_ops
 from pilosa_tpu.ops import kernels
@@ -183,14 +182,15 @@ def _expand_view(view: PageView):
     """Materialize a PageView into the assembled dense operand the
     non-raw fetch path would have returned — the whole-operand decode
     boundary for plans with no packed arm."""
-    pages = view.dense_pages()
-    if view.lane_page is not None:
-        return _assemble_permuted(pages, view.lane_page,
-                                  view.lane_slot, view.page_lanes,
-                                  view.shape)
-    if len(pages) == 1 and view.lanes == view.page_lanes:
-        return pages[0].reshape(view.shape)
-    return bm.assemble_pages(tuple(pages), view.shape)
+    with flight.stage("stack.assemble"):
+        pages = view.dense_pages()
+        if view.lane_page is not None:
+            return _assemble_permuted(pages, view.lane_page,
+                                      view.lane_slot, view.page_lanes,
+                                      view.shape)
+        if len(pages) == 1 and view.lanes == view.page_lanes:
+            return pages[0].reshape(view.shape)
+        return bm.assemble_pages(tuple(pages), view.shape)
 
 
 def _assemble_permuted(pages, lane_page, lane_slot, page_lanes,
@@ -305,17 +305,23 @@ class TileStackCache:
         so a query's flight record says exactly what its stacks cost.
         `recipe` (memory/pages.py StackRecipe) opts the entry into
         paged residency and prefetch."""
-        t0 = time.perf_counter()
-        fp = (self._remember_recipe(key, build, patcher, recipe)
-              if recipe is not None else None)
-        with start_span("stacked.stack") as sp:
+        # the stage learns its name last: a hit is a sum and a count
+        # with no span of its own (one query touches dozens); patch /
+        # rebuild / wait are spans.  The work itself is annotated
+        # where it runs (_serve_whole / _serve_paged).
+        with flight.stage("stack_hit") as st:
+            fp = (self._remember_recipe(key, build, patcher, recipe)
+                  if recipe is not None else None)
             arr, outcome, moved = self._get(key, versions, build,
                                             patcher, recipe)
-            sp.set_tag("outcome", outcome)
-            if moved:
-                sp.set_tag("bytes", moved)
+            st.name = "stack_" + outcome
+            st.keep = outcome != "hit"
+            if st.span is not None:
+                st.span.set_tag("outcome", outcome)
+                if moved:
+                    st.span.set_tag("bytes", moved)
         flight.note_stack(
-            outcome, moved, time.perf_counter() - t0,
+            outcome, moved,
             key_fp=fp if outcome not in ("hit", "wait") else None)
         return arr
 
@@ -353,7 +359,7 @@ class TileStackCache:
             if fp is not None and fp in self._recipes:
                 self._recipes.move_to_end(fp)
         arr = payload if ps_hit is None else self._assemble(*ps_hit)
-        flight.note_stack("hit", 0, time.perf_counter() - t0)
+        flight.note_stack("hit", 0, dt=time.perf_counter() - t0)
         return arr
 
     def _get(self, key, versions: tuple, build, patcher=None,
@@ -426,7 +432,8 @@ class TileStackCache:
                        and not isinstance(stale[1], PagedStack))
         if stale_whole and patcher is not None:
             try:
-                patched = patcher(stale[1], stale[0])
+                with flight.annotate("stack_patch"):
+                    patched = patcher(stale[1], stale[0])
             except Exception:
                 patched = None  # any patch failure → full rebuild
             if patched is not None:
@@ -438,7 +445,8 @@ class TileStackCache:
                 metrics.STACK_CACHE.inc(outcome="patch")
                 metrics.STACK_MAINT_BYTES.inc(pbytes, kind="patched")
         if arr is None:
-            arr = build()
+            with flight.annotate("stack_rebuild"):
+                arr = build()
             nb = int(np.prod(arr.shape)) * arr.dtype.itemsize
             moved = nb
             with self._lock:
@@ -514,93 +522,99 @@ class TileStackCache:
         resident_cap = max(
             int(_ENTRY_RESIDENT_FRAC * self._budget_cap()),
             pl * w * 4)
-        if ps is None or dirty is None:
-            if ps is not None:
-                self._drop_pages(key, ps)
-            ps = PagedStack(shape, pl, weight=recipe.weight,
-                            lane_device=recipe.lane_device,
-                            shard_axis=recipe.shard_axis)
-            host = np.asarray(recipe.build_host(),
-                              dtype=np.uint32).reshape(-1, w)
-            retained = 0
-            for pi in range(ps.n_pages):
-                ids = ps.page_lane_ids(pi)
-                block = host[ids]
-                if block.shape[0] < pl:
-                    block = np.concatenate(
-                        [block, np.zeros((pl - block.shape[0], w),
-                                         np.uint32)])
-                local[pi] = self._commit_page(
-                    block, key, device=self._page_jdev(ps, pi))
-                # true encoded page bytes — both for the admission
-                # cap and the maintenance-traffic attribution (a
-                # packed page uploads its coordinates, not the dense
-                # tile it stands for)
-                nb_pi = encode.page_nbytes(local[pi])
-                rebuilt_b += nb_pi
-                if (retained + nb_pi <= resident_cap
-                        and self._page_install(key, ps, pi,
-                                               local[pi])):
-                    retained += nb_pi
-            outcome = "rebuild"
-            with self._lock:
-                self.full_rebuilds += 1
-                self.rebuilt_bytes += rebuilt_b
-            metrics.STACK_CACHE.inc(outcome="rebuild")
-            metrics.STACK_MAINT_BYTES.inc(rebuilt_b, kind="rebuilt")
-        else:
-            with self._lock:
-                for pi, p in enumerate(ps.pages):
-                    if p is not None:
-                        local[pi] = p
-            by_page: dict[int, dict] = {}
-            for lane, runs in dirty.items():
-                by_page.setdefault(ps.page_of(lane)[0],
-                                   {})[lane] = runs
-            fresh: set[int] = set()
-            retained = ps.resident_bytes()
-            for pi in range(ps.n_pages):
-                if pi not in local:
-                    block = ps.build_page_host(pi, recipe.lane_words)
+        # named for the profiler while it runs; the stage that times
+        # it is the one around TileStackCache.get
+        with flight.annotate(
+                "stack_rebuild" if ps is None or dirty is None
+                else "stack_patch" if old_versions != versions
+                else "stack_page_rebuild"):
+            if ps is None or dirty is None:
+                if ps is not None:
+                    self._drop_pages(key, ps)
+                ps = PagedStack(shape, pl, weight=recipe.weight,
+                                lane_device=recipe.lane_device,
+                                shard_axis=recipe.shard_axis)
+                host = np.asarray(recipe.build_host(),
+                                  dtype=np.uint32).reshape(-1, w)
+                retained = 0
+                for pi in range(ps.n_pages):
+                    ids = ps.page_lane_ids(pi)
+                    block = host[ids]
+                    if block.shape[0] < pl:
+                        block = np.concatenate(
+                            [block, np.zeros((pl - block.shape[0], w),
+                                             np.uint32)])
                     local[pi] = self._commit_page(
                         block, key, device=self._page_jdev(ps, pi))
+                    # true encoded page bytes — both for the admission
+                    # cap and the maintenance-traffic attribution (a
+                    # packed page uploads its coordinates, not the dense
+                    # tile it stands for)
                     nb_pi = encode.page_nbytes(local[pi])
+                    rebuilt_b += nb_pi
                     if (retained + nb_pi <= resident_cap
                             and self._page_install(key, ps, pi,
                                                    local[pi])):
                         retained += nb_pi
-                    rebuilt_b += nb_pi
-                    fresh.add(pi)
-            for pi, lanes_d in by_page.items():
-                if pi in fresh:
-                    continue  # rebuilt from live rows: already current
-                pb, rb = self._patch_page(key, ps, pi, lanes_d,
-                                          recipe, local)
-                patched_b += pb
-                rebuilt_b += rb
-            stale_entry = old_versions != versions
-            if stale_entry:
-                outcome = "patch"
+                outcome = "rebuild"
                 with self._lock:
-                    self.patches += 1
-                    self.patched_bytes += patched_b
+                    self.full_rebuilds += 1
                     self.rebuilt_bytes += rebuilt_b
-                metrics.STACK_CACHE.inc(outcome="patch")
-                if patched_b:
-                    metrics.STACK_MAINT_BYTES.inc(patched_b,
-                                                  kind="patched")
-                if rebuilt_b:
-                    metrics.STACK_MAINT_BYTES.inc(rebuilt_b,
-                                                  kind="rebuilt")
+                metrics.STACK_CACHE.inc(outcome="rebuild")
+                metrics.STACK_MAINT_BYTES.inc(rebuilt_b, kind="rebuilt")
             else:
-                outcome = "page_rebuild"
                 with self._lock:
-                    self.page_rebuilds += 1
-                    self.rebuilt_bytes += rebuilt_b
-                metrics.STACK_CACHE.inc(outcome="page_rebuild")
-                if rebuilt_b:
-                    metrics.STACK_MAINT_BYTES.inc(rebuilt_b,
-                                                  kind="rebuilt")
+                    for pi, p in enumerate(ps.pages):
+                        if p is not None:
+                            local[pi] = p
+                by_page: dict[int, dict] = {}
+                for lane, runs in dirty.items():
+                    by_page.setdefault(ps.page_of(lane)[0],
+                                       {})[lane] = runs
+                fresh: set[int] = set()
+                retained = ps.resident_bytes()
+                for pi in range(ps.n_pages):
+                    if pi not in local:
+                        block = ps.build_page_host(pi, recipe.lane_words)
+                        local[pi] = self._commit_page(
+                            block, key, device=self._page_jdev(ps, pi))
+                        nb_pi = encode.page_nbytes(local[pi])
+                        if (retained + nb_pi <= resident_cap
+                                and self._page_install(key, ps, pi,
+                                                       local[pi])):
+                            retained += nb_pi
+                        rebuilt_b += nb_pi
+                        fresh.add(pi)
+                for pi, lanes_d in by_page.items():
+                    if pi in fresh:
+                        continue  # rebuilt from live rows: already current
+                    pb, rb = self._patch_page(key, ps, pi, lanes_d,
+                                              recipe, local)
+                    patched_b += pb
+                    rebuilt_b += rb
+                stale_entry = old_versions != versions
+                if stale_entry:
+                    outcome = "patch"
+                    with self._lock:
+                        self.patches += 1
+                        self.patched_bytes += patched_b
+                        self.rebuilt_bytes += rebuilt_b
+                    metrics.STACK_CACHE.inc(outcome="patch")
+                    if patched_b:
+                        metrics.STACK_MAINT_BYTES.inc(patched_b,
+                                                      kind="patched")
+                    if rebuilt_b:
+                        metrics.STACK_MAINT_BYTES.inc(rebuilt_b,
+                                                      kind="rebuilt")
+                else:
+                    outcome = "page_rebuild"
+                    with self._lock:
+                        self.page_rebuilds += 1
+                        self.rebuilt_bytes += rebuilt_b
+                    metrics.STACK_CACHE.inc(outcome="page_rebuild")
+                    if rebuilt_b:
+                        metrics.STACK_MAINT_BYTES.inc(rebuilt_b,
+                                                      kind="rebuilt")
         repl = None
         with self._lock:
             old = self._entries.get(key)
@@ -858,21 +872,23 @@ class TileStackCache:
                             lane_page=ps.lane_page,
                             lane_slot=ps.lane_slot,
                             shard_axis=ps.shard_axis)
-        if any(encode.is_encoded(a) for a in arrs):
-            # decode-to-dense boundary: this consumer needs the full
-            # tile operand (no packed arm for arbitrary plan nodes)
-            arrs = [encode.to_dense(a) for a in arrs]
-        if ps.page_table is not None:
-            # device-partitioned pages: single-array consumers pull
-            # everything to one device and undo the placement
-            # permutation (correct-but-slower fallback — the mesh
-            # program is the fast path)
-            return _assemble_permuted(arrs, ps.lane_page,
-                                      ps.lane_slot, ps.page_lanes,
-                                      ps.shape)
-        if len(arrs) == 1 and ps.lanes == ps.page_lanes:
-            return arrs[0].reshape(ps.shape)
-        return bm.assemble_pages(tuple(arrs), ps.shape)
+        with flight.stage("stack.assemble"):
+            if any(encode.is_encoded(a) for a in arrs):
+                # decode-to-dense boundary: this consumer needs the
+                # full tile operand (no packed arm for arbitrary plan
+                # nodes)
+                arrs = [encode.to_dense(a) for a in arrs]
+            if ps.page_table is not None:
+                # device-partitioned pages: single-array consumers
+                # pull everything to one device and undo the placement
+                # permutation (correct-but-slower fallback — the mesh
+                # program is the fast path)
+                return _assemble_permuted(arrs, ps.lane_page,
+                                          ps.lane_slot, ps.page_lanes,
+                                          ps.shape)
+            if len(arrs) == 1 and ps.lanes == ps.page_lanes:
+                return arrs[0].reshape(ps.shape)
+            return bm.assemble_pages(tuple(arrs), ps.shape)
 
     # -- budget / eviction ----------------------------------------------
 
@@ -1310,6 +1326,7 @@ def _groupby_onepass_jit(arm: str, has_planes: bool,
     if fn is not None:
         return fn
 
+    @bm.named("groupby_onepass")
     def run(cg, filt, planes):
         cp, valid = cg[:, :-1], cg[:, -1]
         if has_filter:
@@ -1354,6 +1371,7 @@ def _groupby_onepass_shard_map(mesh, arm: str, has_planes: bool,
     if has_planes:
         in_specs.append(P(axes, None, None))
 
+    @bm.named("groupby_onepass_mesh")
     def body(cg, *rest):
         filt = rest[0] if has_filter else None
         planes = rest[-1] if has_planes else None
@@ -1390,6 +1408,7 @@ def _groupby_kernel_shard_map(mesh, nf: int, has_planes: bool,
     if has_planes:
         in_specs = (stack_spec, P(None, None), P(axes, None, None))
 
+        @bm.named("groupby_percombo_mesh")
         def body(stacks, sel, planes):
             c, n, p, g = kernels.groupby_sum(
                 list(stacks), sel, planes, signed=signed)
@@ -1398,6 +1417,7 @@ def _groupby_kernel_shard_map(mesh, nf: int, has_planes: bool,
     else:
         in_specs = (stack_spec, P(None, None))
 
+        @bm.named("groupby_percombo_mesh")
         def body(stacks, sel):
             c, _n, _p, _g = kernels.groupby_sum(
                 list(stacks), sel, None, signed=signed)
@@ -1428,6 +1448,7 @@ def _groupby_kernel_jit(nf: int, has_planes: bool, signed: bool):
     key = (nf, has_planes, signed)
     fn = _gb_jit_get(key)
     if fn is None:
+        @bm.named("groupby_percombo")
         def run(stacks, sel, planes):
             c, n, p, g = kernels.groupby_sum(
                 list(stacks), sel, planes, signed=signed)
@@ -1478,14 +1499,16 @@ def _eval(node, leaves, params):
         planes = leaves[node[1]]                      # (S, P, W)
         fn = _BSI_CMP[node[2]]
         pb, neg = params[node[3]], params[node[4]]
-        return jax.vmap(fn, in_axes=(0, None, None))(planes, pb, neg)
+        with jax.named_scope("bsi_compare"):
+            return jax.vmap(fn, in_axes=(0, None, None))(planes, pb, neg)
     if k == "bsi_between":
         planes = leaves[node[1]]
         ab, bb = params[node[2]], params[node[3]]
         an, bn = params[node[4]], params[node[5]]
-        return jax.vmap(bsi_ops.range_between,
-                        in_axes=(0, None, None, None, None))(
-            planes, ab, bb, an, bn)
+        with jax.named_scope("bsi_compare"):
+            return jax.vmap(bsi_ops.range_between,
+                            in_axes=(0, None, None, None, None))(
+                planes, ab, bb, an, bn)
     if k == "bsi_notnull":
         return leaves[node[1]][:, 0]                  # exists plane
     if k == "bsi_null":
@@ -1505,6 +1528,21 @@ def _as_stack(out, leaves):
     return out
 
 
+def _filter(tree, leaves, params):
+    """A sub-plan's filter tree as an (S, W) stack, under the `filter`
+    scope of a profile."""
+    with jax.named_scope("filter"):
+        return _as_stack(_eval(tree, leaves, params), leaves)
+
+
+# the jax.named_scope of each sub-plan kind inside a plan program: op
+# metadata for a reader of the profile (XProf, Perfetto), and the name
+# of any op XLA does not fuse
+_SCOPES = {"words": "filter", "count": "count", "bsi_sum": "bsi_sum",
+           "gb_hist": "groupby", "groupby": "groupby",
+           "row_counts": "topn"}
+
+
 def _count_partials(tree, kern: bool):
     """(S,) per-shard popcounts of a tree.  With kernels enabled and
     every operand device-RESIDENT (a leaf — exactly the no-producer-
@@ -1521,7 +1559,7 @@ def _count_partials(tree, kern: bool):
         return lambda leaves, params: kernels.pair_popcount(
             leaves[i], leaves[j])
     return lambda leaves, params: bm.count(
-        _as_stack(_eval(tree, leaves, params), leaves))
+        _filter(tree, leaves, params))
 
 
 def _plan_run(plan, kern: bool = False):
@@ -1560,22 +1598,24 @@ def _plan_run(plan, kern: bool = False):
 
         def run(leaves, params):
             flats = []
-            for start, npages in buckets:
-                ps = leaves[start:start + npages]
-                flats.append(jnp.concatenate(ps, axis=0)
-                             if npages > 1 else ps[0])
             vl = []
-            for b, gi, n, shape in vmeta:
-                g = flats[b][params[gi]]        # (Lpad, W) gather
-                vl.append(g[:n].reshape(shape))
+            with jax.named_scope("page_gather"):
+                for start, npages in buckets:
+                    ps = leaves[start:start + npages]
+                    flats.append(jnp.concatenate(ps, axis=0)
+                                 if npages > 1 else ps[0])
+                for b, gi, n, shape in vmeta:
+                    g = flats[b][params[gi]]    # (Lpad, W) gather
+                    vl.append(g[:n].reshape(shape))
             all_leaves = tuple(vl) + tuple(leaves[ndirect:])
             outs = []
             for s, r in zip(subs, runs):
                 if r is None:
                     _k, b, gi, si, nseg = s
-                    lanes = flats[b][params[gi]]
-                    outs.append(bm.segment_count(lanes, params[si],
-                                                 nseg))
+                    with jax.named_scope("count"):
+                        lanes = flats[b][params[gi]]
+                        outs.append(bm.segment_count(
+                            lanes, params[si], nseg))
                 else:
                     outs.append(r(all_leaves, params))
             return tuple(outs)
@@ -1686,7 +1726,7 @@ def _plan_run(plan, kern: bool = False):
                     cnt, pos, neg = jax.vmap(
                         kernels.bsi_sum_counts)(planes, filt)
                 else:
-                    filt = _as_stack(_eval(tree, leaves, params), leaves)
+                    filt = _filter(tree, leaves, params)
                     cnt, pos, neg = jax.vmap(
                         bsi_ops.sum_counts)(planes, filt)
             if reduce_:
@@ -1709,7 +1749,7 @@ def _plan_run(plan, kern: bool = False):
             cg = leaves[cg_i]                     # (S, CB+1, W)
             cp, valid = cg[:, :-1], cg[:, -1]
             if tree is not None:
-                filt = _as_stack(_eval(tree, leaves, params), leaves)
+                filt = _filter(tree, leaves, params)
                 valid = jnp.bitwise_and(valid, filt)
             planes = leaves[planes_i] if planes_i is not None else None
             c, n, p, g = _onepass_gb(arm)(cp, valid, planes, n_codes,
@@ -1738,7 +1778,7 @@ def _plan_run(plan, kern: bool = False):
             sel_all = params[-1]                      # (n_chunks, C, nf)
             filt = None
             if tree is not None:
-                filt = _as_stack(_eval(tree, leaves, params), leaves)
+                filt = _filter(tree, leaves, params)
 
             def chunk_body(carry, sel):               # sel: (C, nf)
                 m = leaves[stack_is[0]][sel[:, 0]]    # (C, S, W)
@@ -1796,15 +1836,21 @@ def _plan_run(plan, kern: bool = False):
             elif kern and tree[0] == "leaf":
                 c = kernels.rows_filter_counts(rows, leaves[tree[1]])
             else:
-                filt = _as_stack(_eval(tree, leaves, params), leaves)
+                filt = _filter(tree, leaves, params)
                 c = bm.count(jnp.bitwise_and(rows, filt[None]))
             return jnp.sum(c, axis=1) if reduce_ else c
     else:
         raise AssertionError(kind)
-    return run
+    scope = _SCOPES[kind]
+
+    def scoped(leaves, params):
+        with jax.named_scope(scope):
+            return run(leaves, params)
+    return scoped
 
 
-def _compiled(plan, kern: bool = False, sig: tuple | None = None):
+def _compiled(plan, kern: bool = False, sig: tuple | None = None,
+              name: str | None = None):
     """plan: ("words", tree) | ("count", tree, reduce)
     | ("bsi_sum", planes_i, tree|None, reduce)
     | ("row_counts", rows_i, tree|None, reduce)
@@ -1816,14 +1862,19 @@ def _compiled(plan, kern: bool = False, sig: tuple | None = None):
     reduce=True the cross-shard sum happens IN the program — under a
     mesh it lowers to a psum over ICI (the jitted analog of
     mapReduce's reduceFn); int32-exact up to _REDUCE_MAX_SHARDS
-    shards, the caller's responsibility."""
+    shards, the caller's responsibility.  The program is named for
+    its plan KIND (`jit_plan_count`, `jit_plan_ragged` on the
+    profiler's XLA Modules line; `name` lets the ragged plane tell its
+    extras program from the canonical one) — never for the plan's
+    contents, so naming adds no program."""
     sig = (repr(plan), kern) if sig is None else sig
     with _JIT_LOCK:
         ent = _JIT_CACHE.get(sig)
         if ent is not None:
             _JIT_CACHE.move_to_end(sig)
             return ent[0]
-    fn = jax.jit(_plan_run(plan, kern))
+    fn = jax.jit(bm.named(name or "plan_" + plan[0])(
+        _plan_run(plan, kern)))
     client = _jit_client()
     reserved = (_JIT_EST_BYTES
                 if client.reserve(_JIT_EST_BYTES) else 0)
@@ -1896,6 +1947,26 @@ def _block(out):
     return jax.block_until_ready(out)
 
 
+def dispatch_ready(fn, *args):
+    """Call a jitted program and wait for its result: the call until
+    it RETURNS is the `dispatch` stage (the host's share: argument
+    handling, a first call's trace and compile, the enqueue), the
+    rest of the enclosing `execute` / `compile` stage is the device."""
+    with flight.stage("dispatch"):
+        out = fn(*args)
+    return _block(out)
+
+
+def timed_call(kind: str, fn, *args):
+    """(ready result, seconds) of one program call as the stage `kind`
+    ('execute' | 'compile', from _dispatch_kind) over its `dispatch`
+    child — for the engine's own jitted programs, which do not go
+    through timed_dispatch."""
+    with flight.stage(kind) as st:
+        out = dispatch_ready(fn, *args)
+    return out, st.seconds
+
+
 # plan kind -> roofline op family (obs/roofline.py): the per-op
 # labels behind pilosa_device_bandwidth_{gbps,fraction}{op}
 _ROOF_OPS = {"count": "count", "words": "row", "row_counts": "topn",
@@ -1940,15 +2011,13 @@ def timed_dispatch(plan, kern, leaves, params):
     fn = _compiled(plan, kern=kern, sig=sig)
     kind = _dispatch_kind(sig, leaves, params)
     oom0 = metrics.OOM_TOTAL.total(outcome="caught")
-    t0 = time.perf_counter()
-    with start_span("stacked.dispatch", kind=plan[0],
-                    compile=kind == "compile"):
+    with flight.stage(kind, kind=plan[0],
+                      compile=kind == "compile") as st:
         out = pressure.guarded(
-            lambda: _block(fn(tuple(leaves), tuple(params))),
+            lambda: dispatch_ready(fn, tuple(leaves), tuple(params)),
             host_fallback=lambda: pressure.run_host_plan(
                 plan, leaves, params))
-    dt = time.perf_counter() - t0
-    flight.note_phase(kind, dt)
+    dt = st.seconds
     if kind == "execute" and \
             metrics.OOM_TOTAL.total(outcome="caught") == oom0:
         # roofline attribution: operand bytes touched / device time,
@@ -2668,22 +2737,12 @@ class StackedEngine:
             builder.leaves, builder.params)
 
     def _build_timed(self, builder, call):
-        """PlanBuilder.build with plan-build attribution.  Stack/leaf
-        fetches inside the walk are attributed by TileStackCache.get
-        itself, so their share is subtracted here — plan_build is the
-        pure tree-walk cost."""
-        acc = flight.active_acc()
-        stack0 = (sum(v for k, v in acc.phases.items()
-                      if k.startswith("stack_")) if acc else 0.0)
-        t0 = time.perf_counter()
-        with start_span("stacked.plan_build", call=call.name):
-            tree = builder.build(call)
-        dt = time.perf_counter() - t0
-        if acc is not None:
-            dt -= sum(v for k, v in acc.phases.items()
-                      if k.startswith("stack_")) - stack0
-        flight.note_phase("plan_build", max(dt, 0.0))
-        return tree
+        """PlanBuilder.build as the `plan_build` stage.  The stack/
+        leaf fetches inside the walk are stages of their own
+        (TileStackCache.get), children of this one: the pure
+        tree-walk cost is this span's self time."""
+        with flight.stage("plan_build", call=call.name):
+            return builder.build(call)
 
     def _reduce_in_program(self, shards) -> bool:
         """In-program (ICI-collective) cross-shard reduce is int32-
@@ -2900,7 +2959,7 @@ class StackedEngine:
         planes_i = b._planes_leaf(field)
         tree = None
         if filter_call is not None:
-            tree = b.build(filter_call)
+            tree = self._build_timed(b, filter_call)
             if tree == ("zeros",):
                 return 0, 0
         red = self._reduce_in_program(shards)
@@ -2967,6 +3026,7 @@ class StackedEngine:
             key = ("vhist", arm, filt is not None, depth, n_codes)
             fn = _gb_jit_get(key)
             if fn is None:
+                @bm.named("vhist")
                 def run(planes, filt):
                     # the planes-to-code layout lives in ONE place —
                     # kernels.bsi_value_hist; only the arm varies here
@@ -2979,11 +3039,8 @@ class StackedEngine:
             fd = jnp.asarray(filt) if filt is not None else None
             kind = _dispatch_kind(key, [planes] + (
                 [fd] if fd is not None else []), ())
-            t0 = time.perf_counter()
-            counts = np.asarray(_block(fn(planes, fd)),
-                                dtype=np.int64)
-            dt = time.perf_counter() - t0
-            flight.note_phase(kind, dt)
+            out, dt = timed_call(kind, fn, planes, fd)
+            counts = np.asarray(out, dtype=np.int64)
             if kind == "execute":
                 roofline.note("vhist", op_bytes, dt)
         pos_h, neg_h = counts[: 1 << depth], counts[1 << depth:]
@@ -3046,7 +3103,8 @@ class StackedEngine:
             rows_stack = _expand_view(rows_stack)
         b = PlanBuilder(self, idx, shards, pre)
         rows_i = b._add_leaf(rows_stack)
-        tree = b.build(filter_call) if filter_call is not None else None
+        tree = (self._build_timed(b, filter_call)
+                if filter_call is not None else None)
         if tree == ("zeros",):
             return np.zeros(rows_stack.shape[0], dtype=np.int64)
         red = self._reduce_in_program(shards)
@@ -3327,7 +3385,7 @@ class StackedEngine:
         filt = None
         if filter_call is not None:
             b0 = PlanBuilder(self, idx, list(skey), pre)
-            tree0 = b0.build(filter_call)
+            tree0 = self._build_timed(b0, filter_call)
             if tree0 == ("zeros",):
                 return _zero_groupby_result(len(combos), depth,
                                             agg_field, agg_op)
@@ -3380,10 +3438,7 @@ class StackedEngine:
             sig = ("onepass_mesh", arm, has_planes, filt is not None,
                    signed, n_codes)
             kind = _dispatch_kind(sig, args, ())
-            t0 = time.perf_counter()
-            out = _block(fn(*args))
-            dt = time.perf_counter() - t0
-            flight.note_phase(kind, dt)
+            out, dt = timed_call(kind, fn, *args)
             if kind == "execute":
                 roofline.note("groupby", op_bytes, dt)
             counts, nn, pos, neg = _onepass_unpack(
@@ -3411,10 +3466,7 @@ class StackedEngine:
                    signed, n_codes, minmax)
             args = [a for a in (cg, filt, planes) if a is not None]
             kind = _dispatch_kind(sig, args, ())
-            t0 = time.perf_counter()
-            out = _block(fn(cg, filt, planes))
-            dt = time.perf_counter() - t0
-            flight.note_phase(kind, dt)
+            out, dt = timed_call(kind, fn, cg, filt, planes)
             if kind == "execute":
                 roofline.note("groupby", op_bytes, dt)
             out = _onepass_unpack(out, n_codes, depth, has_planes,
@@ -3559,13 +3611,9 @@ class StackedEngine:
             kind = _dispatch_kind(
                 sig, stacks + ([planes] if planes is not None else []),
                 (sel,))
-            t0 = time.perf_counter()
-            if planes is None:
-                out = _block(fn(tuple(stacks), sel))
-            else:
-                out = _block(fn(tuple(stacks), sel, planes))
-            dt = time.perf_counter() - t0
-            flight.note_phase(kind, dt)
+            out, dt = timed_call(
+                kind, fn, tuple(stacks), sel,
+                *(() if planes is None else (planes,)))
             if kind == "execute":
                 roofline.note("groupby", op_bytes, dt)
             return self._groupby_kernel_unpack(out, len(combos),
@@ -3606,11 +3654,12 @@ class StackedEngine:
                        agg_field is not None, signed)
                 args = list(stacks) + (
                     [planes] if planes is not None else [])
-                if _dispatch_kind(sig, args, (sel,)) == "compile":
+                kind = _dispatch_kind(sig, args, (sel,))
+                if kind == "compile":
                     compiled_any = True
-                t0 = time.perf_counter()
-                out = _block(fn(tuple(stacks), sel, planes))
-                dispatch_s += time.perf_counter() - t0
+                out, dt = timed_call(kind, fn, tuple(stacks), sel,
+                                     planes)
+                dispatch_s += dt
                 kc = sel.shape[0]
                 c, a = self._groupby_kernel_unpack(out, kc, depth,
                                                    agg_field)
@@ -3619,8 +3668,6 @@ class StackedEngine:
                     agg[0][clo:clo + kc] += a[0]
                     agg[1][clo:clo + kc] += a[1]
                     agg[2][clo:clo + kc] += a[2]
-        flight.note_phase("compile" if compiled_any else "execute",
-                          dispatch_s)
         if not compiled_any:
             roofline.note("groupby", op_bytes, dispatch_s)
         return counts, agg
@@ -3735,7 +3782,7 @@ class StackedEngine:
                 # combo intersection, so one mask filters counts and
                 # aggregates alike (r04 guard lift)
                 b0 = PlanBuilder(self, idx, list(skey), pre)
-                tree0 = b0.build(filter_call)
+                tree0 = self._build_timed(b0, filter_call)
                 if tree0 == ("zeros",):
                     return _zero_groupby_result(n_combos, depth,
                                                 agg_field)
@@ -3765,7 +3812,7 @@ class StackedEngine:
             planes_i = b._planes_leaf(agg_field)
         tree = None
         if filter_call is not None:
-            tree = b.build(filter_call)
+            tree = self._build_timed(b, filter_call)
             if tree == ("zeros",):
                 return _zero_groupby_result(n_combos, depth, agg_field)
         red = self._reduce_in_program(skey)

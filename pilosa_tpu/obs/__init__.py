@@ -16,7 +16,6 @@ from pilosa_tpu.obs.metrics import (
 )
 from pilosa_tpu.obs.tracing import (
     NopTracer,
-    ProfiledSpan,
     RecordingTracer,
     Span,
     TraceContext,
@@ -42,7 +41,6 @@ __all__ = [
     "NopTracer",
     "RecordingTracer",
     "Span",
-    "ProfiledSpan",
     "TraceContext",
     "capture_context",
     "span_into",

@@ -59,7 +59,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 
-from pilosa_tpu.obs import faults, incidents, metrics
+from pilosa_tpu.obs import faults, flight, incidents, metrics
 from pilosa_tpu.obs.monitor import capture_exception
 
 # -- module config (the [audit] knobs; apply_audit_settings() writes
@@ -407,9 +407,16 @@ class AuditPlane:
             self._mismatch(s, d, got)
 
     def _shadow_exec(self, s: _Sample):
-        if s.sql is not None:
-            return self._sql_oracle_engine().query_one(s.sql)
-        return self.oracle().execute(s.index, s.q, s.shards)
+        # the whole run is one `audit.shadow` stage of the run's own
+        # flight record (the oracle's `solo` record, opened inside;
+        # the request envelope carries the stage to it).  A wait in
+        # flight's vocabulary: it lasts seconds at 954 shards, and
+        # annotated it would name every device-idle gap inside it —
+        # its `audit.step` children name the ones they cause.
+        with flight.request(), flight.stage("audit.shadow"):
+            if s.sql is not None:
+                return self._sql_oracle_engine().query_one(s.sql)
+            return self.oracle().execute(s.index, s.q, s.shards)
 
     def oracle(self):
         """The independent verification arm: a private Executor with
@@ -418,8 +425,7 @@ class AuditPlane:
         sparse device fast paths, no result cache."""
         with self._oracle_lock:
             if self._oracle is None:
-                from pilosa_tpu.executor.executor import Executor
-                o = Executor(self.serving.executor.holder)
+                o = _oracle_executor(self.serving.executor.holder)
                 o.use_stacked = False
                 self._oracle = o
             return self._oracle
@@ -616,6 +622,39 @@ class AuditPlane:
             "scrub": dict(self.scrub_stats),
             "tracked_keys": len(self._keys),
         }
+
+
+def _oracle_executor(holder):
+    """The oracle arm's Executor, each STEP of its host loop — one
+    shard's bitmap tree, one eager JAX op per node — an `audit.step`
+    stage: annotated into the profiler's host plane (the shadow run
+    shares the GIL with the serving threads, so a device-idle gap
+    under it reads `host: audit.step`) and summed into
+    pilosa_audit_shadow_seconds_total."""
+    from pilosa_tpu.executor.executor import Executor
+
+    class InStep(threading.local):
+        on = False
+
+    class OracleExecutor(Executor):
+        _in_step = InStep()
+
+        def _bitmap_call_shard(self, idx, call, shard, pre):
+            tls = self._in_step
+            if tls.on:                       # an inner node of a step
+                return super()._bitmap_call_shard(idx, call, shard,
+                                                  pre)
+            tls.on = True
+            st = flight.stage("audit.step")
+            try:
+                with st:
+                    return super()._bitmap_call_shard(idx, call,
+                                                      shard, pre)
+            finally:
+                tls.on = False
+                metrics.AUDIT_SHADOW_SECONDS.inc(st.seconds)
+
+    return OracleExecutor(holder)
 
 
 def _fp(key) -> str:

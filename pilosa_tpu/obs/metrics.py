@@ -609,6 +609,12 @@ AUDIT_TOTAL = registry.counter(
     "standing/replica) and outcome (sampled/match/mismatch/"
     "stale_skip/shed/unguarded/repaired/error)")
 
+AUDIT_SHADOW_SECONDS = registry.counter(
+    "pilosa_audit_shadow_seconds_total",
+    "Seconds the audit plane's shadow executor ran on its background "
+    "thread (the sum of its audit.step stages) — host time, and the "
+    "GIL, that the serving threads did not have")
+
 # -- SLO burn-rate plane (obs/slo.py) --
 SLO_BURN_RATE = registry.gauge(
     "pilosa_slo_burn_rate",

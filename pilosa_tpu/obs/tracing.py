@@ -59,8 +59,6 @@ class Span:
         return d
 
 
-ProfiledSpan = Span  # profiled spans are plain spans kept in a tree
-
 
 class Tracer:
     """Records a span tree per thread.  Subclass or use as-is."""
@@ -119,8 +117,14 @@ class _NopSpan(Span):
     def finish(self):
         pass
 
+class _Tls(threading.local):
+    # class-level default: reading it on a thread that never pushed a
+    # tracer is a plain attribute load, not a caught AttributeError
+    tracer = None
+
+
 _global = NopTracer()
-_tls = threading.local()
+_tls = _Tls()
 
 
 def set_tracer(t: Tracer):
@@ -133,6 +137,20 @@ def get_tracer() -> Tracer:
     wins over the process-global tracer."""
     t = getattr(_tls, "tracer", None)
     return t if t is not None else _global
+
+
+def recording_tracer(nested: bool = False) -> Tracer | None:
+    """The active tracer when it records, else None — the one check a
+    flight stage (obs/flight.py) pays on the untraced hot path.
+    `nested`: only when a span is open on this thread — a stage joins
+    a tree, it never roots one (``profile[0]`` stays
+    ``executor.Execute`` whatever ran before it)."""
+    t = getattr(_tls, "tracer", None)
+    if t is None:
+        t = _global
+    if isinstance(t, NopTracer) or (nested and not t._stack()):
+        return None
+    return t
 
 
 def push_thread_tracer(t: Tracer) -> Tracer | None:
@@ -275,19 +293,41 @@ def span_from_wire(d: dict, anchor: float) -> Span:
 
 
 @contextmanager
-def span_into(ctx: TraceContext | None, name: str, **tags):
+def span_into(ctx, name: str, **tags):
     """Open a span on THIS thread that records (with everything
-    start_span() nests inside it) into `ctx`'s tree.  With ctx=None
+    start_span() nests inside it) into `ctx`'s tree — or, given a
+    list of contexts, into each of their trees.  With ctx=None
     the body is SILENCED, not left on the thread's own tracer: a
     traced batch leader serving an untraced follower must not adopt
     the follower's inner spans (stack fetches etc.) into its own
     profile tree."""
-    if ctx is None:
+    shared = None
+    if isinstance(ctx, (list, tuple)):
+        # one interval a batch leader runs for SEVERAL riders (the
+        # fused dispatch): recorded once on a private tracer, with
+        # whatever nests inside it, and grafted into every traced
+        # rider's tree as its own copy
+        shared = [c for c in ctx if c is not None]
+        ctx = None
+    if ctx is None and not shared:
         prev = push_thread_tracer(_NOP_TRACER)
         try:
             yield _NopSpan()
         finally:
             pop_thread_tracer(prev)
+        return
+    if shared:
+        t = Tracer()
+        prev = push_thread_tracer(t)
+        s = None
+        try:
+            with t.span(name, **tags) as s:
+                yield s
+        finally:
+            pop_thread_tracer(prev)
+            if s is not None:
+                for c in shared:
+                    c.attach(s.copy())
         return
     t = _AttachTracer(ctx)
     prev = push_thread_tracer(t)

@@ -251,7 +251,20 @@ def patch_rows_np(stack2d: np.ndarray, idxs, starts,
 from functools import partial as _partial
 
 
+def named(name: str):
+    """Decorator: the stable name a function carries into a profile.
+    jax.jit names its program after the function (`jit_<name>` on the
+    profiler's XLA Modules line) and an op outside every
+    jax.named_scope takes it too — so a closure called `run` is given
+    its plan kind's name before it is jitted, never its contents'."""
+    def deco(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return deco
+
+
 @_partial(jax.jit, static_argnums=(1,))
+@named("assemble_pages")
 def _assemble_pages_jit(pages, shape: tuple):
     n_lanes = 1
     for d in shape[:-1]:

@@ -128,6 +128,7 @@ def popcount_rows(x):
         in_specs=[pl.BlockSpec((_ROW_BLOCK, bw), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+        name="popcount_rows",
         interpret=_interpret(),
     )(x)
     return out[:n, 0]
@@ -169,6 +170,7 @@ def pair_popcount(a, b):
         in_specs=[spec, spec],
         out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+        name="pair_popcount",
         interpret=_interpret(),
     )(a, b)
     return out[:n, 0]
@@ -210,6 +212,7 @@ def masked_popcount(x, mask):
         ],
         out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+        name="masked_popcount",
         interpret=_interpret(),
     )(x, mask.reshape(1, w))
     return out[:n, 0]
@@ -274,6 +277,7 @@ def bsi_sum_counts(planes, filter_words=None):
             jax.ShapeDtypeStruct((depth, 1), jnp.int32),
             jax.ShapeDtypeStruct((depth, 1), jnp.int32),
         ],
+        name="bsi_sum_counts",
         interpret=_interpret(),
     )(planes, filter_words.reshape(1, w))
     return cnt[0, 0], pos[:, 0], neg[:, 0]
@@ -332,6 +336,7 @@ def rows_filter_counts(rows, filt):
             ],
             out_specs=pl.BlockSpec((bs, r), lambda s, j: (s, 0)),
             out_shape=jax.ShapeDtypeStruct((spad, r), jnp.int32),
+            name="rows_filter_counts",
             interpret=_interpret(),
         )(chunk, filt)
         out.append(rc[:s_dim].T)
@@ -467,6 +472,7 @@ def groupby_sum(stacks, sel, planes=None, signed=True):
             out_specs=out_specs,
         ),
         out_shape=out_shape,
+        name="groupby_sum",
         interpret=_interpret(),
     )(sel, *arrays)
     if planes is None:
@@ -700,6 +706,7 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((k, g_pad), lambda s, w: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, g_pad), jnp.int32),
+        name="groupby_onehot",
         interpret=_interpret(),
     )(*arrays)
     counts = out[0, :n_codes]
@@ -887,6 +894,8 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
         in_specs=in_specs,
         out_specs=out_specs if minmax else out_specs[0],
         out_shape=out_shape if minmax else out_shape[0],
+        name=("groupby_fused_minmax" if minmax
+              else "groupby_fused_sum"),
         interpret=_interpret(),
     )(*arrays)
     hist = out[0] if minmax else out

@@ -34,7 +34,7 @@ from urllib.parse import parse_qs, urlparse
 
 from pilosa_tpu.api import API, ApiError
 from pilosa_tpu.models.holder import Holder
-from pilosa_tpu.obs import metrics
+from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs.logger import Logger, NopLogger
 
 
@@ -568,7 +568,6 @@ class Server:
                                   from an audit-mismatch incident
                                   bundle to the query's full trace
         """
-        from pilosa_tpu.obs import flight
         q = req.query
         limit = int(q.get("limit", q.get("n", ["100"]))[0])
         # scan the whole ring, filter, then truncate — "matched" is
@@ -610,7 +609,6 @@ class Server:
         """Recent flight records as Chrome trace_event JSON — save
         the body and open it in Perfetto (ui.perfetto.dev) or
         chrome://tracing."""
-        from pilosa_tpu.obs import flight
         n = int(req.query.get("n", ["100"])[0])
         return RawResponse(flight.recorder.chrome_trace_json(n),
                            "application/json")
@@ -752,15 +750,16 @@ class Server:
     # -- handlers ------------------------------------------------------
 
     def _post_query(self, req):
-        body = req.json_lenient()
         remote = False
-        if body is not None:
-            pql = body.get("query", "")
-            shards = body.get("shards")
-            remote = bool(body.get("remote"))
-        else:  # raw PQL body, like the reference's text/plain mode
-            pql = req.text()
-            shards = None
+        with flight.stage("http.read"):     # the decode half
+            body = req.json_lenient()
+            if body is not None:
+                pql = body.get("query", "")
+                shards = body.get("shards")
+                remote = bool(body.get("remote"))
+            else:  # raw PQL body, like the reference's text/plain mode
+                pql = req.text()
+                shards = None
         profile = req.query.get("profile", ["false"])[0] == "true"
         trace_id = req.headers.get("X-Pilosa-Trace-Id")
         if trace_id is None:
@@ -775,7 +774,6 @@ class Server:
         # same thread-tracer machinery Profile=true uses — and the
         # serialized tree returns in the response's "trace" trailer
         # for the coordinator's per-node Perfetto lanes.
-        from pilosa_tpu.obs import flight
         parent = req.headers.get("X-Pilosa-Span-Parent", "")
         node = getattr(self.api, "name", "") or "local"
         with flight.remote_leg(trace_id) as (tracer, spans):
@@ -1113,7 +1111,6 @@ class Server:
         return {"standard": self.api.shard_max()}
 
     def _get_metrics(self, req):
-        from pilosa_tpu.obs import flight
         flight.flush_metrics()  # drain buffered phase samples first
         # exemplars are EXPLICITLY opt-in (?exemplars=1): the classic
         # 0.0.4 text parser fails the whole scrape on a mid-line '#',
@@ -1129,7 +1126,6 @@ class Server:
                            "text/plain; version=0.0.4")
 
     def _get_metrics_json(self, req):
-        from pilosa_tpu.obs import flight
         flight.flush_metrics()  # JSON scrapes see current data too
         return metrics.registry.render_json()
 
@@ -1224,14 +1220,20 @@ def _make_handler(server: Server):
 
         # dispatch --------------------------------------------------------
         def _handle(self, method: str):
-            u = urlparse(self.path)
-            self.query = parse_qs(u.query)
-            # always drain the body: unread bytes on a keep-alive
-            # connection would be parsed as the next request line
-            self._raw = self._body()
-            self.extra_headers = {}  # reset across keep-alive requests
-            status, result = server.dispatch(method, u.path, self)
-            self._send(status, result)
+            # the request envelope (obs/flight.py): the stages outside
+            # the query's flight record — body read, decode, encode,
+            # socket write — reach that record, and its request_ms is
+            # this block, first byte read to last byte written
+            with flight.request():
+                u = urlparse(self.path)
+                self.query = parse_qs(u.query)
+                # always drain the body: unread bytes on a keep-alive
+                # connection would be parsed as the next request line
+                with flight.stage("http.read"):
+                    self._raw = self._body()
+                self.extra_headers = {}  # reset across keep-alive
+                status, result = server.dispatch(method, u.path, self)
+                self._send(status, result)
             metrics.HTTP_REQUESTS.inc(
                 method=method, path=u.path.split("/")[1] or "/",
                 status=str(status))
@@ -1242,15 +1244,17 @@ def _make_handler(server: Server):
                         else result.body.encode())
                 ctype = result.content_type
             else:
-                body = json.dumps(result).encode()
+                with flight.stage("result.encode"):
+                    body = json.dumps(result).encode()
                 ctype = "application/json"
-            self.send_response(status)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(body)))
-            for k, v in getattr(self, "extra_headers", {}).items():
-                self.send_header(k, v)
-            self.end_headers()
-            self.wfile.write(body)
+            with flight.stage("http.write"):
+                self.send_response(status)
+                self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(body)))
+                for k, v in getattr(self, "extra_headers", {}).items():
+                    self.send_header(k, v)
+                self.end_headers()
+                self.wfile.write(body)
 
         def do_GET(self):
             self._handle("GET")

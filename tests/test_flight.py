@@ -255,6 +255,298 @@ def test_phase_histogram_exemplars(holder):
 
 
 # ---------------------------------------------------------------------------
+# stages: one call feeds phases, spans, the Profile tree and the profiler
+# ---------------------------------------------------------------------------
+
+def _check_spans(rec):
+    """The invariants of a record's `spans`: offsets monotone per
+    thread in list order, children inside their parents, and per name
+    the spans sum to `phases`."""
+    spans = rec["spans"]
+    last = {}
+    for name, off, dur, parent, thread in spans:
+        assert dur is not None and dur >= 0, (name, dur)
+        assert off >= last.get(thread, -1e18), (name, off, spans)
+        last[thread] = off
+    eps = 0.01  # ms: offsets and durations are rounded to 1e-4 each
+    for i, (name, off, dur, parent, _thr) in enumerate(spans):
+        assert -1 <= parent < i, (name, parent)
+        if parent >= 0:
+            _pn, poff, pdur, _pp, _pt = spans[parent]
+            assert poff - eps <= off and off + dur <= poff + pdur + eps, \
+                (name, spans[parent], spans[i])
+    sums = {}
+    for name, _off, dur, _p, _t in spans:
+        sums[name] = sums.get(name, 0.0) + dur
+    for name, total in sums.items():
+        if name in rec["phases"]:
+            assert abs(total - rec["phases"][name]) <= 0.01 + 1e-3 * total, \
+                (name, total, rec["phases"][name])
+    return [n for n, *_ in spans]
+
+
+def test_served_record_spans_cover_the_request():
+    """Over HTTP the record holds every stage from the socket to the
+    socket: the envelope's stages sit before `start` and past
+    `duration_ms`, and request_ms covers the whole."""
+    from pilosa_tpu.server.http import Server
+
+    flight.recorder.configure(enabled=True)
+    srv = Server().start()
+    try:
+        _req(srv.port, "POST", "/index/sp", {})
+        _req(srv.port, "POST", "/index/sp/field/f", {})
+        _req(srv.port, "POST", "/index/sp/field/g", {})
+        for c in range(40):
+            _req(srv.port, "POST", "/index/sp/query",
+                 {"query": f"Set({c}, f={c % 3}) Set({c}, g={c % 5})"})
+        q = "Count(Intersect(Row(f=1), Row(g=2)))"
+        _req(srv.port, "POST", "/index/sp/query", {"query": q})
+        _req(srv.port, "POST", "/index/sp/query", {"query": q})  # a hit
+        time.sleep(0.05)    # the handler appends the tail after the reply
+        st, d = _req(srv.port, "GET", "/debug/queries?n=50")
+        recs = [r for r in d["queries"] if r["query"].startswith("Count")]
+        hit, served = recs[0], recs[1]
+    finally:
+        srv.close()
+    assert served["route"] in ("fused", "direct")
+    names = _check_spans(served)
+    for want in ("http.read", "pql.parse", "admission.classify",
+                 "cache_lookup", "batch", "batch.wait", "plan_build",
+                 "dispatch", "demux", "result.encode", "http.write"):
+        assert want in names, (want, names)
+    assert "execute" in names or "compile" in names
+    assert served["request_ms"] >= served["duration_ms"]
+    by = {n: (off, dur) for n, off, dur, _p, _t in served["spans"]}
+    assert by["http.read"][0] < 0 and by["pql.parse"][0] < 0
+    assert by["http.write"][0] >= served["duration_ms"] - 0.01
+    assert by["http.write"][0] + by["http.write"][1] <= \
+        served["request_ms"] + by["http.read"][0] + 0.5
+    # the envelope's stages are spans alone: `phases` keeps its keys
+    assert "http.read" not in served["phases"]
+    # dispatch is the host's share of its execute/compile parent
+    i = names.index("dispatch")
+    assert served["spans"][served["spans"][i][3]][0] in ("execute",
+                                                         "compile")
+    # a cache hit never executes, and its record says so
+    assert hit["route"] == "cached"
+    hit_names = _check_spans(hit)
+    assert "cache_lookup" in hit_names and "http.write" in hit_names
+    for absent in ("execute", "compile", "dispatch", "plan_build",
+                   "batch"):
+        assert absent not in hit_names and absent not in hit["phases"]
+    assert hit["request_ms"] >= hit["duration_ms"]
+
+
+def test_follower_record_carries_leader_stages(holder):
+    """A request fused into another thread's batch shows the leader's
+    stages in ITS record, with the leader's thread."""
+    ex = Executor(holder)
+    ex.enable_serving(window_s=0.05, max_batch=64, cache_bytes=0)
+    flight.recorder.configure(enabled=True)
+    queries = [f"Count(Intersect(Row(a={i % 3}), Row(b={i % 5})))"
+               for i in range(8)]
+    found = None
+    for _attempt in range(4):
+        flight.recorder.clear()
+        barrier = threading.Barrier(len(queries))
+
+        def run(q):
+            barrier.wait()
+            ex.execute_serving("i", q)
+
+        threads = [threading.Thread(target=run, args=(q,))
+                   for q in queries]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+        for rec in flight.recorder.recent(50):
+            if rec["route"] != "fused" or rec["batch"] < 2:
+                continue
+            _check_spans(rec)
+            own = next(t for n, _o, _d, _p, t in rec["spans"]
+                       if n == "batch")
+            lead = [(n, t) for n, _o, _d, _p, t in rec["spans"]
+                    if t != own]
+            if lead:
+                found = (rec, own, lead)
+                break
+        if found:
+            break
+    assert found, "no follower rode another thread's batch"
+    rec, own, lead = found
+    lead_names = {n for n, _t in lead}
+    assert "plan_build" in lead_names
+    assert lead_names & {"execute", "compile"}
+    assert "dispatch" in lead_names and "demux" in lead_names
+    assert len({t for _n, t in lead}) == 1      # one leader
+    # the follower itself waited, on its own thread, inside `batch`
+    waits = [s for s in rec["spans"] if s[0] == "batch.wait"]
+    assert waits and waits[0][4] == own
+    assert rec["spans"][waits[0][3]][0] == "batch"
+    # what the leader did hangs under the follower's stay in the batch
+    i = next(i for i, s in enumerate(rec["spans"])
+             if s[0] == "plan_build")
+    assert rec["spans"][rec["spans"][i][3]][0] == "batch"
+
+
+def test_span_cap_holds_and_phases_keep_every_second():
+    acc = flight.Acc()
+    prev = flight.push_acc(acc)
+    try:
+        with flight.stage("plan_build"):
+            for _ in range(200):
+                with flight.stage("stack_patch"):
+                    pass
+    finally:
+        flight.pop_acc(prev)
+    assert len(acc.spans) == flight.Acc._MAX_SPANS == 64
+    assert acc.depth == 0 and acc.cur == -1
+    assert acc.phases["stack_patch"] > 0
+    # the spans that were kept nest; the sum counts all 200
+    assert all(s[3] == 0 for s in acc.spans[1:])
+    kept = sum(s[2] for s in acc.spans[1:])
+    assert acc.phases["stack_patch"] * 1e3 >= kept - 0.01
+    # root seconds: the outermost stage alone
+    assert abs(acc.root_s - acc.phases["plan_build"]) < 1e-9
+
+
+def test_spanless_stage_hands_children_to_its_parent():
+    """A stack hit is a sum and a count, no span: what ran inside it
+    (an assemble) hangs under what was open around it."""
+    acc = flight.Acc()
+    prev = flight.push_acc(acc)
+    try:
+        with flight.stage("plan_build"):
+            with flight.stage("stack_hit") as st:
+                with flight.stage("stack.assemble"):
+                    pass
+                st.keep = False
+            with flight.stage("stack_hit") as st:
+                st.name = "stack_rebuild"
+    finally:
+        flight.pop_acc(prev)
+    assert [s[0] for s in acc.spans] == ["plan_build", "stack.assemble",
+                                         "stack_rebuild"]
+    assert [s[3] for s in acc.spans] == [-1, 0, 0]
+    assert set(acc.phases) == {"plan_build", "stack_hit",
+                               "stack.assemble", "stack_rebuild"}
+
+
+def test_stage_adds_nothing_with_recorder_disabled(holder):
+    ex = Executor(holder)
+    ex.enable_serving(window_s=0.0, max_batch=8)
+    flight.recorder.configure(enabled=False)
+    try:
+        flight.recorder.clear()
+        with flight.request():
+            with flight.stage("http.read") as st:
+                pass
+            assert flight.active_acc() is None
+            assert getattr(flight._tls, "env", None) is None
+            assert ex.execute_serving("i", "Count(Row(a=0))")
+        assert st.seconds >= 0 and st.span is None
+        assert flight.recorder.recent(5) == []
+    finally:
+        flight.recorder.configure(enabled=True)
+
+
+def test_shared_stage_is_one_interval_in_every_rider():
+    a, b = flight.Acc(), flight.Acc()
+    with flight.stage("execute", accs=[a, b]) as st:
+        with flight.stage("dispatch"):
+            pass
+    for acc in (a, b):
+        assert [s[0] for s in acc.spans] == ["execute", "dispatch"]
+        assert acc.spans[1][3] == 0
+        assert acc.phases["execute"] == st.seconds
+    assert flight.active_acc() is None
+
+
+def test_profiler_host_plane_names_work_stages_only(holder, tmp_path):
+    """Under jax.profiler a work stage is a TraceAnnotation on the
+    host plane — the device trace's clock — and a wait is not: a
+    parked thread would otherwise name every idle gap."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ex = Executor(holder)
+    ex.enable_serving(window_s=0.0, max_batch=8, cache_bytes=0)
+    flight.recorder.configure(enabled=True)
+    ex.execute_serving("i", "Count(Intersect(Row(a=1), Row(b=3)))")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ex.execute_serving("i", "Count(Intersect(Row(a=1), Row(b=3)))")
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1
+    names = set()
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+    assert "plan_build" in names and "dispatch" in names, sorted(names)[:50]
+    assert "cache_lookup" in names and "demux" in names
+    for wait in flight.WAITS:
+        assert wait not in names, wait
+
+
+def test_debug_trace_events_sit_at_recorded_offsets(holder):
+    ex = Executor(holder)
+    ex.enable_serving(window_s=0.0, max_batch=8, cache_bytes=0)
+    flight.recorder.configure(enabled=True)
+    flight.recorder.clear()
+    ex.execute_serving("i", "Count(Union(Row(a=1), Row(b=1)))")
+    rec = flight.recorder.recent(1)[0]
+    evs = [e for e in flight.recorder.chrome_trace(5)["traceEvents"]
+           if e.get("cat") == "stage"]
+    assert len(evs) == len(rec["spans"]) > 0
+    for ev, (name, off, dur, parent, _thr) in zip(evs, rec["spans"]):
+        assert ev["name"] == name
+        assert abs(ev["ts"] - (rec["start"] * 1e6 + off * 1e3)) < 1.0
+        assert abs(ev["dur"] - max(dur * 1e3, 0.5)) < 1e-6
+        assert ev["args"]["parent"] == parent
+    # the invented layout is gone: nothing is categorised a "phase"
+    assert not any(e.get("cat") == "phase"
+                   for e in flight.recorder.chrome_trace(5)["traceEvents"])
+    q = next(e for e in flight.recorder.chrome_trace(5)["traceEvents"]
+             if e.get("cat") == "query")
+    assert q["args"]["phases"] == rec["phases"]
+
+
+def test_profile_tree_from_the_single_stage_call(holder):
+    """Profile=true on the solo path: the same tree as before the
+    stages — Execute > executeCount > plan_build > stack, then the
+    dispatch — built by the one stage call per site."""
+    api = API(holder)  # serving never enabled
+    # Not() has no packed host arm: the count is a device dispatch
+    resp = api.query("i", "Count(Not(Row(a=2)))", profile=True)
+    root = resp["profile"][0]
+    assert root["name"] == "executor.Execute"
+    call = next(c for c in root["children"]
+                if c["name"] == "executor.executeCount")
+    kids = {c["name"]: c for c in call.get("children", [])}
+    assert "plan_build" in kids and kids["plan_build"]["tags"] == {
+        "call": "Not"}
+    stacks = [c for c in kids["plan_build"].get("children", [])
+              if c["name"].startswith("stack_")]
+    assert stacks and all("outcome" in c["tags"] for c in stacks)
+    run = kids.get("execute") or kids.get("compile")
+    assert run and run["tags"]["kind"] == "count"
+    assert run["tags"]["compile"] == (run["name"] == "compile")
+    assert [c["name"] for c in run["children"]] == ["dispatch"]
+
+
+# ---------------------------------------------------------------------------
 # acceptance: Profile=true fused into a concurrent batch
 # ---------------------------------------------------------------------------
 
@@ -308,24 +600,27 @@ def test_profile_fused_batch_multithreaded(holder):
             assert prof and prof[0]["name"] == "executor.Execute"
             spans = _span_names(prof[0], [])
             names = [n for n, _t in spans]
-            if "serving.dispatch" in names:
+            if "execute" in names or "compile" in names:
                 fused_trees.append(spans)
         # at least one query must have ridden a real (>=2) batch and
         # carry the leader-executed device phases in ITS OWN tree
         batched = []
         for spans in fused_trees:
             for name, tags in spans:
-                if name == "serving.dispatch" and tags.get("batch", 0) >= 2:
+                if (name in ("execute", "compile")
+                        and tags.get("batch", 0) >= 2):
                     batched.append((spans, tags))
         if batched:
             break
     assert batched, "no profiled query ever fused into a >=2 batch"
     spans, dtags = batched[0]
     names = [n for n, _t in spans]
-    # per-subquery phases: plan + dispatch + demux all present, and
-    # the dispatch span says whether it compiled or hit the jit cache
-    assert "serving.plan" in names
-    assert "serving.demux" in names
+    # per-subquery stages: plan + dispatch + demux all present, and
+    # the shared execute/compile span says whether it compiled or hit
+    # the jit cache and holds the host's `dispatch` share as a child
+    assert "plan_build" in names
+    assert "demux" in names
+    assert "dispatch" in names
     assert "compile" in dtags and "subqueries" in dtags
     # the fused subtree includes the trace-tagged root on the caller
     assert any(n == "executor.Execute" for n in names)

@@ -9,6 +9,7 @@ from pilosa_tpu.obs import (
     NopTracer,
     RecordingTracer,
     set_tracer,
+    span_into,
     start_span,
 )
 from pilosa_tpu.obs import logger as lg
@@ -245,3 +246,62 @@ def test_histogram_quantiles_render():
 def test_histogram_quantile_empty_is_zero():
     r = MetricsRegistry()
     assert r.histogram("lat4", "x", quantiles=(0.5,)).quantile(0.5) == 0.0
+
+
+def test_span_into_list_grafts_a_copy_into_every_context():
+    """One interval a batch leader runs for several riders: recorded
+    once, with what nests inside it, and grafted as its own copy into
+    each traced rider's tree; untraced riders (None) are skipped."""
+    from pilosa_tpu.obs.tracing import (
+        capture_context,
+        pop_thread_tracer,
+        push_thread_tracer,
+    )
+    tracers = [RecordingTracer(), RecordingTracer()]
+    ctxs = []
+    for t in tracers:
+        prev = push_thread_tracer(t)
+        try:
+            ctxs.append(capture_context())
+        finally:
+            pop_thread_tracer(prev)
+    with span_into([ctxs[0], None, ctxs[1]], "execute", batch=3) as sp:
+        with start_span("dispatch"):
+            pass
+    assert sp.name == "execute"
+    trees = [t.roots[0] for t in tracers]
+    assert trees[0] is not trees[1]
+    for tree in trees:
+        d = tree.to_dict()
+        assert d["name"] == "execute" and d["tags"] == {"batch": 3}
+        assert [c["name"] for c in d["children"]] == ["dispatch"]
+    # a batch with no traced rider silences the borrowed thread
+    outer = RecordingTracer()
+    prev = push_thread_tracer(outer)
+    try:
+        with start_span("root"):
+            with span_into([None, None], "execute"):
+                with start_span("dispatch"):
+                    pass
+    finally:
+        pop_thread_tracer(prev)
+    assert "children" not in outer.roots[0].to_dict()
+
+
+def test_recording_tracer_joins_a_tree_and_never_roots_one():
+    from pilosa_tpu.obs.tracing import (
+        pop_thread_tracer,
+        push_thread_tracer,
+        recording_tracer,
+    )
+    assert recording_tracer() is None           # the nop default
+    t = RecordingTracer()
+    prev = push_thread_tracer(t)
+    try:
+        assert recording_tracer() is t
+        assert recording_tracer(nested=True) is None    # nothing open
+        with start_span("root"):
+            assert recording_tracer(nested=True) is t
+    finally:
+        pop_thread_tracer(prev)
+    assert recording_tracer() is None
