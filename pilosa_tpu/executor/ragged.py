@@ -13,29 +13,33 @@ per group, drive one kernel over a *page table* —
   but under ``stacked.raw_pages()`` its stack leaves come back as
   :class:`PageView` handles (the cache's raw page arrays) instead of
   assembled operands;
-- pages of every query land in per-(page_lanes, width) *buckets*; a
-  flat page-index array per operand (contiguous ``arange`` today —
-  the layout survives future page dedup/subsetting) gathers each
-  operand out of its bucket INSIDE the fused program (the "ragged"
-  plan kind in stacked.py inlines the concat+gather so one
-  concatenate is shared per bucket; ``ops.bitmap.concat_gather`` is
-  the single-operand reference implementation of the same contract),
-  so the per-access assemble dispatch disappears too;
+- each such operand — a *virtual leaf* — owns a static run of the
+  program's leaves: its own real pages, each once, in lane order.
+  INSIDE the fused program (the "ragged" plan kind in stacked.py) the
+  leaf is assembled from that run exactly once, in the shape its
+  consumers read (``ops.bitmap.concat_pages``: concatenate, trim the
+  last page's padding, reshape; a one-page leaf is the page itself),
+  so the per-access assemble dispatch disappears and nothing is
+  copied that the query does not read;
 - single-leaf Counts — the dominant point-read shape — skip operand
-  materialization entirely: their lanes concatenate into one segment
-  family reduced by ``ops.bitmap.segment_count`` (popcount +
-  segment-sum, one pass at raw memory bandwidth — the Buddy-RAM
-  bound, arxiv 1611.09988);
+  materialization entirely: the pages of a family's members
+  concatenate into one lane block reduced by
+  ``ops.bitmap.segment_count`` with one segment id per lane (popcount
+  + segment-sum, one pass at raw memory bandwidth — the Buddy-RAM
+  bound, arxiv 1611.09988); the padding lanes of a member's last page
+  point at a dump segment;
 - every other subplan kind (tree counts, words, bsi_sum, row_counts)
   evaluates exactly as in the "multi" plan over the combined
   virtual+direct leaf space, so results are bit-exact by construction.
 
-Page layout and segment ids ride as runtime *params* while the plan
-stays a static int tuple: two batches with the same structural shape
-(tree shapes, lane counts, bucket layout) share one compiled
-executable even when their page tables differ, and pow2 padding of
-page counts, gather arrays, and segment counts keeps the shape space
-log-bounded across varying batch compositions.
+The plan is a static tuple that names structure only — tree shapes,
+each virtual leaf's page count and shape, a family's member runs —
+while the pages and segment ids ride as runtime arguments: two batches
+with the same structural shape share one compiled executable whatever
+rows they read, and the pow2-padded segment count (one dump slot
+included) keeps a family's output shape log-bounded.  The mesh
+program (``ragged_mesh``) keeps per-device page pools and gather
+tables of its own.
 
 Consistency is inherited unchanged from the serving layer: the
 post-batch snapshot re-check (executor/serving.py ``_run_batch``)
@@ -120,8 +124,8 @@ def _remap_sub(sub, lmap, poff):
         return ("row_counts", lmap[sub[1]], tree, sub[3])
     if kind == "gb_hist":
         # one-pass GroupBy histogram rider (ISSUE 11): the group-code
-        # stack and BSI plane leaves gather through the same page
-        # table as every other operand
+        # stack and BSI plane leaves are virtual leaves like every
+        # other operand
         tree = None if sub[2] is None else _remap_tree(sub[2], lmap,
                                                        poff)
         planes = None if sub[3] is None else lmap[sub[3]]
@@ -152,12 +156,12 @@ class RaggedProgram:
         # device combines run inside the compiled shard_map program
         self.ndev = int(ndev)
         self.mesh = self.ndev > 1
-        # (page_lanes, width_words) -> accumulated page arrays; in
-        # mesh mode, a list of per-device page lists instead (pages
-        # stay committed on their placement owner — the pool assembly
-        # in _finalize_mesh never moves a byte between devices)
+        # mesh mode only: (page_lanes, width_words) -> per-device page
+        # lists (pages stay committed on their placement owner — the
+        # pool assembly in _finalize_mesh never moves a byte between
+        # devices)
         self.buckets: OrderedDict[tuple, list] = OrderedDict()
-        # non-mesh vleaf: (bucket_key, lane_idx, n, shape)
+        # non-mesh vleaf: ((page_lanes, width_words), pages, n, shape)
         # mesh vleaf:     (bucket_key, pool_row, lane_dev, n, shape,
         #                  shard_axis, group_i)
         self.vleaves: list = []
@@ -194,19 +198,14 @@ class RaggedProgram:
                 if self.mesh:
                     lmap[i] = ("v", self._add_mesh_leaf(leaf, gidx))
                     continue
-                key = (leaf.page_lanes, leaf.width_words)
-                pages = self.buckets.setdefault(key, [])
-                base = len(pages) * leaf.page_lanes
-                # per-page decode-to-dense boundary: the fused gather
-                # program indexes a homogeneous dense page pool, so
-                # container-encoded pages (memory/encode.py) expand
-                # here — page identity and lane mapping unchanged
-                pages.extend(leaf.dense_pages())
-                lane_idx = (base + np.arange(leaf.lanes)).astype(
-                    np.int32)
+                # per-page decode-to-dense boundary: the program
+                # concatenates homogeneous dense pages, so container-
+                # encoded pages (memory/encode.py) expand here — page
+                # identity and lane order unchanged
                 lmap[i] = ("v", len(self.vleaves))
-                self.vleaves.append((key, lane_idx, leaf.lanes,
-                                     leaf.shape))
+                self.vleaves.append(
+                    ((leaf.page_lanes, leaf.width_words),
+                     leaf.dense_pages(), leaf.lanes, leaf.shape))
             else:
                 if self.mesh:
                     raise RaggedUnbuildable(
@@ -255,16 +254,6 @@ class RaggedProgram:
         self.params.append(np.ascontiguousarray(arr, dtype=np.int32))
         return len(self.params) - 1
 
-    def _add_param(self, arr: np.ndarray, pad_value) -> int:
-        """Append a pow2-padded int32 param array; returns its index."""
-        n = arr.shape[0]
-        npad = _pow2(max(n, 1))
-        if npad != n:
-            arr = np.concatenate(
-                [arr, np.full(npad - n, pad_value, np.int32)])
-        self.params.append(np.ascontiguousarray(arr, dtype=np.int32))
-        return len(self.params) - 1
-
     def finalize(self):
         """(plan, leaves, params, served, table, meshinfo) or None
         when nothing was built.  ``served``: [(req, demux, extract),
@@ -275,9 +264,10 @@ class RaggedProgram:
         if not any(entries for entries, _l, _p in self.groups):
             return None
         # -- segment-count families: single-leaf reduced Counts whose
-        # leaf is paged coalesce per bucket into one segment reduce
+        # leaf is paged coalesce per (page_lanes, width) class into
+        # one segment reduce
         families: OrderedDict[tuple, list] = OrderedDict()
-        seg_entry: dict = {}      # id(entry tuple) -> (bucket, slot)
+        seg_entry: dict = {}      # id(entry tuple) -> (class, slot)
         for entries, lmap, _poff in self.groups:
             for ent in entries:
                 sub = ent[1]
@@ -295,7 +285,7 @@ class RaggedProgram:
         # -- keep only the virtual leaves some surviving (non-segment)
         # subplan actually reads: a leaf consumed solely by a segment
         # family never materializes — its lanes reduce straight out of
-        # the bucket gather
+        # its pages
         def _refs(sub) -> set:
             """LOCAL leaf indices a subplan reads."""
             out: set = set()
@@ -348,33 +338,25 @@ class RaggedProgram:
         vre = {vi: k for k, vi in enumerate(vkeep)}
         if self.mesh:
             return self._finalize_mesh(families, plain, vkeep, vre)
-        # -- leaf layout: bucket pages (pow2-padded) first, direct
-        # after.  Only buckets something references survive — a failed
-        # subplan build can leave orphan page leaves behind, and an
-        # unused bucket would still pay its in-program concatenate.
-        used_keys = {self.vleaves[vi][0] for vi in vkeep} \
-            | set(families.keys())
-        bucket_meta: list = []
-        bucket_id: dict = {}
-        cur = 0
+        # -- leaf layout: every virtual leaf a sub or a family reads
+        # owns ONE static run of the program's leaves — its real
+        # pages, each once — and the direct leaves follow.  A leaf
+        # nothing references (a failed subplan build can orphan one)
+        # brings no page.
         leaves: list = []
-        for key, pages in self.buckets.items():
-            if key not in used_keys:
-                continue
-            npad = _pow2(max(len(pages), 1))
-            padded = pages + [pages[-1]] * (npad - len(pages))
-            bucket_id[key] = len(bucket_meta)
-            bucket_meta.append((cur, npad))
-            leaves.extend(padded)
-            cur += npad
+        run_of: dict = {}
+
+        def _run(v) -> tuple:
+            """(leaf_start, n_pages) of virtual leaf `v`'s pages."""
+            r = run_of.get(id(v))
+            if r is None:
+                r = run_of[id(v)] = (len(leaves), len(v[1]))
+                leaves.extend(v[1])
+            return r
+
+        vmeta = tuple(_run(v) + (v[3],)
+                      for v in (self.vleaves[vi] for vi in vkeep))
         nv = len(vkeep)
-        leaves.extend(self.direct)
-        # -- virtual-leaf meta + gather params
-        vmeta: list = []
-        for vi in vkeep:
-            key, lane_idx, n, shape = self.vleaves[vi]
-            gi = self._add_param(lane_idx, lane_idx[-1])
-            vmeta.append((bucket_id[key], gi, int(n), tuple(shape)))
         # -- final lmaps + subs.  Unreferenced virtual leaves map to
         # None: _remap_sub only touches indices a sub actually reads,
         # so a None ever surfacing in a plan is a planner bug that
@@ -402,45 +384,36 @@ class RaggedProgram:
                 table[slot_key] = (demux, ("plain", i))
             for r in riders:
                 served.append((r, demux, ("plain", i)))
-        for vkey, members in families.items():
-            # duplicate calls share one leaf (PlanBuilder dedupe), so
-            # their lane_idx object is shared — one segment slot
-            # serves every rider of that call
-            slot_of: dict[int, int] = {}
-            uniq: list = []
-            member_slots: list = []
-            for ent, v in members:
-                li = v[1]
-                s = slot_of.get(id(li))
-                if s is None:
-                    s = slot_of[id(li)] = len(uniq)
-                    uniq.append(li)
-                member_slots.append((ent, s))
+        # -- segment families.  Duplicate calls share one leaf
+        # (PlanBuilder dedupe): one segment serves every rider of that
+        # call.  A member's lanes past its last real one (the final
+        # page's zero padding) point at the dump segment, which also
+        # pads the segment count to pow2 so the executable survives
+        # composition churn.
+        for members in families.values():
+            uniq = list({id(v): v for _ent, v in members}.values())
             nseg = len(uniq)
-            npad_seg = _pow2(nseg + 1)   # +1 dump slot for padding
-            lane_cat = np.concatenate(uniq)
             seg_ids = np.concatenate(
-                [np.full(li.shape[0], slot, np.int32)
-                 for slot, li in enumerate(uniq)])
-            # pad lanes to pow2 pointing at the dump segment so the
-            # executable shape survives composition churn
-            gi = self._add_param(lane_cat, lane_cat[-1])
-            si = self._add_param(seg_ids, nseg)
-            subs.append(("segcount", bucket_id[vkey], gi, si,
-                         npad_seg))
-            for ent, slot in member_slots:
+                [np.where(np.arange(len(pages) * page_lanes) < n,
+                          slot, nseg)
+                 for slot, ((page_lanes, _w), pages, n, _shape)
+                 in enumerate(uniq)])
+            self.params.append(seg_ids.astype(np.int32))
+            subs.append(("segcount", tuple(_run(v) for v in uniq),
+                         len(self.params) - 1, _pow2(nseg + 1)))
+            slot_of = {id(v): slot for slot, v in enumerate(uniq)}
+            for ent, v in members:
                 riders, _sub, demux, slot_key = ent
+                ext = ("seg", len(subs) - 1, slot_of[id(v)])
                 if slot_key is not None:
-                    table[slot_key] = (demux,
-                                       ("seg", len(subs) - 1, slot))
+                    table[slot_key] = (demux, ext)
                 for r in riders:
-                    served.append((r, demux,
-                                   ("seg", len(subs) - 1, slot)))
+                    served.append((r, demux, ext))
         if not subs:
             return None
-        plan = ("ragged", tuple(bucket_meta), tuple(vmeta),
-                tuple(subs))
-        return plan, leaves, self.params, served, table, None
+        plan = ("ragged", len(leaves), vmeta, tuple(subs))
+        return (plan, leaves + self.direct, self.params, served, table,
+                None)
 
     def _finalize_mesh(self, families, plain, vkeep, vre):
         """Emit the ``("ragged_mesh", ...)`` plan: per-device page
@@ -820,14 +793,13 @@ def _mesh_width(eng) -> int:
     return placement.mesh_devices()
 
 
-def _note_roofline(plan, leaves, dt, meshinfo, served) -> None:
+def _note_roofline(nbytes: int, dt, meshinfo, served) -> None:
     """Per-dispatch bandwidth attribution for the fused ragged
     program: the aggregate 'ragged' op family plus — under the mesh —
     a per-device series (each chip's resident pool bytes over the
     same program wall time) and the per-device page-encoding mix on
     every rider's flight record."""
     from pilosa_tpu.obs import roofline
-    nbytes = sum(int(getattr(a, "nbytes", 0)) for a in leaves)
     roofline.note("ragged", nbytes, dt)
     if not meshinfo:
         return
@@ -1051,7 +1023,8 @@ def _dispatch_served(eng, plan, leaves, params, served, meshinfo,
     kern = (kernels.enabled() and not eng.host_only
             and plan[0] != "ragged_mesh")
     # the program's signature is a repr of the whole plan and a shape
-    # key per page: milliseconds at 16k pages, and part of building it
+    # key per page: milliseconds at hundreds of pages, and part of
+    # building it
     with _plan_stage([r for r, _d, _e in served]):
         sig = (repr(plan), kern)
         kind = _dispatch_kind(sig, leaves, params)
@@ -1082,11 +1055,18 @@ def _dispatch_served(eng, plan, leaves, params, served, meshinfo,
         for r, _d, _e in served:
             r.direct = True
         return None
-    metrics.SERVING_DISPATCH.inc(
-        kind="ragged_mesh" if plan[0] == "ragged_mesh" else "ragged")
+    metrics.SERVING_DISPATCH.inc(kind=plan[0])
+    # one pass over the leaves' sizes (a host attribute, no device
+    # read) serves the counter and the roofline note
+    nbytes = [int(getattr(a, "nbytes", 0)) for a in leaves]
+    if plan[0] == "ragged":
+        assembled = sum(nbytes[:plan[1]])
+        metrics.RAGGED_ASSEMBLED_BYTES.inc(assembled)
+        for r, _d, _e in served:
+            r.acc.assembled_bytes += assembled
     if kind == "execute" and \
             metrics.OOM_TOTAL.total(outcome="caught") == oom0:
-        _note_roofline(plan, leaves, st.seconds, meshinfo, served)
+        _note_roofline(sum(nbytes), st.seconds, meshinfo, served)
     return outs
 
 

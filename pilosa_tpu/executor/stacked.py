@@ -133,7 +133,7 @@ _RAW_TLS = threading.local()
 
 class PageView:
     """Raw paged payload of one stack-cache entry: the page arrays a
-    ragged program gathers through its page table.  ``pages`` is a
+    ragged program assembles its operand from.  ``pages`` is a
     local snapshot (references keep the buffers alive against
     concurrent eviction, the same contract as the assemble path);
     the last page is zero-padded past ``lanes``.  Entries under the
@@ -863,7 +863,7 @@ class TileStackCache:
             flight.note_pages(_page_mix(arrs))
         if getattr(_RAW_TLS, "on", False):
             # ragged page-table dispatch: hand the caller the raw page
-            # snapshot — the fused program gathers them itself, so the
+            # snapshot — the fused program assembles them itself, so the
             # per-access assemble dispatch is skipped entirely (sparse
             # pages ride along encoded; consumers expand per page or
             # take the packed fast paths)
@@ -1581,39 +1581,35 @@ def _plan_run(plan, kern: bool = False):
         return run
     if kind == "ragged":
         # the cross-index page-table program (executor/ragged.py):
-        #   ("ragged", buckets, vmeta, subs)
-        # leaves = per-bucket page arrays first, then direct leaves;
-        # buckets = ((leaf_start, n_pages), ...) one per (page_lanes,
-        # W) shape class; vmeta = ((bucket, gather_param, n_lanes,
-        # shape), ...) — virtual leaves materialized by ONE in-program
-        # gather each; subs evaluate over the combined virtual+direct
-        # leaf space like "multi", except ("segcount", bucket, gparam,
-        # sparam, nseg) entries reduce a whole family of single-leaf
-        # Counts through one popcount+segment-sum without ever
+        #   ("ragged", n_pages, vmeta, subs)
+        # leaves = the n_pages page arrays, then the direct leaves.
+        # vmeta = ((leaf_start, n_pages, shape), ...): each virtual
+        # leaf owns a static run of the page leaves — its real pages,
+        # each once — and is assembled from them exactly once, in the
+        # shape its consumers read (bm.concat_pages).  subs evaluate
+        # over the combined virtual+direct leaf space like "multi",
+        # except ("segcount", ((leaf_start, n_pages), ...), sparam,
+        # nseg) entries, which reduce a whole family of single-leaf
+        # Counts through one popcount+segment-sum over the
+        # concatenation of the members' pages without ever
         # materializing their operands.
-        buckets, vmeta, subs = plan[1], plan[2], plan[3]
-        ndirect = (buckets[-1][0] + buckets[-1][1]) if buckets else 0
+        n_pages, vmeta, subs = plan[1], plan[2], plan[3]
         runs = tuple(None if s[0] == "segcount" else _plan_run(s, kern)
                      for s in subs)
 
         def run(leaves, params):
-            flats = []
-            vl = []
             with jax.named_scope("page_gather"):
-                for start, npages in buckets:
-                    ps = leaves[start:start + npages]
-                    flats.append(jnp.concatenate(ps, axis=0)
-                                 if npages > 1 else ps[0])
-                for b, gi, n, shape in vmeta:
-                    g = flats[b][params[gi]]    # (Lpad, W) gather
-                    vl.append(g[:n].reshape(shape))
-            all_leaves = tuple(vl) + tuple(leaves[ndirect:])
+                vl = tuple(bm.concat_pages(leaves[start:start + n], shape)
+                           for start, n, shape in vmeta)
+            all_leaves = vl + tuple(leaves[n_pages:])
             outs = []
             for s, r in zip(subs, runs):
                 if r is None:
-                    _k, b, gi, si, nseg = s
+                    _k, members, si, nseg = s
                     with jax.named_scope("count"):
-                        lanes = flats[b][params[gi]]
+                        lanes = jnp.concatenate(
+                            [p for start, n in members
+                             for p in leaves[start:start + n]], axis=0)
                         outs.append(bm.segment_count(
                             lanes, params[si], nseg))
                 else:

@@ -86,7 +86,7 @@ class Acc:
 
     __slots__ = ("phases", "stack", "bytes_moved", "keys", "attempts",
                  "t0", "node_spans", "ops", "pages", "spans", "cur",
-                 "depth", "root_s")
+                 "depth", "root_s", "assembled_bytes")
 
     # per-record stack-key cap: a pathological query touching hundreds
     # of stacks must not bloat the ring
@@ -131,6 +131,9 @@ class Acc:
         # (encoding -> page count; memory/encode.py container kinds) —
         # how a record shows which arm served it, packed or dense
         self.pages: dict[str, int] = {}
+        # bytes of the page leaves the ragged programs that served
+        # this query were handed (executor/ragged.py)
+        self.assembled_bytes = 0
         # stage spans [name, offset, duration, parent, thread]: seconds
         # from t0 here (ms_spans() makes the record's ms), parent an
         # index into this list or -1; `cur` is the
@@ -290,6 +293,7 @@ class Acc:
                 st[0] += b
                 st[1] += s
         self.add_pages(other.pages)
+        self.assembled_bytes += other.assembled_bytes
 
 
 def push_acc(acc: Acc):
@@ -873,6 +877,8 @@ def commit(rec: dict | None, duration_s: float, route: str = "solo",
         # page-encoding mix of the stack operands touched (sparse
         # device format, memory/encode.py): packed vs dense served
         rec["page_mix"] = dict(acc.pages)
+    if acc.assembled_bytes:
+        rec["assembled_bytes"] = acc.assembled_bytes
     if acc.ops:
         # roofline share: bytes touched / execute time per op family,
         # with achieved GB/s (+ fraction once the peak probe landed)

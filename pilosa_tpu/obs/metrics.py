@@ -473,6 +473,11 @@ SERVING_DISPATCH = registry.counter(
     "Fused serving device dispatches by kind (ragged = one cross-"
     "index page-table program per batch; group = one multi program "
     "per (index, shards) group)")
+RAGGED_ASSEMBLED_BYTES = registry.counter(
+    "pilosa_ragged_assembled_bytes_total",
+    "Bytes of the page leaves handed to ragged programs: what one "
+    "in-program assembly of every operand copies, summed over "
+    "dispatches (from the plan, on the host)")
 ADMISSION_TOTAL = registry.counter(
     "pilosa_serving_admission_total",
     "Serving admission decisions by class (point/heavy) and outcome "

@@ -263,14 +263,23 @@ def named(name: str):
     return deco
 
 
-@_partial(jax.jit, static_argnums=(1,))
-@named("assemble_pages")
-def _assemble_pages_jit(pages, shape: tuple):
+def concat_pages(pages, shape: tuple):
+    """The assembly graph, un-jitted: page blocks (each (page_lanes,
+    W)) concatenated along the lane axis, the final page's padding
+    trimmed, the logical shape restored.  A one-page operand is the
+    page itself.  The ragged plan kind (executor/stacked.py) builds
+    each of its operands with this inside its own program."""
     n_lanes = 1
     for d in shape[:-1]:
         n_lanes *= int(d)
     flat = jnp.concatenate(pages, axis=0) if len(pages) > 1 else pages[0]
     return flat[:n_lanes].reshape(shape)
+
+
+@_partial(jax.jit, static_argnums=(1,))
+@named("assemble_pages")
+def _assemble_pages_jit(pages, shape: tuple):
+    return concat_pages(pages, shape)
 
 
 def assemble_pages(pages, shape: tuple):
@@ -292,29 +301,14 @@ def assemble_pages(pages, shape: tuple):
 # Ragged segment reductions (page-table dispatch) ---------------------------
 #
 # The ragged serving plane (executor/ragged.py) drives ONE device
-# program over a page table assembled from many queries' PagedStack
-# pages: a flat page array is gathered into per-query lane segments
-# and reduced per segment.  These are the segment primitives; they are
-# plain jnp functions so the ragged plan kind composes them inside one
-# jitted program (the Ragged Paged Attention shape from PAPERS.md —
-# ragged per-query page lists + segment ids instead of per-group
-# padding).
-
-def concat_gather(pages, lane_idx):
-    """Page-table gather: concatenate page blocks (each (page_lanes,
-    W)) into the flat bucket lane space and gather ``lane_idx`` rows
-    out of it — the materialization of one ragged operand.  The
-    caller pow2-pads both the page tuple (repeating the last page)
-    and ``lane_idx`` (repeating the last index) so the executable
-    cache grows log-, not linearly, in batch composition.  This is
-    the REFERENCE implementation of the contract (pinned by
-    tests/test_ragged.py); the fused "ragged" plan kind
-    (executor/stacked.py _plan_run) inlines the same graph so that
-    operands of one bucket share a single concatenate."""
-    pages = tuple(pages)
-    flat = jnp.concatenate(pages, axis=0) if len(pages) > 1 else pages[0]
-    return flat[jnp.asarray(lane_idx)]
-
+# program over the pages of many queries' PagedStacks: each operand is
+# assembled from its own pages (concat_pages above), and a family of
+# single-leaf Counts reduces over the concatenation of its members'
+# pages, one segment id per lane (the Ragged Paged Attention shape from
+# PAPERS.md — ragged per-query page lists + segment ids instead of
+# per-group padding).  This is the segment primitive; it is a plain jnp
+# function so the ragged plan kind composes it inside one jitted
+# program.
 
 def segment_count(lanes, seg_ids, num_segments: int):
     """Per-segment popcount totals of a flat (L, W) lane block:

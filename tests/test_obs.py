@@ -305,3 +305,53 @@ def test_recording_tracer_joins_a_tree_and_never_roots_one():
     finally:
         pop_thread_tracer(prev)
     assert recording_tracer() is None
+
+
+def test_ragged_dispatch_counts_the_bytes_of_its_page_leaves():
+    """One ragged dispatch moves pilosa_ragged_assembled_bytes_total by
+    the bytes of the pages the program was handed — the real pages of
+    every operand, once each — and the rider's flight record carries
+    the same figure."""
+    from pilosa_tpu import memory
+    from pilosa_tpu.executor import stacked as stk
+    from pilosa_tpu.executor.executor import Executor
+    from pilosa_tpu.models.holder import Holder
+    from pilosa_tpu.obs import flight, metrics
+
+    h = Holder()
+    idx = h.create_index("ab", track_existence=False)
+    idx.create_field("a")
+    idx.create_field("b")
+    plain = Executor(h)
+    for shard in range(5):
+        plain.execute("ab", f"Set({shard * idx.width + shard}, a=1)")
+        plain.execute("ab", f"Set({shard * idx.width + 7}, b=2)")
+    prev = memory.page_bytes()
+    memory.configure(page_bytes=256 << 10)  # 2 lanes of 2**15 words
+    try:
+        ex = Executor(h)
+        ex.enable_serving(window_s=0.0, max_batch=8, cache_bytes=0,
+                          admission=False)
+        flight.recorder.configure(enabled=True)
+        flight.recorder.clear()
+        skey = tuple(sorted(idx.available_shards))
+        with stk.raw_pages():
+            views = [ex.stacked.row_stack(idx, idx.field(f), ("standard",),
+                                          row, skey)
+                     for f, row in (("a", 1), ("b", 2))]
+        want = sum(int(p.nbytes) for pv in views
+                   for p in pv.dense_pages())
+        # 5 lanes in 3 pages of 2: the padding lane counts, a pow2
+        # fourth page does not exist
+        assert want == 2 * 3 * (256 << 10)
+        b0 = metrics.RAGGED_ASSEMBLED_BYTES.value()
+        d0 = metrics.SERVING_DISPATCH.value(kind="ragged")
+        (n,) = ex.execute_serving(
+            "ab", "Count(Union(Row(a=1), Row(b=2)))")
+    finally:
+        memory.configure(page_bytes=prev)
+    assert n == 10
+    assert metrics.SERVING_DISPATCH.value(kind="ragged") == d0 + 1
+    assert metrics.RAGGED_ASSEMBLED_BYTES.value() == b0 + want
+    rec = flight.recorder.recent(1)[0]
+    assert rec["route"] == "fused" and rec["assembled_bytes"] == want

@@ -152,8 +152,9 @@ def test_multipage_page_table(holder):
 
 
 def test_segment_ops_bit_exact():
-    """ops/bitmap.py segment primitives: page-table gather + segment
-    popcount reduce match the numpy twin, padding contract included."""
+    """ops/bitmap.py ragged primitives: an operand assembled from its
+    own pages and the segment popcount reduce match the numpy twins,
+    the dump-segment padding contract included."""
     import numpy as np
 
     from pilosa_tpu.ops import bitmap as bm
@@ -161,18 +162,148 @@ def test_segment_ops_bit_exact():
     rng = np.random.default_rng(3)
     pages = [rng.integers(0, 1 << 32, size=(4, 8), dtype=np.uint32)
              for _ in range(3)]
-    # pow2-pad the page tuple by repeating the last page
-    padded = tuple(pages) + (pages[-1],)
-    lane_idx = np.array([0, 5, 11, 2, 7, 7, 3, 3], np.int32)
-    got = np.asarray(bm.concat_gather(padded, lane_idx))
     flat = np.concatenate(pages)
-    assert (got == flat[lane_idx]).all()
-    seg_ids = np.array([0, 0, 1, 1, 2, 2, 3, 3], np.int32)
-    counts = np.asarray(bm.segment_count(got, seg_ids, 5))
-    want = bm.segment_count_np(flat[lane_idx], seg_ids, 5)
-    assert (counts[:5] == want).all()
-    # the dump segment (no lanes mapped) stays zero
-    assert counts[4] == 0 == want[4]
+    # 10 real lanes: the last page holds two of them
+    got = np.asarray(bm.concat_pages(tuple(pages), (5, 2, 8)))
+    assert (got == flat[:10].reshape(5, 2, 8)).all()
+    assert (np.asarray(bm.concat_pages((pages[0],), (4, 8)))
+            == pages[0]).all()
+    # a family's lanes: every page of every member, the lanes past a
+    # member's last real one pointed at the dump segment (4)
+    seg_ids = np.array([0, 0, 0, 0, 1, 1, 1, 1, 2, 2, 4, 4], np.int32)
+    counts = np.asarray(bm.segment_count(flat, seg_ids, 8))
+    want = bm.segment_count_np(flat, seg_ids, 8)
+    assert (counts == want).all()
+    assert counts[3] == 0 and (counts[5:] == 0).all()
+    assert counts[2] == np.bitwise_count(flat[8:10]).sum()
+
+
+def _page_view(rng, shape, page_lanes):
+    """A PageView over random words, paged as memory/pages.py pages a
+    stack: fixed-size lane blocks, the last one zero-padded."""
+    import numpy as np
+
+    from pilosa_tpu.executor import stacked as stk
+
+    lanes = int(np.prod(shape[:-1]))
+    flat = rng.integers(0, 1 << 32, size=(lanes, shape[-1]),
+                        dtype=np.uint32)
+    n_pages = -(-lanes // page_lanes)
+    padded = np.zeros((n_pages * page_lanes, shape[-1]), np.uint32)
+    padded[:lanes] = flat
+    pages = [padded[i * page_lanes:(i + 1) * page_lanes]
+             for i in range(n_pages)]
+    return stk.PageView(shape, lanes, page_lanes, pages)
+
+
+def _finalize_one_group(leaves, subs):
+    """(plan, program leaves, params, outputs) of one group built
+    straight on RaggedProgram: `leaves` are the builder's leaves,
+    `subs` its sub-plans over them."""
+    import types
+
+    from pilosa_tpu.executor import ragged
+    from pilosa_tpu.executor import stacked as stk
+
+    prog = ragged.RaggedProgram()
+    builder = types.SimpleNamespace(leaves=list(leaves), params=[])
+    prog.add_group(builder, [([], sub, None, None) for sub in subs])
+    plan, pleaves, params, _served, _table, _mesh = prog.finalize()
+    outs = stk._plan_run(plan)(tuple(pleaves), tuple(params))
+    return plan, pleaves, params, outs
+
+
+@pytest.mark.parametrize("shape,page_lanes", [
+    ((8, 16), 4),        # multi-page, every page full
+    ((3, 3, 16), 4),     # multi-page, one real lane in the last page
+    ((3, 16), 4),        # one page, partly filled
+    ((4, 16), 4),        # one page, the leaf IS the page
+    ((2, 5, 16), 32),    # one page much larger than the leaf
+], ids=["multipage", "ragged-tail", "one-page-tail", "one-page-exact",
+        "one-page-wide"])
+def test_virtual_leaf_equals_assemble_pages(shape, page_lanes):
+    """The in-program assembly of a virtual leaf is bm.assemble_pages
+    of the same PageView, and the program is handed the leaf's real
+    pages and nothing else."""
+    import numpy as np
+
+    from pilosa_tpu.ops import bitmap as bm
+
+    pv = _page_view(np.random.default_rng(11), shape, page_lanes)
+    want = np.asarray(bm.assemble_pages(tuple(pv.pages), pv.shape))
+    plan, pleaves, _params, outs = _finalize_one_group(
+        [pv], [("words", ("leaf", 0))])
+    assert plan[:3] == ("ragged", len(pv.pages),
+                        ((0, len(pv.pages), tuple(shape)),))
+    assert [id(p) for p in pleaves] == [id(p) for p in pv.pages]
+    assert (np.asarray(outs[0]) == want).all()
+
+
+def test_virtual_leaf_shared_by_two_subplans_is_assembled_once():
+    """Two sub-plans over one leaf read ONE virtual leaf: its pages
+    enter the program once, beside the other leaf's and before the
+    direct leaf."""
+    import numpy as np
+
+    from pilosa_tpu.ops import bitmap as bm
+
+    rng = np.random.default_rng(12)
+    a = _page_view(rng, (5, 16), 4)
+    b = _page_view(rng, (5, 16), 2)
+    direct = rng.integers(0, 1 << 32, size=(5, 16), dtype=np.uint32)
+    both = ("nary", "intersect", (("leaf", 0), ("leaf", 1)))
+    plan, pleaves, _params, outs = _finalize_one_group(
+        [a, b, direct],
+        [("words", ("leaf", 0)), ("count", both, False),
+         ("count", ("nary", "union", (("leaf", 0), ("leaf", 2))),
+          False)])
+    assert plan[1] == len(a.pages) + len(b.pages) == 5
+    assert plan[2] == ((0, 2, (5, 16)), (2, 3, (5, 16)))
+    assert len(pleaves) == plan[1] + 1 and pleaves[-1] is direct
+    assert len({id(p) for p in pleaves}) == len(pleaves)
+    wa = np.asarray(bm.assemble_pages(tuple(a.pages), a.shape))
+    wb = np.asarray(bm.assemble_pages(tuple(b.pages), b.shape))
+    assert (np.asarray(outs[0]) == wa).all()
+    assert (np.asarray(outs[1])
+            == np.bitwise_count(wa & wb).sum(axis=-1)).all()
+    assert (np.asarray(outs[2])
+            == np.bitwise_count(wa | direct).sum(axis=-1)).all()
+
+
+def test_segment_family_reduces_its_members_own_pages():
+    """A family of single-leaf Counts: each member's pages enter once
+    (a member that a plain sub also reads shares its run), the lanes
+    of a partly filled last page fall into the dump segment, and
+    every count is exact."""
+    import numpy as np
+
+    from pilosa_tpu.ops import bitmap as bm
+
+    rng = np.random.default_rng(13)
+    views = [_page_view(rng, (5, 16), 2) for _ in range(3)]
+    # poison the padding lanes: only the dump segment may see them
+    for pv in views:
+        pv.pages[-1][1:] = 0xFFFFFFFF
+    subs = [("count", ("leaf", i), True) for i in range(3)]
+    subs.append(("words", ("leaf", 1)))
+    plan, pleaves, params, outs = _finalize_one_group(views, subs)
+    seg = plan[3][-1]
+    assert seg[0] == "segcount" and seg[3] == 4      # pow2(3 + dump)
+    # leaf 1 is also read by the words sub: its run comes first and
+    # the family points at the same pages
+    assert plan[2] == ((0, 3, (5, 16)),)
+    assert seg[1] == ((3, 3), (0, 3), (6, 3))
+    assert plan[1] == len(pleaves) == 9
+    assert len({id(p) for p in pleaves}) == 9
+    seg_ids = params[seg[2]]
+    assert seg_ids.shape == (18,) and (seg_ids[5::6] == 3).all()
+    got = np.asarray(outs[-1])
+    for slot, i in enumerate((0, 1, 2)):
+        flat = np.concatenate(views[i].pages)[:5]
+        assert got[slot] == np.bitwise_count(flat).sum()
+    assert got[3] == 3 * 16 * 32                      # the poison
+    assert (np.asarray(outs[0]) == np.asarray(bm.assemble_pages(
+        tuple(views[1].pages), (5, 16)))).all()
 
 
 def test_raw_pages_view(holder):
@@ -376,3 +507,103 @@ def test_canonical_composition_stabilizes_executable(holder):
     assert {s for s in stk._JIT_CACHE
             if s[0].startswith("('ragged'")} == union_sigs
     assert len(layer._ragged_canon.slots) == 6
+
+
+def _spy_dispatches(monkeypatch):
+    """Every (plan, leaves, params) the ragged plane dispatches."""
+    from pilosa_tpu.executor import ragged
+
+    seen = []
+    real = ragged._dispatch_served
+
+    def spy(eng, plan, leaves, params, *rest):
+        seen.append((plan, list(leaves), list(params)))
+        return real(eng, plan, leaves, params, *rest)
+    monkeypatch.setattr(ragged, "_dispatch_served", spy)
+    return seen
+
+
+def _page_runs(plan):
+    """Every (leaf_start, n_pages) run a "ragged" plan reads."""
+    runs = {(start, n) for start, n, _shape in plan[2]}
+    for sub in plan[3]:
+        if sub[0] == "segcount":
+            runs.update(sub[1])
+    return sorted(runs)
+
+
+def test_program_receives_exactly_the_real_pages(holder, monkeypatch):
+    """Small pages, a mixed batch with a segment family: the page
+    leaves of every dispatched program are the runs its plan names,
+    back to back — no padding page, no page object twice — and the
+    answers equal the solo path's."""
+    from pilosa_tpu.executor import stacked as stk
+
+    prev = memory.page_bytes()
+    memory.configure(page_bytes=256 << 10)   # 2 lanes of 2**15 words
+    try:
+        plain = Executor(holder)
+        srv = Executor(holder)
+        layer = srv.enable_serving(window_s=0.05, max_batch=64,
+                                   cache_bytes=0, admission=False)
+        seen = _spy_dispatches(monkeypatch)
+        assert _run_one_batch(layer, MIXED) == solo_expect(plain, MIXED)
+    finally:
+        memory.configure(page_bytes=prev)
+    assert seen
+    family_runs = set()
+    for plan, leaves, _params in seen:
+        assert plan[0] == "ragged"
+        cur = 0
+        for start, n in _page_runs(plan):
+            assert start == cur and n >= 1
+            cur += n
+        assert cur == plan[1] <= len(leaves)
+        pages = leaves[:plan[1]]
+        assert len({id(p) for p in pages}) == len(pages)
+        assert all(p.ndim == 2 for p in pages)
+        assert not any(isinstance(x, stk.PageView) for x in leaves)
+        for sub in plan[3]:
+            if sub[0] == "segcount":
+                family_runs.update(n for _start, n in sub[1])
+    # one family of single-leaf Counts spans both indexes: alpha's
+    # 3-shard rows (2 pages, half of the second empty) and beta's
+    # 2-shard rows (1 page)
+    assert family_runs == {1, 2}
+
+
+def test_same_structure_different_rows_share_one_executable(
+        holder, monkeypatch):
+    """The plan keys on structure (tree shapes, each leaf's page
+    count and shape), never on which rows ride: a later batch of the
+    same templates over other rows builds the same plan and runs the
+    first batch's executable."""
+    from pilosa_tpu.executor import stacked as stk
+
+    srv = Executor(holder)
+    layer = srv.enable_serving(window_s=0.05, max_batch=64,
+                               cache_bytes=0, admission=False)
+    plain = Executor(holder)
+    seen = _spy_dispatches(monkeypatch)
+
+    def batch(i):
+        # no string twice: a repeat would be promoted into the
+        # canonical program, another composition
+        return [("alpha", f"Count(Intersect(Row(a={i}), Row(b={i})))",
+                 None),
+                ("alpha", f"Sum(Row(a={i}), field=v)", None),
+                ("alpha", f"TopN(b, Row(a={i}), n=2)", None),
+                ("alpha", f"Count(Row(a={i}))", None),
+                ("alpha", f"Count(Row(b={i}))", None)]
+
+    assert _run_one_batch(layer, batch(0)) == solo_expect(plain, batch(0))
+    (plan, _leaves, _params), = seen
+    assert any(sub[0] == "segcount" for sub in plan[3])
+    fn = stk._JIT_CACHE[(repr(plan), False)][0]
+    n_exec = fn._cache_size()
+    for i in (1, 2, 3):
+        assert _run_one_batch(layer, batch(i)) \
+            == solo_expect(plain, batch(i))
+    assert [p for p, _l, _p in seen] == [plan] * 4
+    assert stk._JIT_CACHE[(repr(plan), False)][0] is fn
+    assert fn._cache_size() == n_exec
