@@ -26,8 +26,8 @@ from harness import pql
 
 
 def canonical(call_name: str, result):
-    """A response's ``results[0]`` in the reference's canonical form;
-    raises on any other shape."""
+    """A response's ``results[0]`` in the reference's canonical form (a
+    row of a keyed field by its key); raises on any other shape."""
     if call_name == "Count":
         if type(result) is not int:
             raise ValueError(f"Count gave {result!r}")
@@ -35,11 +35,11 @@ def canonical(call_name: str, result):
     if call_name in ("Sum", "Min", "Max"):
         return (result["value"], result["count"])
     if call_name == "TopN":
-        return [(p["id"], p["count"]) for p in result]
+        return [(p.get("key", p["id"]), p["count"]) for p in result]
     if call_name == "GroupBy":
         out = {}
         for r in result:
-            ids = tuple(g["row_id"] for g in r["group"])
+            ids = tuple(g.get("row_key", g["row_id"]) for g in r["group"])
             if ids in out:
                 raise ValueError(f"group {ids} twice")
             out[ids] = (r["count"], r.get("agg"))

@@ -2,9 +2,10 @@
 
 The reference has to know what a query asks without the program's
 parser.  ``parse`` turns one call into a ``Call``: positional
-arguments (calls or bare names), keyword arguments (integers, names or
-calls) and conditions (``age > 40``).  Anything else is an error, so a
-traffic file that leaves this subset fails before any load.
+arguments (calls or bare names), keyword arguments (integers, names,
+double-quoted strings or calls) and conditions (``age > 40``).
+Anything else is an error, so a traffic file that leaves this subset
+fails before any load.
 """
 
 from __future__ import annotations
@@ -12,7 +13,8 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-_TOKEN = re.compile(r"\s*([A-Za-z_][A-Za-z0-9_]*|-?\d+|>=|<=|==|!=|[(),=<>])")
+_TOKEN = re.compile(
+    r'\s*([A-Za-z_][A-Za-z0-9_]*|-?\d+|"[^"\\]*"|>=|<=|==|!=|[(),=<>])')
 OPS = (">", "<", ">=", "<=", "==", "!=")
 
 
@@ -52,6 +54,8 @@ def _value(toks, pos, text):
     tok = toks[pos]
     if re.fullmatch(r"-?\d+", tok):
         return int(tok), pos + 1
+    if tok[0] == '"':
+        return tok[1:-1], pos + 1
     if pos + 1 < len(toks) and toks[pos + 1] == "(":
         return _call(toks, pos, text)
     return tok, pos + 1
@@ -70,6 +74,8 @@ def _call(toks, pos, text):
         elif nxt in OPS:
             call.conds.append((toks[pos], nxt, int(toks[pos + 2])))
             pos += 3
+        elif toks[pos][0] == '"':
+            raise PqlError(f"a string as an argument in {text!r}")
         else:
             arg, pos = _value(toks, pos, text)
             call.args.append(arg)

@@ -1,14 +1,38 @@
 """The system under test, as an operator gets it, and the harness's view of it.
 
-Copied from ``chip_smoke.py`` (which ran on the chip in PR 21) and
-generalised only so far that a configuration file names the fields:
-device check, compile-cache placing, native build, ``build_server``
-with the default config, schema over HTTP, bulk load through
-``Fragment.import_row_words``, a small HTTP client, one ``/metrics.json``
-scrape as an object, and the compile counter.
+Copied from ``chip_smoke.py`` (which ran on the chip in PR 21): device
+check, compile-cache placing, native build, ``build_server`` with the
+default config, schema over HTTP, bulk load through the fragments'
+own imports, a small HTTP client, one ``/metrics.json`` scrape as an
+object, and the compile counter.
 
 Only this module (and ``run.py``, which calls it) touches JAX and the
 program.  The load generator is a child process that imports neither.
+
+**What a deployment brings, and what the harness reads of it.**  A
+configuration file names its ``generator`` (``generators/<name>.py``)
+and carries ``params``.  Of ``params`` the harness reads ``index``,
+``shards``, ``rehearsal_shards`` and the field list
+(``harness/fields.py``: ``name``, ``type``, ``options``, ``rows``);
+everything else there is the generator's.  The generator exports
+
+- ``make_shard(params, seed, shard) -> (rows, tables)``: one shard's
+  data and its additive part of the reference's tables.  ``rows[field]``
+  is in the form the generator made it: ``{row id: packed uint32
+  words}`` (``Fragment.import_row_words``); ``(row ids, column ids)``
+  (``import_mutex`` on a ``mutex`` or ``bool`` field, ``import_bits``
+  on any other); or, on an ``int`` field, ``(column ids, values)``
+  (``import_values`` at the field's depth).  Column ids are within the
+  shard.  An ``int`` field loads into its BSI view, every other into
+  the standard view (a ``time`` field's quantum views and a ``keys``
+  field's translation are not loaded: no cell asks for them yet).
+  Every column of a loaded shard exists, unless ``rows["_exists"]``
+  (words, row 0) says which do;
+- ``add_tables(total, part)`` and ``drop_columns(tables, part)``: the
+  sum over shards, and the sum without one shard (the stale-shard
+  control);
+- ``Reference(params, tables).answer(call)``: the plain answer to a
+  ``harness.pql.Call`` in ``harness.check.canonical``'s form.
 """
 
 from __future__ import annotations
@@ -21,6 +45,10 @@ import shutil
 import subprocess
 import sys
 from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+from harness import fields
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -195,17 +223,19 @@ def start(config: dict):
     srv = build_server(cfg).start()
     params = config["params"]
     http_ = Http(srv.port, params["index"])
-    bsi = params["bsi"]
-    fields = [{"name": f["name"],
-               "options": {"type": "set", "cache_type": "none"}}
-              for f in (*params["plain"], *params["categorical"])]
-    fields.append({"name": bsi["name"], "options": {
-        "type": "int", "min": 0, "max": (1 << bsi["depth"]) - 1}})
+    want = fields.schema_fields(params)
     http_.call("POST", "/schema", {"indexes": [
-        {"name": params["index"], "fields": fields}]})
-    got = http_.call("GET", "/schema")["indexes"][0]
-    if len(got["fields"]) != len(fields):
-        raise BenchError(f"schema came back as {got}")
+        {"name": params["index"], "fields": want}]})
+    got = {f["name"]: f["options"]
+           for f in http_.call("GET", "/schema")["indexes"][0]["fields"]}
+    for f in want:
+        back = got.pop(f["name"], None)
+        if back is None or any(back.get(k) != v
+                               for k, v in f["options"].items()):
+            raise BenchError(f"field {f['name']} was posted as "
+                             f"{f['options']} and came back as {back}")
+    if got:
+        raise BenchError(f"the schema came back with {sorted(got)} besides")
     serving = srv.api.executor.serving
     if not (serving is not None and serving.batching
             and serving.cache is not None):
@@ -215,20 +245,37 @@ def start(config: dict):
 
 def load(srv, config: dict, generator, seed: int, shards: int,
          skip_shards: frozenset = frozenset()):
-    """Generate every shard from the seed on all cores and bulk-load
-    it (Fragment.import_row_words, the restore path).  Returns the
-    summed reference tables.  Shards in `skip_shards` are generated
-    and counted by the reference but never reach the server: the
-    control's broken guarantee (an acknowledged import that no read
-    sees)."""
+    """Generate every shard from the seed on all cores and hand each
+    field's rows to its fragment in the form the generator made them
+    (the module docstring's three forms; the restore path, nothing
+    over HTTP).  Returns the summed reference tables.  Shards in
+    `skip_shards` are generated and counted by the reference but never
+    reach the server: the control's broken guarantee (an acknowledged
+    import that no read sees)."""
     from pilosa_tpu.models.index import EXISTENCE_FIELD
+    from pilosa_tpu.models.schema import FieldType
     from pilosa_tpu.models.view import VIEW_STANDARD
     params = config["params"]
     idx = srv.holder.index(params["index"])
-    idx._ensure_existence()     # every column exists, as in a restore
-    bsi_name = params["bsi"]["name"]
-    views = {name: f.view(f.bsi_view if name == bsi_name else VIEW_STANDARD,
-                          create=True) for name, f in idx.fields.items()}
+    idx._ensure_existence()
+    views = {name: f.view(f.bsi_view if f.options.type.is_bsi
+                          else VIEW_STANDARD, create=True)
+             for name, f in idx.fields.items()}
+    every = {0: np.full(idx.width // 32, 0xFFFFFFFF, dtype=np.uint32)}
+
+    def hand_over(shard, name, data):
+        frag = views[name].fragment(shard, create=True)
+        field = idx.fields[name]
+        if isinstance(data, dict):
+            for r, w in data.items():
+                frag.import_row_words(r, w)
+        elif field.options.type.is_bsi:
+            frag.import_values(*data, field.bit_depth)
+        elif field.options.type in (FieldType.MUTEX, FieldType.BOOL):
+            frag.import_mutex(*data)
+        else:
+            frag.import_bits(*data)
+
     tables = None
     workers = max(1, min(12, os.cpu_count() or 1))
     with ThreadPoolExecutor(workers) as pool:
@@ -239,10 +286,8 @@ def load(srv, config: dict, generator, seed: int, shards: int,
                 tables = generator.add_tables(tables, part)
                 if shard in skip_shards:
                     continue
-                # existence and the BSI not-null row: every column
-                rows[EXISTENCE_FIELD] = {0: rows[bsi_name][0]}
-                for f, frows in rows.items():
-                    frag = views[f].fragment(shard, create=True)
-                    for r, w in frows.items():
-                        frag.import_row_words(r, w)
+                for name, data in rows.items():
+                    hand_over(shard, name, data)
+                if EXISTENCE_FIELD not in rows:
+                    hand_over(shard, EXISTENCE_FIELD, every)
     return tables

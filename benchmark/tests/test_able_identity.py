@@ -1,0 +1,72 @@
+"""``able-1b`` is untouched in effect by a change to the harness: the
+body ``start`` posts to ``/schema`` and the bits ``load`` leaves in
+every fragment, at the rehearsal size and seed 2147483777, equal what
+the parent of PR 29 (commit 04b8d07) posted and left.  The guard
+against a benchmark that moved.
+
+``data/able-1b.identity.json`` was recorded from that commit, with
+this file copied into a checkout of it, by
+
+    JAX_PLATFORMS=cpu python benchmark/tests/test_able_identity.py > benchmark/tests/data/able-1b.identity.json
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 2147483777
+
+
+def fingerprint() -> dict:
+    from harness import server
+    config = server.load_json("configs", "able-1b.json")
+    params = config["params"]
+    gen = server.load_module("generators", config["generator"])
+    posted = []
+    real = server.Http.call
+
+    def call(self, method, path, body=None):
+        if (method, path) == ("POST", "/schema"):
+            posted.append(json.dumps(body))
+        return real(self, method, path, body)
+
+    server.Http.call = call
+    try:
+        srv, http_ = server.start(config)
+    finally:
+        server.Http.call = real
+    try:
+        server.load(srv, config, gen, SEED, params["rehearsal_shards"])
+        digest, n = hashlib.sha256(), 0
+        fields = srv.holder.index(params["index"]).fields
+        for fname in sorted(fields):
+            for vname, view in sorted(fields[fname].views.items()):
+                for shard, frag in sorted(view.fragments.items()):
+                    for row in frag.row_ids:
+                        digest.update(
+                            f"{fname}/{vname}/{shard}/{row}".encode())
+                        digest.update(np.ascontiguousarray(
+                            frag.row_words(row), dtype=np.uint32).tobytes())
+                        n += 1
+    finally:
+        http_.close()
+        srv.close()
+    return {"seed": SEED, "shards": params["rehearsal_shards"],
+            "schema_body": posted[0], "rows": n,
+            "row_words_sha256": digest.hexdigest()}
+
+
+def test_able_posts_the_same_schema_and_loads_the_same_bits():
+    with open(os.path.join(HERE, "data", "able-1b.identity.json")) as f:
+        recorded = json.load(f)
+    assert fingerprint() == recorded
+
+
+if __name__ == "__main__":
+    for path in (os.path.dirname(HERE), os.path.dirname(os.path.dirname(HERE))):
+        sys.path.insert(0, path)
+    print(json.dumps(fingerprint(), indent=1))
