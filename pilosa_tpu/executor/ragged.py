@@ -552,7 +552,7 @@ class RaggedProgram:
             if rsub[0] == "gb_hist":
                 # pallas arms can't lower inside the shard_map body;
                 # the XLA arm is the same math, bit-exact
-                rsub = rsub[:6] + ("xla",)
+                rsub = rsub[:6] + ("xla",) + rsub[7:]
             i = sub_ix.get(rsub)
             if i is None:
                 s, _sp, _sel = _geometry(gidx)

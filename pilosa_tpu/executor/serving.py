@@ -1327,6 +1327,7 @@ class ServingLayer:
         device program — a GroupBy rider costs the batch ONE
         single-pass tile walk, not its own dispatch (ISSUE 11)."""
         from pilosa_tpu.executor.stacked import (
+            _code_digits,
             _code_space,
             _combo_codes,
             _onepass_arm,
@@ -1393,8 +1394,10 @@ class ServingLayer:
         planes_i = (b._planes_leaf(agg_field)
                     if agg_field is not None else None)
         GROUPBY_ONEPASS.inc()
+        digits = _code_digits(fields_rows)
         if arm == "fused":
-            GROUPBY_FUSED.inc(path="batched")
+            GROUPBY_FUSED.inc(path="batched", body=kernels.fused_body(
+                digits, depth, signed))
         has_planes = agg_field is not None
 
         def demux_groupby(out):
@@ -1409,7 +1412,7 @@ class ServingLayer:
                 "sum", agg_nn, agg_pos, agg_neg, None, None, None,
                 None)]
         return (("gb_hist", cg_i, tree, planes_i, n_codes, signed,
-                 arm), demux_groupby)
+                 arm, digits), demux_groupby)
 
     def _row_result(self, idx, shards: list[int], words) -> RowResult:
         """Mirror Executor._bitmap_result + the translateResults key

@@ -42,6 +42,7 @@ local split has the same shape: fast path plus fallback).
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import logging
 import os
@@ -1229,6 +1230,13 @@ def _code_space(fields_rows):
     return bits, shifts, 1 << acc
 
 
+def _code_digits(fields_rows) -> tuple:
+    """((bits, rows), ...) per field in _code_space order: the static
+    layout kernels.groupby_fused visits only the live codes by."""
+    bits, _shifts, _n_codes = _code_space(fields_rows)
+    return tuple((b, len(rl)) for b, (_, rl) in zip(bits, fields_rows))
+
+
 def _groupby_unit_costs(fields_rows, n_combos: int, depth: int,
                         has_agg: bool, n_shards: int,
                         width_words: int) -> tuple[float, float]:
@@ -1263,8 +1271,9 @@ def _onepass_arm(n_codes: int, depth: int,
                  minmax: bool = False) -> str:
     """Which one-pass device program serves the histogram:
 
-    - "fused"  — the int8 MXU popcount-accumulate single-pass kernel
-      (groupby_fused; the default on TPU, ISSUE 11)
+    - "fused"  — the single-pass kernel on packed words
+      (groupby_fused; the default on TPU; its body, packed or
+      one-hot, is kernels.fused_body's choice from the shapes)
     - "onehot" — the first-generation f32 one-hot matmul kernel (the
       A/B arm; PILOSA_TPU_GROUPBY_FUSED=0, no Min/Max support)
     - "xla"    — the scatter-add reference (the bit-exactness oracle
@@ -1291,10 +1300,13 @@ def _onepass_arm(n_codes: int, depth: int,
     return "fused"
 
 
-def _onepass_gb(arm: str):
-    """The arm's histogram callable (shared by jit + shard_map)."""
-    return {"fused": kernels.groupby_fused,
-            "onehot": kernels.groupby_onehot,
+def _onepass_gb(arm: str, digits=None):
+    """The arm's histogram callable (shared by jit + shard_map).
+    `digits` (_code_digits) tells the fused kernel which codes are
+    live; the other arms histogram the whole code space."""
+    if arm == "fused":
+        return functools.partial(kernels.groupby_fused, digits=digits)
+    return {"onehot": kernels.groupby_onehot,
             "xla": kernels.groupby_codes_xla}[arm]
 
 
@@ -1317,11 +1329,11 @@ def _onepass_unpack(flat, n_codes: int, depth: int, has_planes: bool,
 
 def _groupby_onepass_jit(arm: str, has_planes: bool,
                          has_filter: bool, signed: bool, n_codes: int,
-                         minmax: bool = False):
+                         minmax: bool = False, digits=None):
     """Single-device jitted one-pass program: group-code stack in,
     ONE flat histogram array out (one fetch round trip)."""
     key = ("onepass", arm, has_planes, has_filter, signed,
-           n_codes, minmax)
+           n_codes, minmax, digits)
     fn = _gb_jit_get(key)
     if fn is not None:
         return fn
@@ -1331,7 +1343,7 @@ def _groupby_onepass_jit(arm: str, has_planes: bool,
         cp, valid = cg[:, :-1], cg[:, -1]
         if has_filter:
             valid = jnp.bitwise_and(valid, filt)
-        gb = _onepass_gb(arm)
+        gb = _onepass_gb(arm, digits)
         if minmax:
             c, n, p, g, mm = gb(cp, valid, planes, n_codes, signed,
                                 minmax=True)
@@ -1349,7 +1361,7 @@ def _groupby_onepass_jit(arm: str, has_planes: bool,
 
 def _groupby_onepass_shard_map(mesh, arm: str, has_planes: bool,
                                has_filter: bool, signed: bool,
-                               n_codes: int):
+                               n_codes: int, digits=None):
     """Mesh one-pass wrapper: every device histograms its local shard
     slice of the flat-placed group-code stack, partial (K, G) tables
     psum over the whole mesh — the histogram is combo-count-free, so
@@ -1360,7 +1372,7 @@ def _groupby_onepass_shard_map(mesh, arm: str, has_planes: bool,
     from pilosa_tpu.parallel.mesh import shard_map_nocheck
 
     key = ("onepass_mesh", id(mesh), arm, has_planes,
-           has_filter, signed, n_codes)
+           has_filter, signed, n_codes, digits)
     fn = _gb_jit_get(key)
     if fn is not None:
         return fn
@@ -1378,7 +1390,7 @@ def _groupby_onepass_shard_map(mesh, arm: str, has_planes: bool,
         cp, valid = cg[:, :-1], cg[:, -1]
         if filt is not None:
             valid = jnp.bitwise_and(valid, filt)
-        gb = _onepass_gb(arm)
+        gb = _onepass_gb(arm, digits)
         c, n, p, g = gb(cp, valid, planes, n_codes, signed)
         flat = c if not has_planes else jnp.concatenate(
             [c, n, p.ravel(), g.ravel()])
@@ -1731,7 +1743,8 @@ def _plan_run(plan, kern: bool = False):
             return cnt, pos, neg
     elif kind == "gb_hist":
         # plan: ("gb_hist", cg_i, tree|None, planes_i|None, n_codes,
-        #        signed, arm) — the one-pass group-code histogram as a
+        #        signed, arm, digits) — the one-pass group-code
+        #        histogram (digits: _code_digits, the live codes) as a
         #        BATCHABLE subplan (ISSUE 11): a GroupBy rider inside
         #        a fused "multi"/"ragged" program evaluates the same
         #        single-pass tile walk as the solo one-pass path (arm
@@ -1739,7 +1752,7 @@ def _plan_run(plan, kern: bool = False):
         #        gathers its combos out of the flat (K*G,) table.
         #        Unlike "groupby" it reads nothing from params[-1], so
         #        it composes with any other subplan.
-        cg_i, tree, planes_i, n_codes, signed, arm = plan[1:7]
+        cg_i, tree, planes_i, n_codes, signed, arm, digits = plan[1:8]
 
         def run(leaves, params):
             cg = leaves[cg_i]                     # (S, CB+1, W)
@@ -1748,8 +1761,8 @@ def _plan_run(plan, kern: bool = False):
                 filt = _filter(tree, leaves, params)
                 valid = jnp.bitwise_and(valid, filt)
             planes = leaves[planes_i] if planes_i is not None else None
-            c, n, p, g = _onepass_gb(arm)(cp, valid, planes, n_codes,
-                                          signed)
+            c, n, p, g = _onepass_gb(arm, digits)(
+                cp, valid, planes, n_codes, signed)
             if planes_i is None:
                 return c
             return jnp.concatenate([c, n, p.ravel(), g.ravel()])
@@ -3374,6 +3387,7 @@ class StackedEngine:
         GROUPBY_ONEPASS.inc()
         minmax = agg_op in ("min", "max")
         bits, shifts, n_codes = _code_space(fields_rows)
+        digits = _code_digits(fields_rows)
         combos_arr = np.asarray(combos, dtype=np.int64).reshape(
             len(combos), len(fields_rows))
         codes = _combo_codes(shifts, combos_arr)
@@ -3412,14 +3426,16 @@ class StackedEngine:
         elif multi and not minmax:
             arm = _onepass_arm(n_codes, depth)
             if arm == "fused":
-                GROUPBY_FUSED.inc(path="onepass_mesh")
+                GROUPBY_FUSED.inc(
+                    path="onepass_mesh", body=kernels.fused_body(
+                        digits, depth if has_planes else 0, signed))
             cg = self.groupcode_stack(idx, fields_rows, skey,
                                       flat=True)
             planes = (self.plane_stack_flat(idx, agg_field, skey)
                       if has_planes else None)
             fn = _groupby_onepass_shard_map(
                 self.mesh, arm,
-                has_planes, filt is not None, signed, n_codes)
+                has_planes, filt is not None, signed, n_codes, digits)
             args = [cg]
             if filt is not None:
                 # the filter tree ran under the 1D shard placement;
@@ -3432,7 +3448,7 @@ class StackedEngine:
             if has_planes:
                 args.append(planes)
             sig = ("onepass_mesh", arm, has_planes, filt is not None,
-                   signed, n_codes)
+                   signed, n_codes, digits)
             kind = _dispatch_kind(sig, args, ())
             out, dt = timed_call(kind, fn, *args)
             if kind == "execute":
@@ -3451,15 +3467,19 @@ class StackedEngine:
                 # GSPMD — keep the rare mesh Min/Max on it
                 arm = "xla"
             if arm == "fused":
-                GROUPBY_FUSED.inc(path="onepass")
+                GROUPBY_FUSED.inc(
+                    path="onepass", body=kernels.fused_body(
+                        digits, depth if has_planes else 0, signed,
+                        minmax))
             cg = self.groupcode_stack(idx, fields_rows, skey)
             planes = (self.plane_stack(idx, agg_field, skey)
                       if has_planes else None)
             fn = _groupby_onepass_jit(
                 arm, has_planes,
-                filt is not None, signed, n_codes, minmax=minmax)
+                filt is not None, signed, n_codes, minmax=minmax,
+                digits=digits)
             sig = ("onepass", arm, has_planes, filt is not None,
-                   signed, n_codes, minmax)
+                   signed, n_codes, minmax, digits)
             args = [a for a in (cg, filt, planes) if a is not None]
             kind = _dispatch_kind(sig, args, ())
             out, dt = timed_call(kind, fn, cg, filt, planes)

@@ -376,8 +376,11 @@ GROUPBY_ONEPASS = registry.counter(
     "GroupBy queries served by the one-pass group-code histogram")
 GROUPBY_FUSED = registry.counter(
     "pilosa_groupby_fused_total",
-    "One-pass GroupBy dispatches served by the fused int8 MXU "
-    "single-pass kernel, by path (onepass/onepass_mesh/batched)")
+    "One-pass GroupBy dispatches served by the fused single-pass "
+    "kernel, by path (onepass/onepass_mesh/batched) and by the body "
+    "the shapes take (packed: masks ANDed and popcounted on packed "
+    "words / onehot: the one-hot MXU body, where the packed "
+    "accumulators would not fit VMEM)")
 
 # -- tile-stack maintenance (executor/stacked.py TileStackCache) --
 # Outcomes: hit (fresh entry), miss (any non-hit), patch (stale entry

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from pilosa_tpu.ops import bitmap as bm
 
@@ -73,7 +74,8 @@ def enabled() -> bool:
 
 
 def _pc(x):
-    return jax.lax.population_count(x).astype(jnp.int32)
+    return jax.lax.convert_element_type(jax.lax.population_count(x),
+                                        jnp.int32)
 
 
 def _pad_rows(x, block):
@@ -423,8 +425,6 @@ def groupby_sum(stacks, sel, planes=None, signed=True):
     Per-combo totals accumulate across shard tiles in int32 (exact
     below ~2k shards; callers above that use the unreduced XLA path).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     nf = len(stacks)
     c_dim, nf2 = sel.shape
     assert nf2 == nf and nf >= 1
@@ -538,7 +538,7 @@ def groupby_codes_xla(code_planes, valid, planes=None, n_codes: int = 1,
     """
     depth = 0 if planes is None else planes.shape[1] - 2
     assert not (minmax and depth == 0), "minmax requires BSI planes"
-    k = 1 if depth == 0 else 2 + (2 if signed else 1) * depth
+    k = _payload_rows(depth, signed)
     big = 1 << depth
 
     def one_shard(acc, args):
@@ -685,7 +685,7 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
         code_planes = jnp.zeros((s_dim, 1, w_dim), dtype=jnp.uint32)
         cb = 1
     depth = 0 if planes is None else planes.shape[1] - 2
-    k = 1 if depth == 0 else 2 + (2 if signed else 1) * depth
+    k = _payload_rows(depth, signed)
     g_pad = max(-(-int(n_codes) // 128) * 128, 128)
     # word block sized so the per-step (BW, G) one-hot stays ~2 MB f32
     bw = min(w_dim, max(128, (1 << 19) // g_pad))
@@ -720,44 +720,236 @@ def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# fused single-pass GroupBy: int8 MXU popcount-accumulate
+# fused single-pass GroupBy: per-group masks ANDed and popcounted on
+# packed words
 # ---------------------------------------------------------------------------
 #
-# Second-generation one-pass kernel (ISSUE 11).  groupby_onehot above
-# walks the 32 bit positions of each word block and pays one f32
-# (K, BW) @ (BW, G) matmul PER BIT — 32 MXU launches per tile, with
-# f32 one-hot operands 4x the bytes they need.  groupby_fused flattens
-# bit-position chunks into the contraction axis and accumulates the
-# whole (K, G) histogram with int8 @ int8 -> int32 MXU dots — a
-# popcount computed by the matrix unit (the dot of two 0/1 int8
-# vectors IS popcount(a & b)), 4x the MXU throughput of the f32 path
-# and a handful of launches per tile instead of 32.  Each (lanes,
-# words) stack tile crosses VMEM exactly once and simultaneously
-# yields:
+# One pass over the operands, like groupby_onehot above, but the inner
+# body never leaves the packed domain (ISSUE 32).  Per (shard, word
+# block) grid step:
 #
-#   - the group-code histogram (counts),
-#   - validity counts (nn) and per-group BSI Sum sign-split plane
-#     partials (pos/neg) — identical layout to groupby_codes_xla,
-#   - optionally per-group Min/Max: each column's magnitude is
-#     rebuilt from its plane bits and reduced per group with one
-#     masked max/min over the same one-hot, on the VPU,
-#   - and (as a byproduct of the same tile walk) fused Range/Distinct
-#     over BSI planes: bsi_value_hist() below runs THIS kernel with
-#     the magnitude+sign planes as the code planes, so the dense
-#     per-value histogram — distinct values, min/max, and arbitrary
-#     range counts — falls out of one single-pass walk.
+#   0. every plane of the block is re-laid out in VMEM so that its
+#      words fill whole (8, 128) vregs (the (1, P, BW) block arrives
+#      with the planes along sublanes: one sublane of eight per plane);
+#   1. every LIVE group's column mask is formed from the fields' digit
+#      planes — a row of a field is the AND of its digit planes or
+#      their complements, `valid` is ANDed into the first field's rows,
+#      and the fields multiply out as a tree (6 -> 12 -> 60 masks for
+#      the able query), each level one array of masks — and parked in
+#      a VMEM scratch;
+#   2. per group the payload rows are popcounts of ANDs: popcount(m),
+#      popcount(m & exists), popcount(m & exists [& ~sign | & sign] &
+#      plane_p) — three word operations per (group, row) for 32
+#      columns — folded over the block's vregs in registers and added
+#      once per grid step into the group's int32 lane partials;
 #
-# Exactness: per-chunk partial sums are <= bc*BW*32 < 2^24 terms of
-# {0, 1} products accumulated in int32 — exact; cross-tile
-# accumulation is int32 (callers bound shards like the other paths).
+# The lane partials leave the kernel as they are, by one copy on the
+# last grid step, and one small XLA reduce makes the dense (K, G)
+# table of them; codes whose digit exceeds its field's row count are
+# never visited and stay 0.
+#
+# The accumulators grow with live groups x payload rows.  Where they
+# do not fit the VMEM a kernel may ask for (_PACKED_VMEM_BYTES), and
+# for Min/Max (which the packed body does not compute: ROADMAP A3),
+# the earlier body serves:
+# every column unpacked to an int32 code, compared with a 128-lane
+# iota into a one-hot and contracted against the 0/1 payload rows on
+# the MXU (int8 @ int8 -> int32), Min/Max as masked reductions over
+# the same one-hot.  fused_body() is the one place that decides, from
+# the static arguments alone.
+#
+# Exactness: popcounts of {0, 1} columns accumulated in int32 lane
+# partials and summed in int32 (callers bound shards like the other
+# paths); the one-hot body's per-chunk partial sums are <= bc*BW*32
+# < 2^24 terms.
+
+_VREG_WORDS = 8 * _LANES      # one (8, 128) vreg of packed words
+# what the packed body may ask of the 16 MiB of scoped VMEM a v5e
+# kernel gets by default; the rest is Mosaic's own
+_PACKED_VMEM_BYTES = 13 << 20
+_PACKED_BLOCK_VREGS = 16      # vregs of each plane per grid step, at most
 
 
-def _gb_fused_kernel(cb: int, depth: int, signed: bool, k: int,
-                     g_pad: int, bw: int, bc: int, minmax: bool):
-    """Kernel body factory.  Per (shard, word-block) grid step the 32
-    bit positions are processed in chunks of `bc`; each chunk is one
-    flattened (bc*bw,) column axis shared by the int8 payload matmul
-    and (when requested) the Min/Max masked reductions."""
+def _live_codes(digits) -> list[int]:
+    """Dense codes of the groups the packed body visits, in the order
+    it visits them (first field fastest)."""
+    codes, shift = [0], 0
+    for bits, rows in digits:
+        codes = [c | (r << shift) for r in range(rows) for c in codes]
+        shift += bits
+    return codes
+
+
+def _payload_rows(depth: int, signed: bool) -> int:
+    """K of the shared (K, G) layout: counts, then nn and the
+    sign-split plane partials when there is a BSI field."""
+    return 1 if depth == 0 else 2 + (2 if signed else 1) * depth
+
+
+def _packed_block_vregs(digits, depth: int, signed: bool) -> int:
+    """Vregs of each plane the packed body takes per grid step: the
+    most (a power of two up to _PACKED_BLOCK_VREGS) at which what it
+    keeps in VMEM fits _PACKED_VMEM_BYTES, 0 when not even one does.
+    Counted in vregs: the accumulators (live groups x payload rows, +
+    one each for the mask tree's inner levels), and per block vreg the
+    mask scratch (live groups), the operand blocks (double-buffered,
+    planes padded to 8 sublanes) and their re-laid-out copy."""
+    n_live = int(np.prod([rows for _, rows in digits], dtype=np.int64))
+    cb = max(sum(bits for bits, _ in digits), 1)
+    groups = [cb, 1] + ([2 + depth] if depth else [])
+    acc = n_live * (_payload_rows(depth, signed) + 1)
+    per_vreg = (n_live + sum(groups)
+                + 2 * sum(-(-g // 8) * 8 for g in groups))
+    nv = _PACKED_BLOCK_VREGS
+    while nv and 4 * _VREG_WORDS * (
+            acc + nv * per_vreg) > _PACKED_VMEM_BYTES:
+        nv //= 2
+    return nv
+
+
+def fused_body(digits, depth: int, signed: bool = True,
+               minmax: bool = False) -> str:
+    """Which body of groupby_fused serves these static arguments:
+    "packed" for counts and Sum where its accumulators fit the
+    kernel's VMEM, else "onehot" (Min/Max always).  `digits` is
+    ((bits, rows), ...) per GroupBy field (the _code_space layout with
+    each field's row count).  Called at trace time by groupby_fused
+    and at dispatch by the pilosa_groupby_fused_total{body=} sites, so
+    both agree."""
+    if minmax:
+        return "onehot"
+    return ("packed" if _packed_block_vregs(digits, depth, signed)
+            else "onehot")
+
+
+def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int):
+    """Packed body factory (see the block comment above)."""
+    cb = sum(bits for bits, _ in digits)
+    # lax primitives, not jnp's jitted wrappers (each a nested pjit to
+    # trace and lower), and `step` vregs of words to an operation:
+    # what Mosaic has to lower stays in the hundreds of operations
+    _and, _not = jax.lax.bitwise_and, jax.lax.bitwise_not
+    step = next(u for u in (4, 2, 1) if nv % u == 0)
+
+    def kernel(cp_ref, va_ref, *refs):
+        pl_ref = refs[0] if depth else None
+        out_ref, acc_ref, masks_ref, dense_ref = refs[1 if depth else 0:]
+        s, wi = pl.program_id(0), pl.program_id(1)
+
+        @pl.when((s == 0) & (wi == 0))
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        # 0. planes along sublanes -> each plane's words in whole vregs
+        srcs = [(cp_ref, b) for b in range(cb)] + [(va_ref, 0)] + [
+            (pl_ref, p) for p in range(2 + depth if depth else 0)]
+        for i, (ref, p) in enumerate(srcs):
+            dense_ref[i] = ref[0, p, :].reshape(8 * nv, _LANES)
+
+        # 1. the live groups' masks, one vreg of words at a time.  A
+        # level of the tree is one array of masks, (n, 8, 128): the
+        # operations to lower number the fields' rows, not the groups
+        def mask_step(v, carry):
+            rs = pl.ds(pl.multiple_of(v * 8, 8), 8)
+            masks, b0 = dense_ref[pl.ds(cb, 1), rs, :], 0
+            for bits, rows in digits:
+                if bits:
+                    one = [dense_ref[pl.ds(b0 + b, 1), rs, :]
+                           for b in range(bits)]
+                    zero = [_not(x) for x in one]
+                    level = []
+                    for r in range(rows):
+                        lit = None
+                        for b in range(bits):
+                            t = one[b] if (r >> b) & 1 else zero[b]
+                            lit = t if lit is None else _and(lit, t)
+                        level.append(_and(masks, lit))
+                    masks = jax.lax.concatenate(level, 0)
+                b0 += bits
+            masks_ref[:, rs, :] = masks
+            return carry
+
+        jax.lax.fori_loop(0, nv, mask_step, 0)
+
+        # 2. per group: popcounts of ANDs, `step` vregs of words to an
+        # operation, folded over the block in registers; one VMEM add
+        # per (group, row)
+        def group(j, carry):
+            hist = [None] * k
+            for c in range(nv // step):
+                rs = slice(8 * step * c, 8 * step * (c + 1))
+                m = masks_ref[j, rs, :]
+                words = [m]
+                if depth:
+                    em = _and(m, dense_ref[cb + 1, rs, :])
+                    words.append(em)
+                    mags = [dense_ref[cb + 3 + p, rs, :]
+                            for p in range(depth)]
+                    sides = [em]
+                    if signed:
+                        sg = dense_ref[cb + 2, rs, :]
+                        sides = [_and(em, _not(sg)), _and(em, sg)]
+                    for side in sides:
+                        words += [_and(side, mg) for mg in mags]
+                for r, x in enumerate(words):
+                    n = _pc(x)
+                    if step > 1:
+                        n = jnp.sum(n.reshape(step, 8, _LANES), axis=0)
+                    hist[r] = n if hist[r] is None \
+                        else jax.lax.add(hist[r], n)
+            for r in range(k):
+                acc_ref[j, r] += hist[r]
+            return carry
+
+        jax.lax.fori_loop(0, masks_ref.shape[0], group, 0)
+
+        @pl.when((s == pl.num_programs(0) - 1)
+                 & (wi == pl.num_programs(1) - 1))
+        def _flush():
+            pltpu.sync_copy(acc_ref, out_ref)
+    return kernel
+
+
+def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
+                     signed: bool, n_codes: int):
+    """groupby_fused's packed body: returns the dense (K, G) table."""
+    s_dim, _cb, w_dim = code_planes.shape
+    k = _payload_rows(depth, signed)
+    live = _live_codes(digits)
+    nv = min(_packed_block_vregs(digits, depth, signed),
+             -(-w_dim // _VREG_WORDS))
+    bw = nv * _VREG_WORDS
+    # zero padding is neutral: valid is ANDed into every mask
+    arrays = [_pad_axis(x, 2, bw) for x in (
+        code_planes, valid[:, None, :]) + ((planes,) if depth else ())]
+    # the accumulators are scratch, so the VMEM asked for is what
+    # _packed_block_vregs counted; they leave by one copy at the end
+    table = (len(live), k, 8, _LANES)
+    out = pl.pallas_call(
+        _gb_packed_kernel(digits, depth, signed, k, nv),
+        grid=(s_dim, arrays[0].shape[2] // bw),
+        in_specs=[pl.BlockSpec((1, x.shape[1], bw),
+                               lambda s, w: (s, 0, w)) for x in arrays],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(table, jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM(table, jnp.int32),
+            pltpu.VMEM((len(live), 8 * nv, _LANES), jnp.uint32),
+            pltpu.VMEM((sum(x.shape[1] for x in arrays), 8 * nv,
+                        _LANES), jnp.uint32)],
+        name="groupby_fused_sum",
+        interpret=_interpret(),
+    )(*arrays)
+    return jnp.zeros((k, n_codes), jnp.int32).at[:, np.asarray(live)].set(
+        jnp.sum(out, axis=(2, 3)).T)
+
+
+def _gb_fused_onehot_kernel(cb: int, depth: int, signed: bool, k: int,
+                            g_pad: int, bw: int, bc: int, minmax: bool):
+    """One-hot body factory.  Per (shard, word-block) grid step the
+    32 bit positions are processed in chunks of `bc`; each chunk is
+    one flattened (bc*bw,) column axis shared by the int8 payload
+    matmul and (when requested) the Min/Max masked reductions."""
 
     def kernel(cp_ref, va_ref, *refs):
         pl_ref = refs[0] if depth else None
@@ -833,33 +1025,12 @@ def _gb_fused_kernel(cb: int, depth: int, signed: bool, k: int,
     return kernel
 
 
-def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
-                  signed: bool = True, minmax: bool = False):
-    """Fused single-pass GroupBy histogram — int8 MXU
-    popcount-accumulate (the ISSUE 11 tentpole kernel).
-
-    Same contract as :func:`groupby_codes_xla` (bit-exact against it,
-    against groupby_onehot, and against the host twins — the property
-    suite cross-checks all of them).  Returns (counts, nn, pos, neg)
-    and, with ``minmax=True`` (requires planes), additionally a
-    (4, G) int32 table [max_mag_pos, min_mag_pos, max_mag_neg,
-    min_mag_neg] with identities (-1 / 1<<depth) marking empty sides —
-    combine with :func:`minmax_from_table`.
-
-    Schedule: grid (S, W/BW) with NO combo axis — every code plane,
-    valid word, and BSI plane word streams through VMEM exactly once
-    and the (K, G) table (+ (4, G) Min/Max table) stays VMEM-resident
-    for the whole walk.  The combo dimension exists only inside a grid
-    step as the one-hot axis of int8 matmuls the MXU does for free
-    next to the bandwidth-bound stream.
-    """
+def _gb_fused_onehot(code_planes, valid, planes, depth: int,
+                     signed: bool, minmax: bool, n_codes: int):
+    """groupby_fused's one-hot body: returns the dense (K, G) table
+    and the (4, G) Min/Max table (or None)."""
     s_dim, cb, w_dim = code_planes.shape
-    if cb == 0:                        # all fields single-row: code 0
-        code_planes = jnp.zeros((s_dim, 1, w_dim), dtype=jnp.uint32)
-        cb = 1
-    depth = 0 if planes is None else planes.shape[1] - 2
-    assert not (minmax and depth == 0), "minmax requires BSI planes"
-    k = 1 if depth == 0 else 2 + (2 if signed else 1) * depth
+    k = _payload_rows(depth, signed)
     g_pad = max(-(-int(n_codes) // 128) * 128, 128)
     # word block + bit-chunk sized so the per-chunk int8 one-hot
     # (bc*bw, G) stays ~2 MB (Min/Max selects over it in int32, so a
@@ -889,7 +1060,8 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
         out_specs.append(pl.BlockSpec((4, g_pad), fixed))
         out_shape.append(jax.ShapeDtypeStruct((4, g_pad), jnp.int32))
     out = pl.pallas_call(
-        _gb_fused_kernel(cb, depth, signed, k, g_pad, bw, bc, minmax),
+        _gb_fused_onehot_kernel(cb, depth, signed, k, g_pad, bw, bc,
+                                minmax),
         grid=(s_dim, wpad // bw),
         in_specs=in_specs,
         out_specs=out_specs if minmax else out_specs[0],
@@ -898,17 +1070,64 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
               else "groupby_fused_sum"),
         interpret=_interpret(),
     )(*arrays)
-    hist = out[0] if minmax else out
-    counts = hist[0, :n_codes]
+    if not minmax:
+        return out[:, :n_codes], None
+    return out[0][:, :n_codes], out[1][:, :n_codes]
+
+
+def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
+                  signed: bool = True, minmax: bool = False,
+                  digits=None):
+    """Fused single-pass GroupBy histogram on packed words.
+
+    Same contract as :func:`groupby_codes_xla` (bit-exact against it,
+    against groupby_onehot, and against the host twins — the property
+    suite cross-checks all of them).  Returns (counts, nn, pos, neg)
+    and, with ``minmax=True`` (requires planes), additionally a
+    (4, G) int32 table [max_mag_pos, min_mag_pos, max_mag_neg,
+    min_mag_neg] with identities (-1 / 1<<depth) marking empty sides —
+    combine with :func:`minmax_from_table`.
+
+    ``digits`` is the fields' digit layout, ((bits, rows), ...) in
+    code-plane order (stacked._code_space with each field's row
+    count): only codes whose every digit is below its field's row
+    count are visited, the others stay 0 — which is what they hold
+    anyway when `valid` is the AND of the field unions.  Without it
+    every code of the CB planes is live (bsi_value_hist).
+
+    Schedule: grid (S, W/BW) with NO combo axis — every code plane,
+    valid word, and BSI plane word streams through VMEM exactly once
+    and the accumulators stay VMEM-resident for the whole walk.  Per
+    group the kernel ANDs the group's mask with the payload planes
+    and popcounts, 32 columns per word operation (the packed body);
+    shapes whose accumulators would not fit VMEM, and ``minmax``,
+    take the one-hot body instead (see the block comment above, and
+    fused_body).
+    """
+    s_dim, cb, w_dim = code_planes.shape
+    depth = 0 if planes is None else planes.shape[1] - 2
+    assert not (minmax and depth == 0), "minmax requires BSI planes"
+    if digits is None:
+        digits = ((1, 2),) * cb
+    digits = tuple((int(b), int(r)) for b, r in digits)
+    assert sum(b for b, _ in digits) == cb, (digits, cb)
+    if cb == 0:                        # all fields single-row: code 0
+        code_planes = jnp.zeros((s_dim, 1, w_dim), dtype=jnp.uint32)
+    if fused_body(digits, depth, signed, minmax) == "packed":
+        hist, mm = _gb_fused_packed(code_planes, valid, planes, digits,
+                                    depth, signed, n_codes), None
+    else:
+        hist, mm = _gb_fused_onehot(code_planes, valid, planes, depth,
+                                    signed, minmax, n_codes)
+    counts = hist[0]
     if depth == 0:
         return counts, None, None, None
-    nn = hist[1, :n_codes]
-    pos = hist[2:2 + depth, :n_codes].T                # (G, depth)
-    neg = (hist[2 + depth:, :n_codes].T if signed
-           else jnp.zeros_like(pos))
+    nn = hist[1]
+    pos = hist[2:2 + depth].T                          # (G, depth)
+    neg = hist[2 + depth:].T if signed else jnp.zeros_like(pos)
     if not minmax:
         return counts, nn, pos, neg
-    return counts, nn, pos, neg, out[1][:, :n_codes]
+    return counts, nn, pos, neg, mm
 
 
 def minmax_from_table(mm, depth: int, op: str):

@@ -67,9 +67,95 @@ def _fixture(rng, nf_rows, depth, s_dim=3, w=16, signed=True,
     return args, fields, vals, ex, bits, width
 
 
+def _digits(nf_rows):
+    """((bits, rows), ...): the layout stacked._code_digits hands the
+    fused kernel, for the fixture's fields."""
+    return tuple((max(nr - 1, 0).bit_length(), nr) for nr in nf_rows)
+
+
+def _filtered(rng, args):
+    """The fixture's args with a random filter ANDed into `valid`."""
+    import jax.numpy as jnp
+    cp, valid, planes, n_codes, signed = args
+    filt = rng.integers(0, 2**32, size=valid.shape, dtype=np.uint32)
+    return (cp, jnp.asarray(np.asarray(valid) & filt), planes, n_codes,
+            signed)
+
+
 class TestFusedKernelBitExact:
     """groupby_fused == groupby_codes_xla == groupby_onehot == numpy
     host twin, over randomized trials + named edge cases."""
+
+    # the packed body with the fields' digit layout handed over
+    # (ISSUE 32): (nf_rows, depth, signed, s_dim, w, filtered,
+    #              block_vregs or None)
+    PACKED_CASES = [
+        ((6, 2, 5), 7, False, 2, 16, False, None),     # the able query
+        ((6, 2, 5, 4), 3, True, 1, 16, True, None),    # 240 groups
+        ((6, 2, 5), 0, True, 2, 16, False, None),      # count-only
+        ((1, 5), 4, True, 2, 16, False, None),         # a field of one row
+        ((1,), 3, False, 3, 16, True, None),           # cb == 0
+        ((3, 2), 5, True, 1, 40, True, None),          # one shard
+        ((5, 3), 4, True, 2, 2100, False, 1),          # W not a multiple
+        ((6, 2, 5), 7, False, 1, 1030, True, 1),       # of the block
+    ]
+
+    @pytest.mark.parametrize("case", PACKED_CASES)
+    def test_packed_vs_reference(self, rng, monkeypatch, case):
+        nf_rows, depth, signed, s_dim, w, filtered, block = case
+        if block is not None:
+            monkeypatch.setattr(kernels, "_PACKED_BLOCK_VREGS", block)
+        args, *_ = _fixture(rng, nf_rows, max(depth, 1), s_dim=s_dim,
+                            w=w, signed=signed)
+        if filtered:
+            args = _filtered(rng, args)
+        if depth == 0:
+            args = args[:2] + (None,) + args[3:]
+        digits = _digits(nf_rows)
+        assert kernels.fused_body(digits, depth, signed) == "packed"
+        ref = kernels.groupby_codes_xla(*args)
+        fused = kernels.groupby_fused(*args, digits=digits)
+        for r, f in zip(ref, fused):
+            if r is None:
+                assert f is None
+            else:
+                np.testing.assert_array_equal(np.asarray(r),
+                                              np.asarray(f))
+        if depth and not signed:
+            assert not np.asarray(fused[3]).any()
+        # with no layout handed over every code is live: same table
+        dense = kernels.groupby_fused(*args)
+        np.testing.assert_array_equal(np.asarray(dense[0]),
+                                      np.asarray(ref[0]))
+
+    def test_onehot_body_still_serves(self, rng, monkeypatch):
+        """Shapes whose accumulators do not fit take the one-hot body
+        (here by a budget of nothing) and answer the same."""
+        monkeypatch.setattr(kernels, "_PACKED_VMEM_BYTES", 0)
+        args, *_ = _fixture(rng, (6, 2, 5), 4, s_dim=2)
+        digits = _digits((6, 2, 5))
+        assert kernels.fused_body(digits, 4, True, True) == "onehot"
+        ref = kernels.groupby_codes_xla(*args, minmax=True)
+        fused = kernels.groupby_fused(*args, minmax=True, digits=digits)
+        for r, f in zip(ref, fused):
+            np.testing.assert_array_equal(np.asarray(r), np.asarray(f))
+
+    def test_body_by_shape(self):
+        """The choice of body from the static arguments: the able
+        forms and the 240-group form packed; the kernel's bound
+        shapes, a 12-bit value histogram and every Min/Max (which the
+        packed body does not compute) one-hot."""
+        able = _digits((6, 2, 5))
+        for depth, signed in ((0, False), (7, False), (7, True)):
+            assert kernels.fused_body(able, depth, signed) == "packed"
+        for signed in (False, True):
+            assert kernels.fused_body(able, 7, signed, True) == "onehot"
+        assert kernels.fused_body(_digits((6, 2, 5, 4)), 7,
+                                  False) == "packed"
+        bound = ((1, 2),) * 12
+        assert kernels.fused_body(bound, 16, True) == "onehot"
+        assert kernels.fused_body(bound, 16, True, True) == "onehot"
+        assert kernels.fused_body(((1, 2),) * 13, 0) == "onehot"
 
     CASES = [
         # (nf_rows, depth, signed, all_invalid, extreme)
@@ -200,8 +286,77 @@ class TestFusedMinMax:
                 assert not hasmax[code] and not hasmin[code]
 
 
+    # With a digit layout handed over (row counts that are no powers
+    # of two), as the executor hands one to every GroupBy; Min/Max
+    # takes the one-hot body whatever the layout.
+    # (nf_rows, depth, signed, s_dim, w, mode): mode "filter" ANDs a
+    # random filter into valid, "empty" keeps only the first 40
+    # columns of each shard valid (most groups and sides stay empty),
+    # "invalid" none at all
+    LAYOUT_CASES = [
+        ((6, 2, 5), 7, False, 2, 16, "filter"),
+        ((6, 2, 5), 7, True, 2, 16, "filter"),
+        ((6, 2, 5, 4), 3, False, 1, 16, "empty"),
+        ((4, 3), 5, True, 2, 16, "empty"),
+        ((1, 5), 1, True, 2, 16, "filter"),
+        ((5, 3), 4, False, 1, 16, "invalid"),
+        ((3, 2), 6, True, 3, 70, "filter"),
+    ]
+
+    @pytest.mark.parametrize("case", LAYOUT_CASES)
+    def test_layout_table_vs_reference(self, rng, case):
+        import jax.numpy as jnp
+        nf_rows, depth, signed, s_dim, w, mode = case
+        args, *_ = _fixture(rng, nf_rows, depth, s_dim=s_dim, w=w,
+                            signed=signed, all_invalid=mode == "invalid")
+        if mode == "filter":
+            args = _filtered(rng, args)
+        elif mode == "empty":
+            keep = np.zeros((s_dim, w), np.uint32)
+            keep[:, 0] = 0xFFFFFFFF
+            keep[:, 1] = 0xFF
+            args = (args[0], jnp.asarray(np.asarray(args[1]) & keep)
+                    ) + args[2:]
+        digits = _digits(nf_rows)
+        assert kernels.fused_body(digits, depth, signed, True) == "onehot"
+        ref = kernels.groupby_codes_xla(*args, minmax=True)
+        fused = kernels.groupby_fused(*args, minmax=True, digits=digits)
+        for r, f in zip(ref, fused):
+            np.testing.assert_array_equal(np.asarray(r), np.asarray(f))
+        mm = np.asarray(fused[4])
+        big = 1 << depth
+        if mode != "filter":
+            # empty sides carry the identities
+            assert (mm[0] == -1).any() and (mm[1] == big).any()
+        if not signed or mode == "invalid":
+            assert (mm[2] == -1).all() and (mm[3] == big).all()
+
+
 class TestValueHistByproduct:
     """Range/Distinct/MinMax out of the fused value histogram."""
+
+    @pytest.mark.parametrize("depth,s_dim,w,block", [
+        (7, 2, 16, None), (3, 1, 40, None), (5, 2, 1030, 1)])
+    def test_hist_packed_every_code_live(self, rng, monkeypatch, depth,
+                                         s_dim, w, block):
+        """bsi_value_hist hands no layout over: every one of the
+        2^(depth+1) codes is live in the packed body."""
+        import jax.numpy as jnp
+        if block is not None:
+            monkeypatch.setattr(kernels, "_PACKED_BLOCK_VREGS", block)
+        assert kernels.fused_body(((1, 2),) * (depth + 1), 0) == "packed"
+        planes = jnp.asarray(rng.integers(
+            0, 2**32, size=(s_dim, 2 + depth, w), dtype=np.uint32))
+        filt = jnp.asarray(rng.integers(0, 2**32, size=(s_dim, w),
+                                        dtype=np.uint32))
+        pos, neg = kernels.bsi_value_hist(planes, filt)
+        posr, negr = kernels.bsi_value_hist(planes, filt,
+                                            use_kernel=False)
+        np.testing.assert_array_equal(np.asarray(pos), np.asarray(posr))
+        np.testing.assert_array_equal(np.asarray(neg), np.asarray(negr))
+        assert int(np.asarray(pos).sum() + np.asarray(neg).sum()) == int(
+            np.bitwise_count(np.asarray(planes[:, 0])
+                             & np.asarray(filt)).sum())
 
     @pytest.mark.parametrize("depth,filtered", [(4, False), (6, True),
                                                 (1, False)])
@@ -308,6 +463,57 @@ class TestEngineFusedArm:
         Executor(h).execute(
             "i", "GroupBy(Rows(g), Rows(d), aggregate=Sum(field=v))")
         assert GROUPBY_FUSED.total() > before
+
+    def test_body_label_by_shape(self, rng, monkeypatch):
+        """pilosa_groupby_fused_total{body=}: the able shapes (rows 6,
+        2, 5; an unsigned 7-bit int) dispatch the packed body, the
+        kernel's bound shapes (4,096 codes, depth 16) and Min/Max the
+        one-hot body — and all answer like the host loop."""
+        from pilosa_tpu.executor import Executor
+        from pilosa_tpu.models import FieldOptions, FieldType, Holder
+        from pilosa_tpu.obs.metrics import GROUPBY_FUSED
+        W = 1 << 12
+        h = Holder(width=W)
+        idx = h.create_index("i")
+        for name in ("edu", "gen", "dom", "p", "q"):
+            idx.create_field(name, FieldOptions(type=FieldType.MUTEX))
+        idx.create_field("age", FieldOptions(type=FieldType.INT,
+                                             min=0, max=127))
+        idx.create_field("wide", FieldOptions(type=FieldType.INT,
+                                              min=-65535, max=65535))
+        cols = list(range(0, 2 * W, 3))
+        for name, rows in (("edu", 6), ("gen", 2), ("dom", 5),
+                           ("p", 64), ("q", 64)):
+            idx.field(name).import_bits(
+                [int(r) for r in rng.integers(0, rows, size=len(cols))],
+                cols)
+        idx.field("age").import_values(
+            cols, [int(v) for v in rng.integers(0, 128, size=len(cols))])
+        idx.field("wide").import_values(
+            cols, [int(v) for v in rng.integers(-65535, 65536,
+                                                size=len(cols))])
+        idx.mark_columns_exist(cols)
+        assert idx.field("wide").bit_depth == 16
+        ex_loop = Executor(h)
+        ex_loop.use_stacked = False
+        monkeypatch.setenv("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "fused")
+        monkeypatch.setenv("PILOSA_TPU_GROUPBY_ONEPASS", "1")
+        for q, body in (
+                ("GroupBy(Rows(edu), Rows(gen), Rows(dom), "
+                 "aggregate=Sum(field=age))", "packed"),
+                ("GroupBy(Rows(edu), Rows(gen), Rows(dom))", "packed"),
+                ("GroupBy(Rows(edu), Rows(gen), Rows(dom), "
+                 "aggregate=Max(field=age))", "onehot"),
+                ("GroupBy(Rows(p), Rows(q), aggregate=Sum(field=wide))",
+                 "onehot")):
+            was = {b: GROUPBY_FUSED.value(path="onepass", body=b)
+                   for b in ("packed", "onehot")}
+            got = Executor(h).execute("i", q)[0]
+            moved = {b: GROUPBY_FUSED.value(path="onepass", body=b) - v
+                     for b, v in was.items()}
+            other = "onehot" if body == "packed" else "packed"
+            assert moved == {body: 1, other: 0}, (q, moved)
+            assert _as_t(got) == _as_t(ex_loop.execute("i", q)[0]), q
 
     def test_minmax_falls_back_on_overlap(self, rng, monkeypatch):
         """Overlapping rows refuse the one-pass gate; Min/Max must

@@ -57,10 +57,28 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _fused(n_codes, depth, minmax=False, cb=6):
+ABLE = ((3, 6), (1, 2), (3, 5))      # edu, gen, dom: (bits, rows)
+ABLE_REG = ABLE + ((2, 4),)           # the 240-group form
+# scoped VMEM a v5e kernel may use unless it asks for more
+# (CompilerParams(vmem_limit_bytes=), which groupby_fused does not)
+V5E_SCOPED_VMEM = 16 << 20
+
+
+def _fused(n_codes, depth, minmax=False, cb=6, signed=True, digits=None,
+           body="packed"):
+    """groupby_fused at W = 32768; `body` is the one its shapes must
+    take (kernels.fused_body) for the case to mean what its name
+    says."""
+    if digits is not None:
+        cb = sum(b for b, _ in digits)
+        n_codes = 1 << cb
+    assert kernels.fused_body(digits or ((1, 2),) * cb, depth, signed,
+                              minmax) == body
+
     def fn(cp, va, *planes):
         return kernels.groupby_fused(cp, va, planes[0] if planes else None,
-                                     n_codes, True, minmax=minmax)
+                                     n_codes, signed, minmax=minmax,
+                                     digits=digits)
     shapes = [(S, cb, W), (S, W)] + ([(S, 2 + depth, W)] if depth else [])
     return fn, shapes
 
@@ -77,11 +95,17 @@ def _groupby_sum():
 CASES = {
     "groupby_fused_count": lambda: _fused(64, 0),
     "groupby_fused_sum": lambda: _fused(64, 8),
-    "groupby_fused_minmax": lambda: _fused(64, 8, minmax=True),
-    # the kernel's own bounds (stacked._ONEPASS_KERNEL_MAX_*)
-    "groupby_fused_sum_bounds": lambda: _fused(4096, 16, cb=12),
+    "groupby_fused_minmax": lambda: _fused(64, 8, minmax=True,
+                                           body="onehot"),
+    # what able-1b's Min/Max requests dispatch (age: unsigned, 7 bits)
+    "groupby_fused_minmax_able": lambda: _fused(
+        0, 7, minmax=True, signed=False, digits=ABLE, body="onehot"),
+    # the kernel's own bounds (stacked._ONEPASS_KERNEL_MAX_*): their
+    # accumulators do not fit VMEM, the one-hot body serves
+    "groupby_fused_sum_bounds": lambda: _fused(4096, 16, cb=12,
+                                               body="onehot"),
     "groupby_fused_minmax_bounds": lambda: _fused(4096, 16, minmax=True,
-                                                  cb=12),
+                                                  cb=12, body="onehot"),
     "groupby_onehot": lambda: (
         lambda cp, va, pl: kernels.groupby_onehot(cp, va, pl, 64, True),
         [(S, 6, W), (S, W), (S, 10, W)]),
@@ -104,6 +128,38 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# the able query's forms through the packed body (ISSUE 32): age is an
+# unsigned 7-bit int.  "vhist_1024" is the largest value histogram
+# that takes it (a 9-bit int: every one of 1,024 codes live, so the
+# mask tree is at its widest and the block at one vreg)
+PACKED = {
+    "able_sum": dict(digits=ABLE, depth=7),
+    "able_count": dict(digits=ABLE, depth=0),
+    "able_reg_sum": dict(digits=ABLE_REG, depth=7),
+    "vhist_1024": dict(digits=((1, 2),) * 10, depth=0),
+}
+
+
+@pytest.mark.parametrize("name", list(PACKED))
+def test_packed_body_fits_v5e_vmem(one_chip, name):
+    """The packed body compiles for the chip and the scoped VMEM it
+    asks for (accumulators, mask scratch, operand blocks, Mosaic's
+    own) stays under what a kernel gets by default."""
+    import re
+    fn, shapes = _fused(0, signed=False, **PACKED[name])
+    args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "groupby_fused_" in calls[0]
+    asked = [int(n) for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', calls[0])]
+    assert asked and 0 < asked[0] < V5E_SCOPED_VMEM, asked
+    assert asked[0] <= kernels._PACKED_VMEM_BYTES + (1 << 20), asked
 
 
 @pytest.mark.parametrize("arm", ["fused", "onehot"])
