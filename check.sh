@@ -3,243 +3,19 @@
 # Usage: ./check.sh            (full gate)
 #        CHECK_BUDGET_S=600 ./check.sh
 # Fails fast on lint regressions and on slow-test creep (the pytest
-# run is killed — and the gate fails — past the budget).
+# run is killed — and the gate fails — past the budget).  The pytest
+# flags are the driver's (/root/TESTS_LAST_RUN.json): six xdist
+# workers, one file per worker at a time.
 set -u
 cd "$(dirname "$0")"
 
-BUDGET="${CHECK_BUDGET_S:-870}"
+BUDGET="${CHECK_BUDGET_S:-1470}"
 
 if command -v ruff >/dev/null 2>&1; then
     echo "== ruff =="
-    ruff check pilosa_tpu tests bench.py bench || exit 1
+    ruff check pilosa_tpu tests benchmark || exit 1
 else
     echo "check.sh: ruff not installed — skipping lint" >&2
-fi
-
-echo "== tracing-overhead smoke =="
-# flight-recorder on-vs-off micro-bench (bench.py --overhead-smoke):
-# catches observability regressions (instrumentation creeping into
-# the hot path) at tier-1 time.  Hard gates are the stable fixed-cost
-# probes (PILOSA_TPU_OVERHEAD_{OFF,ON}_MAX_US) plus the roofline-
-# attribution probe (flight cycle + per-dispatch bandwidth note with
-# attribution enabled vs disabled, PILOSA_TPU_ROOFLINE_ON_MAX_US —
-# the ISSUE 10 trace-propagation + attribution budget); the
-# scheduler-noisy qps A/B is backstopped at PILOSA_TPU_OVERHEAD_MAX_PCT.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --overhead-smoke; then
-    echo "check.sh: tracing-overhead smoke failed" >&2
-    exit 1
-fi
-
-echo "== memory-pressure smoke =="
-# HBM residency manager gate (bench.py --memory-smoke): budget
-# clamped below the working set -> queries stay bit-exact (paging
-# correctness) and injected RESOURCE_EXHAUSTED never escapes the
-# backstop (evict + retry, then host fallback)
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --memory-smoke; then
-    echo "check.sh: memory-pressure smoke failed" >&2
-    exit 1
-fi
-
-echo "== chaos smoke =="
-# failure-tolerance gate (bench.py --chaos-smoke): kill + warm-start
-# rejoin of a worker under a concurrent read storm on an in-process
-# cluster -> zero failed queries, bit-exact results vs the fault-free
-# run, resync carried the while-down writes
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --chaos-smoke; then
-    echo "check.sh: chaos smoke failed" >&2
-    exit 1
-fi
-
-echo "== rebalance chaos smoke =="
-# online-resharding gate (bench.py --rebalance-smoke,
-# bench/rebalance.py): a third node joins a live 2-node cluster
-# under a mixed read+write storm with a one-shot
-# transfer-interrupted fault armed -> CORRECTNESS-ONLY gates (2-core
-# rule): the interrupted migration resumed, zero failed / zero
-# mismatched queries, while-transfer writes bit-exact on the
-# recipient vs a cold rebuild, no epoch with zero or two write
-# owners (invariant probe sampled through the storm), then a clean
-# drain under the same gates.  p99 spike is recorded in the JSON,
-# never asserted here.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --rebalance-smoke; then
-    echo "check.sh: rebalance smoke failed" >&2
-    exit 1
-fi
-
-echo "== write-storm smoke =="
-# streaming write plane gate (bench.py --write-smoke): a short
-# sustained-write burst through the coalescing window plane with one
-# injected kill-mid-window (wal-torn) + restart + replay ->
-# CORRECTNESS GATES ONLY: zero acked-record loss (bit-exact vs a
-# cold rebuild AND vs a fresh reopen from disk), the kill struck a
-# plane with acked state behind it, unacked batches replayed, the
-# restarted plane landed windows, zero read failures.  Latency
-# ratios are reported, never gated (small-box scheduler noise).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --write-smoke; then
-    echo "check.sh: write-storm smoke failed" >&2
-    exit 1
-fi
-
-echo "== standing smoke =="
-# standing-query plane gate (bench.py --standing-smoke,
-# bench/standing.py): Count/TopN/GroupBy/SQL standing queries
-# registered on the serving plane, 8 pollers under a streaming write
-# storm, maintained vs PILOSA_TPU_STANDING=0 invalidated A/B ->
-# CORRECTNESS-ONLY gates: every registration admitted, zero poll/
-# writer failures, served results bit-exact vs a cold executor at
-# quiesce, ZERO stack builds during the maintained arm (polls ride
-# the write-through cache; maintenance — declared fallbacks
-# included — is host-side), and maintenance actually advanced
-# results incrementally.  Poll latency/throughput ratios are
-# recorded in the BENCH JSON, never asserted here.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --standing-smoke; then
-    echo "check.sh: standing smoke failed" >&2
-    exit 1
-fi
-
-echo "== audit smoke =="
-# continuous correctness-auditing gate (bench.py --audit-smoke,
-# bench/audit.py): 32-client mixed read/write gauntlet at a
-# production sampling rate (default 2%) with the shadow-execution
-# verifier live -> CORRECTNESS-ONLY gates: ZERO false positives
-# across the storm (matches and stale_skips are the only legal
-# outcomes), the one-shot audit-corrupt drill caught with EXACTLY
-# one audit-mismatch incident bundle carrying both digests and the
-# producing arm, zero read failures, and the serve-time sampling
-# hook's fixed cost <= 8us (PILOSA_TPU_AUDIT_TAP_MAX_US).  The
-# audit-on/off QPS overhead A/B is recorded in the BENCH JSON,
-# never asserted on a 2-core box.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --audit-smoke; then
-    echo "check.sh: audit smoke failed" >&2
-    exit 1
-fi
-
-echo "== ragged smoke =="
-# ragged dispatch + QoS admission gate (bench.py --ragged-smoke):
-# mixed-index traffic through the fused page-table program +
-# admission scheduler — CORRECTNESS-ONLY hard gates (bit-exact vs
-# solo, zero failed, backpressure sheds as typed 503 + Retry-After,
-# the ragged path actually engaged); latency/dispatch ratios are
-# recorded in the BENCH JSON, never asserted (2-core-box flake rule —
-# the committed BENCH_r08 gauntlet run asserts the ratios).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --ragged-smoke; then
-    echo "check.sh: ragged smoke failed" >&2
-    exit 1
-fi
-
-echo "== incident smoke =="
-# incident-forensics gate (bench.py --incident-smoke,
-# bench/incidents.py): an injected serving-dispatch stall under a
-# client storm -> exactly one deduped watchdog-stall bundle persisted
-# with thread stacks + flight records, ZERO failed queries while
-# capture runs (capture is off the hot path by construction); the
-# fixed-cost probes gate the per-stamp watchdog cycle
-# (PILOSA_TPU_WATCHDOG_STAMP_MAX_US, <=8us — same budget class as
-# the tracing probes) and the rate-limited report() cycle
-# (PILOSA_TPU_INCIDENT_REPORT_MAX_US).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --incident-smoke; then
-    echo "check.sh: incident smoke failed" >&2
-    exit 1
-fi
-
-echo "== stats smoke =="
-# statistics-catalog gate (bench.py --stats-smoke): fixed-cost probe
-# for the per-dispatch stats note (<=8us disabled / <=60us enabled,
-# same style as the PR 4/9 probes) + correctness gates — stats-on vs
-# stats-off bit-exact, restart reloads a non-empty catalog with equal
-# cost estimates, and the stats-fed admission arm never misclassifies
-# more than the static arm (rates recorded in BENCH JSON, improvement
-# asserted only as non-regression on the 2-core box)
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --stats-smoke; then
-    echo "check.sh: stats smoke failed" >&2
-    exit 1
-fi
-
-echo "== sql smoke =="
-# SQL serving gate (bench.py --sql-smoke, bench/sqlbench.py):
-# CORRECTNESS-ONLY gates on the 2-core box — pushdown engaged on
-# eligible statements (route-"sql" flight records with fused inner
-# dispatches + planner decisions), both arms bit-exact vs the
-# precomputed host answer key, sheds/deadlines on /sql typed
-# 503/504 (Retry-After on sheds), zero failed.  QPS/latency ratios
-# are recorded in BENCH JSON, never asserted here (the committed
-# gauntlet run carries the >=5x acceptance).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --sql-smoke; then
-    echo "check.sh: sql smoke failed" >&2
-    exit 1
-fi
-
-echo "== sparse-format smoke =="
-# container-adaptive device format gate (bench.py --sparse-smoke,
-# bench/sparse.py): a Zipfian battery must be BIT-EXACT between the
-# sparse arm and the PILOSA_TPU_SPARSE_FORMAT=0 dense arm, packed
-# pages must actually build (pilosa_stack_pages_total{encoding=packed}
-# moves), and a write landing on a packed page must re-encode and
-# stay exact.  Compression/latency ratios are recorded in the JSON,
-# never asserted here (the committed gauntlet run carries them).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --sparse-smoke; then
-    echo "check.sh: sparse-format smoke failed" >&2
-    exit 1
-fi
-
-echo "== multichip smoke =="
-# mesh-sharded serving gate (bench.py --multichip-smoke,
-# bench/multichip.py): 8 FORCED host devices (the flag must precede
-# backend init — the smoke owns its process), the mixed ragged
-# gauntlet served with the serving mesh at 8 devices vs the 1-device
-# arm UNDER INTERLEAVED WRITES — bit-exact across arms and vs solo
-# execution once quiesced, zero failed, the ragged_mesh program
-# actually dispatched (not a silent single-device fallback), and no
-# mesh dispatch leaking into the 1-device arm.  Scaling/latency is
-# recorded in the BENCH JSON, never asserted here (forced host
-# devices share one memory bus; the TPU curve is a labeled
-# projection until hardware lands).
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --multichip-smoke; then
-    echo "check.sh: multichip smoke failed" >&2
-    exit 1
-fi
-
-echo "== kernel interpret-mode smoke =="
-# fused single-pass GroupBy kernel gate (bench.py --kernel-smoke):
-# the fused int8 MXU kernel + Min/Max presence walk + Range/Distinct
-# value-hist byproduct run in Pallas interpret mode on a small
-# fixture and must be bit-exact vs the XLA scatter reference and the
-# host shard loop — a kernel regression fails fast without TPU
-# hardware (correctness-only; latency never gated here)
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --kernel-smoke; then
-    echo "check.sh: kernel interpret-mode smoke failed" >&2
-    exit 1
-fi
-
-echo "== dax smoke =="
-# disaggregated-tier gate (bench.py --dax-smoke, bench/dax.py):
-# an empty-data-dir worker serves a >=10x-over-budget corpus from
-# blob manifests bit-exact vs the local-disk fleet (ledger never
-# over budget, real evictions + re-hydrations), then an injected
-# storm trips the SLO burn threshold and the autoscaler admits the
-# standby live with a scale-event-interrupted fault armed — the run
-# must resume, show zero failed / zero mismatched queries, recover
-# burn, drain the worker back, and serve the scale event's incident
-# bundle over HTTP.  CORRECTNESS-ONLY gates (2-core rule): warmup
-# walls, QPS, and latency are recorded in the JSON, never asserted.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python bench.py --dax-smoke; then
-    echo "check.sh: dax smoke failed" >&2
-    exit 1
 fi
 
 echo "== tier-1 (budget ${BUDGET}s) =="
@@ -251,7 +27,7 @@ trap 'rm -f "$T1LOG"' EXIT
 timeout -k 10 "$BUDGET" env JAX_PLATFORMS=cpu \
     python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly > "$T1LOG" 2>&1
+    -p xdist -n 6 --dist loadfile -p no:randomly > "$T1LOG" 2>&1
 rc=$?
 cat "$T1LOG"
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$T1LOG" | tr -cd . | wc -c)"

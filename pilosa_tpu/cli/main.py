@@ -50,7 +50,6 @@ def build_server(cfg):
     from pilosa_tpu.obs.logger import StderrLogger
     from pilosa_tpu.server.http import Server
 
-    cfg.apply_kernel_setting()
     cfg.apply_stack_settings()
     cfg.apply_flight_settings()
     cfg.apply_memory_settings()
@@ -252,10 +251,6 @@ spec = ""
 # enable by setting a shared HS256 secret
 secret = ""
 policy = ""      # YAML group->permission file (authz)
-
-[tpu]
-# pallas kernel dispatch: "auto" | "on" | "off"
-kernels = "auto"
 
 [flight]
 # query flight recorder: per-query phase records at /debug/queries

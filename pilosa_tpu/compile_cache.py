@@ -1,7 +1,7 @@
 """Where JAX keeps compiled programs between processes.
 
-Every entry point that jits (``pilosa-tpu``, ``bench.py``,
-``chip_smoke.py``) calls :func:`place` before its first jit.  The
+Every entry point that jits (``pilosa-tpu``, ``chip_smoke.py``,
+``benchmark/run.py``) calls :func:`place` before its first jit.  The
 directory is part of the cache key, so it is one fixed path, never a
 temp name: the operator's ``JAX_COMPILATION_CACHE_DIR`` when set
 (JAX reads it itself; nothing is set in code), else ``.jax_cache``

@@ -29,7 +29,6 @@ class Config:
     replicas: int = 1
     auth_secret: str = ""
     auth_policy: str = ""
-    tpu_kernels: str = "auto"   # auto | on | off -> PILOSA_TPU_PALLAS
     # queries slower than this (seconds) go to the long-query log;
     # 0 disables (server.go:201 OptServerLongQueryTime)
     long_query_time: float = 0.0
@@ -273,15 +272,6 @@ class Config:
     dax_cooldown_s: float = 30.0
     dax_chase_lag: int = 8
     dax_chase_rounds: int = 12
-
-    def apply_kernel_setting(self):
-        """Translate tpu_kernels into the Pallas dispatch env flag.
-        'auto' (the default) leaves PILOSA_TPU_PALLAS untouched — a
-        user-exported override must survive config loading."""
-        if self.tpu_kernels == "on":
-            os.environ["PILOSA_TPU_PALLAS"] = "1"
-        elif self.tpu_kernels == "off":
-            os.environ["PILOSA_TPU_PALLAS"] = "0"
 
     def apply_stack_settings(self):
         """Push the [stacked] knobs into the runtime modules (the env
@@ -543,7 +533,6 @@ _TOML_KEYS = {
     "cluster.replicas": "replicas",
     "auth.secret": "auth_secret",
     "auth.policy": "auth_policy",
-    "tpu.kernels": "tpu_kernels",
     "long-query-time": "long_query_time",
     "serving.batching": "serving_batching",
     "serving.batch-window-ms": "serving_batch_window_ms",

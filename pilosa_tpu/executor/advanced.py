@@ -40,7 +40,6 @@ from pilosa_tpu.models.view import VIEW_STANDARD
 from pilosa_tpu.executor.stacked import Unstackable
 from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.ops import bsi as bsi_ops
-from pilosa_tpu.ops import kernels
 from pilosa_tpu.pql.ast import Call, Condition
 
 _ROW_CHUNK = 256      # row tiles per device batch in count scans
@@ -157,15 +156,6 @@ class AdvancedOps:
                 chunk = row_ids[i:i + _ROW_CHUNK]
                 tiles = self._row_tiles(f, shard, chunk, views)
                 if filt is not None:
-                    if kernels.enabled():
-                        # one fused AND+popcount pass (Pallas) — the
-                        # TopK candidate hot loop (executor.go:2750)
-                        got = np.asarray(
-                            kernels.masked_popcount(tiles, filt),
-                            dtype=np.int64)
-                        for r, c in zip(chunk, got):
-                            counts[r] += int(c)
-                        continue
                     tiles = bm.intersect(tiles, filt[None, :])
                 got = np.asarray(bm.count(tiles), dtype=np.int64)
                 for r, c in zip(chunk, got):

@@ -640,11 +640,7 @@ class Executor(AdvancedOps):
                 continue
             planes = frag.device_planes(f.bit_depth)
             filt = self._filter_words(idx, call, shard, pre)
-            if kernels.enabled():
-                # single fused pass over the plane stack (Pallas)
-                parts_per_shard.append(kernels.bsi_sum_counts(planes, filt))
-            else:
-                parts_per_shard.append(bsi_ops.sum_counts(planes, filt))
+            parts_per_shard.append(bsi_ops.sum_counts(planes, filt))
         total, count = 0, 0
         if parts_per_shard:
             cnt = np.asarray(jnp.stack([p[0] for p in parts_per_shard]))

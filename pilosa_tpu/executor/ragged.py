@@ -64,7 +64,6 @@ from pilosa_tpu.executor.stacked import (
 from pilosa_tpu.memory import encode, pressure
 from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs.monitor import capture_exception
-from pilosa_tpu.ops import kernels
 
 
 class RaggedUnbuildable(Exception):
@@ -863,7 +862,7 @@ def run_ragged(layer, groups: dict) -> None:
             cached = None
             canon.cached = None
     if cached is not None:
-        _serve_cached(layer, eng, cached, by_key, len(groups))
+        _serve_cached(layer, cached, by_key, len(groups))
     else:
         slot_groups: OrderedDict[tuple, list] = OrderedDict()
         for s in slots:
@@ -998,7 +997,7 @@ def _plan_and_dispatch(layer, eng, work, n_groups: int,
         # no rider this batch — skip the dispatch but keep the built
         # program for the cache (the next batch serves from it)
         return payload
-    outs = _dispatch_served(eng, plan, leaves, params, served,
+    outs = _dispatch_served(plan, leaves, params, served,
                             meshinfo, program, n_groups)
     if outs is not None:
         _demux_served(served, outs)
@@ -1014,19 +1013,17 @@ def _plan_stage(riders, kind: str = "ragged"):
         ctx=[r.ctx for r in riders], kind=kind)
 
 
-def _dispatch_served(eng, plan, leaves, params, served, meshinfo,
+def _dispatch_served(plan, leaves, params, served, meshinfo,
                      program: str, n_groups: int):
     """The ONE fused dispatch of a batch, timed once as the stage
     `kind` ('execute' | 'compile') for every rider it serves: the
     same span in each rider's flight record and trace tree.  The ready
     outputs, or None after marking the riders direct."""
-    kern = (kernels.enabled() and not eng.host_only
-            and plan[0] != "ragged_mesh")
     # the program's signature is a repr of the whole plan and a shape
     # key per page: milliseconds at hundreds of pages, and part of
     # building it
     with _plan_stage([r for r, _d, _e in served]):
-        sig = (repr(plan), kern)
+        sig = repr(plan)
         kind = _dispatch_kind(sig, leaves, params)
     nsubs = len(plan[3]) if plan[0] == "ragged" else len(plan[6])
     oom0 = metrics.OOM_TOTAL.total(outcome="caught")
@@ -1042,7 +1039,7 @@ def _dispatch_served(eng, plan, leaves, params, served, meshinfo,
             from pilosa_tpu.obs import faults
             faults.fire("serving-dispatch")
             fn = _compiled(
-                plan, kern=kern, sig=sig,
+                plan, sig=sig,
                 name="plan_ragged_extras" if program == "extras"
                 and plan[0] == "ragged" else None)
             outs = pressure.guarded(lambda: dispatch_ready(
@@ -1082,7 +1079,7 @@ def _demux_served(served, outs) -> None:
             r.result = None
 
 
-def _serve_cached(layer, eng, cached, by_key, n_groups: int) -> None:
+def _serve_cached(layer, cached, by_key, n_groups: int) -> None:
     """Serve this batch's canonical riders from the cross-batch
     program cache: no plan building, no leaf fetches — map each rider
     to its slot's demux/extract, run the ONE cached fused program,
@@ -1103,7 +1100,7 @@ def _serve_cached(layer, eng, cached, by_key, n_groups: int) -> None:
                 served.append((r, demux, ext))
     if not served or plan is None:
         return
-    outs = _dispatch_served(eng, plan, leaves, params, served,
+    outs = _dispatch_served(plan, leaves, params, served,
                             meshinfo, "canonical-cached", n_groups)
     if outs is not None:
         _demux_served(served, outs)

@@ -1194,8 +1194,7 @@ class ServingLayer:
         # each — a recompile of the multi program is tagged distinctly
         # from a cached-executable dispatch
         plan = ("multi", tuple(subs))
-        kern = kernels.enabled() and not eng.host_only
-        sig = (repr(plan), kern)  # multi-KB at high occupancy: once
+        sig = repr(plan)  # multi-KB at high occupancy: once
         kind = _dispatch_kind(sig, b.leaves, b.params)
         try:
             with flight.stage(kind, accs=[r.acc for r in pend],
@@ -1208,7 +1207,7 @@ class ServingLayer:
                 # fallback
                 from pilosa_tpu.obs import faults
                 faults.fire("serving-dispatch")
-                fn = _compiled(plan, kern=kern, sig=sig)
+                fn = _compiled(plan, sig=sig)
                 # OOM backstop: RESOURCE_EXHAUSTED on the fused
                 # program evicts via the ledger + retries once; a
                 # persistent OOM falls through to the per-rider direct

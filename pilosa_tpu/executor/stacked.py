@@ -1267,47 +1267,46 @@ def _combo_codes(shifts, combos_arr: np.ndarray) -> np.ndarray:
     return codes
 
 
+def _forced_arm() -> str | None:
+    """PILOSA_TPU_GROUPBY_ONEPASS_ARM=fused|xla, else None: the one
+    read of the seam by which CPU tests, chip_smoke.py --rehearse-cpu
+    and benchmark/run.py --rehearse-cpu run the kernel in interpret
+    mode.  It stands in for the backend, and for nothing else."""
+    import os
+    forced = os.environ.get("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "")
+    return forced if forced in ("fused", "xla") else None
+
+
 def _onepass_arm(n_codes: int, depth: int,
-                 minmax: bool = False) -> str:
+                 mesh_minmax: bool = False) -> str:
     """Which one-pass device program serves the histogram:
 
-    - "fused"  — the single-pass kernel on packed words
-      (groupby_fused; the default on TPU; its body, packed or
-      one-hot, is kernels.fused_body's choice from the shapes)
-    - "onehot" — the first-generation f32 one-hot matmul kernel (the
-      A/B arm; PILOSA_TPU_GROUPBY_FUSED=0, no Min/Max support)
-    - "xla"    — the scatter-add reference (the bit-exactness oracle
-      and the off-TPU default: CPU would only interpret the kernels)
+    - "fused" — the single-pass kernel on packed words
+      (groupby_fused; its body, packed or one-hot, is
+      kernels.fused_body's choice from the shapes): on a TPU, inside
+      _ONEPASS_KERNEL_MAX_*
+    - "xla"   — the scatter-add form (groupby_codes_xla): off a TPU
+      (a CPU would only interpret the kernel), past the bounds (a
+      2^20-code value histogram under the kernel would build a
+      ~128 MB per-chunk one-hot), and for a Min/Max over a mesh (a
+      pallas_call over a mesh-sharded operand would force a gather;
+      the scatter shards under GSPMD)
 
-    PILOSA_TPU_GROUPBY_ONEPASS_ARM forces an arm outright (bench A/B
-    and the interpret-mode test/smoke paths use it)."""
-    import os
-    over_bounds = (n_codes > _ONEPASS_KERNEL_MAX_CODES
-                   or depth > _ONEPASS_KERNEL_MAX_DEPTH)
-    forced = os.environ.get("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "")
-    if forced in ("fused", "onehot", "xla"):
-        # forcing never lifts the kernel size caps: a 2^20-code
-        # value-hist under a forced fused arm would build a ~128 MB
-        # per-chunk one-hot — route oversized shapes to the reference
-        if forced != "xla" and over_bounds:
-            return "xla"
-        # onehot has no Min/Max table — the reference serves those
-        return "xla" if forced == "onehot" and minmax else forced
-    if jax.default_backend() != "tpu" or over_bounds:
+    _forced_arm() stands in for the backend and lifts no bound."""
+    if (mesh_minmax or n_codes > _ONEPASS_KERNEL_MAX_CODES
+            or depth > _ONEPASS_KERNEL_MAX_DEPTH):
         return "xla"
-    if os.environ.get("PILOSA_TPU_GROUPBY_FUSED", "") == "0":
-        return "xla" if minmax else "onehot"
-    return "fused"
+    return _forced_arm() or (
+        "fused" if jax.default_backend() == "tpu" else "xla")
 
 
 def _onepass_gb(arm: str, digits=None):
     """The arm's histogram callable (shared by jit + shard_map).
     `digits` (_code_digits) tells the fused kernel which codes are
-    live; the other arms histogram the whole code space."""
+    live; the XLA form histograms the whole code space."""
     if arm == "fused":
         return functools.partial(kernels.groupby_fused, digits=digits)
-    return {"onehot": kernels.groupby_onehot,
-            "xla": kernels.groupby_codes_xla}[arm]
+    return kernels.groupby_codes_xla
 
 
 def _onepass_unpack(flat, n_codes: int, depth: int, has_planes: bool,
@@ -1555,26 +1554,7 @@ _SCOPES = {"words": "filter", "count": "count", "bsi_sum": "bsi_sum",
            "row_counts": "topn"}
 
 
-def _count_partials(tree, kern: bool):
-    """(S,) per-shard popcounts of a tree.  With kernels enabled and
-    every operand device-RESIDENT (a leaf — exactly the no-producer-
-    to-fuse case kernels.py's dispatch rule names), route through the
-    fused Pallas passes; anything with an upstream XLA producer stays
-    with XLA so fusion isn't broken."""
-    if kern and tree[0] == "leaf":
-        i = tree[1]
-        return lambda leaves, params: kernels.popcount_rows(leaves[i])
-    if (kern and tree[0] == "nary" and tree[1] == "intersect"
-            and len(tree[2]) == 2
-            and all(c[0] == "leaf" for c in tree[2])):
-        i, j = tree[2][0][1], tree[2][1][1]
-        return lambda leaves, params: kernels.pair_popcount(
-            leaves[i], leaves[j])
-    return lambda leaves, params: bm.count(
-        _filter(tree, leaves, params))
-
-
-def _plan_run(plan, kern: bool = False):
+def _plan_run(plan):
     """Un-jitted `run(leaves, params)` for one plan (see _compiled).
     Split out so the "multi" kind — the cross-query batcher's fused
     program (executor/serving.py) — can compose several subplans into
@@ -1586,7 +1566,7 @@ def _plan_run(plan, kern: bool = False):
         # excluded — its run() reads the combo selector from
         # params[-1], which only a solo plan positions.
         assert all(p[0] != "groupby" for p in plan[1])
-        runs = tuple(_plan_run(p, kern) for p in plan[1])
+        runs = tuple(_plan_run(p) for p in plan[1])
 
         def run(leaves, params):
             return tuple(r(leaves, params) for r in runs)
@@ -1606,7 +1586,7 @@ def _plan_run(plan, kern: bool = False):
         # concatenation of the members' pages without ever
         # materializing their operands.
         n_pages, vmeta, subs = plan[1], plan[2], plan[3]
-        runs = tuple(None if s[0] == "segcount" else _plan_run(s, kern)
+        runs = tuple(None if s[0] == "segcount" else _plan_run(s)
                      for s in subs)
 
         def run(leaves, params):
@@ -1650,7 +1630,7 @@ def _plan_run(plan, kern: bool = False):
         smesh = placement.serving_mesh()
         assert smesh.devices.size == ndev
         nb = len(buckets)
-        runs = tuple(None if s[0] == "segcount" else _plan_run(s, kern)
+        runs = tuple(None if s[0] == "segcount" else _plan_run(s)
                      for s in subs)
 
         def _combine(o, comb, prms):
@@ -1711,10 +1691,9 @@ def _plan_run(plan, kern: bool = False):
             return _as_stack(_eval(tree, leaves, params), leaves)
     elif kind == "count":
         tree, reduce_ = plan[1], plan[2]
-        partials = _count_partials(tree, kern)
 
         def run(leaves, params):
-            c = partials(leaves, params)              # (S,)
+            c = bm.count(_filter(tree, leaves, params))   # (S,)
             return jnp.sum(c) if reduce_ else c
     elif kind == "bsi_sum":
         planes_i, tree, reduce_ = plan[1], plan[2], plan[3]
@@ -1722,21 +1701,12 @@ def _plan_run(plan, kern: bool = False):
         def run(leaves, params):
             planes = leaves[planes_i]                 # (S, P, W)
             if tree is None:
-                if kern:
-                    cnt, pos, neg = jax.vmap(
-                        lambda p: kernels.bsi_sum_counts(p, None))(planes)
-                else:
-                    cnt, pos, neg = jax.vmap(
-                        lambda p: bsi_ops.sum_counts(p, None))(planes)
+                cnt, pos, neg = jax.vmap(
+                    lambda p: bsi_ops.sum_counts(p, None))(planes)
             else:
-                if kern and tree[0] == "leaf":
-                    filt = leaves[tree[1]]
-                    cnt, pos, neg = jax.vmap(
-                        kernels.bsi_sum_counts)(planes, filt)
-                else:
-                    filt = _filter(tree, leaves, params)
-                    cnt, pos, neg = jax.vmap(
-                        bsi_ops.sum_counts)(planes, filt)
+                filt = _filter(tree, leaves, params)
+                cnt, pos, neg = jax.vmap(
+                    bsi_ops.sum_counts)(planes, filt)
             if reduce_:
                 return (jnp.sum(cnt), jnp.sum(pos, axis=0),
                         jnp.sum(neg, axis=0))         # scalar, (P,), (P,)
@@ -1836,14 +1806,7 @@ def _plan_run(plan, kern: bool = False):
         def run(leaves, params):
             rows = leaves[rows_i]                     # (R, S, W)
             if tree is None:
-                if kern:
-                    r, s, w = rows.shape
-                    c = kernels.popcount_rows(
-                        rows.reshape(r * s, w)).reshape(r, s)
-                else:
-                    c = bm.count(rows)                # (R, S)
-            elif kern and tree[0] == "leaf":
-                c = kernels.rows_filter_counts(rows, leaves[tree[1]])
+                c = bm.count(rows)                    # (R, S)
             else:
                 filt = _filter(tree, leaves, params)
                 c = bm.count(jnp.bitwise_and(rows, filt[None]))
@@ -1858,14 +1821,12 @@ def _plan_run(plan, kern: bool = False):
     return scoped
 
 
-def _compiled(plan, kern: bool = False, sig: tuple | None = None,
-              name: str | None = None):
+def _compiled(plan, sig: str | None = None, name: str | None = None):
     """plan: ("words", tree) | ("count", tree, reduce)
     | ("bsi_sum", planes_i, tree|None, reduce)
     | ("row_counts", rows_i, tree|None, reduce)
     | ("multi", (subplan, ...)) — the batcher's fused program.
-    One jitted fn per structure; `kern` routes resident-leaf hot ops
-    through the Pallas kernels.  `sig` lets a caller that already
+    One jitted fn per structure.  `sig` lets a caller that already
     paid for repr(plan) — the multi-plan repr is multi-KB at high
     batch occupancy — pass it in instead of rebuilding it.  With
     reduce=True the cross-shard sum happens IN the program — under a
@@ -1876,14 +1837,14 @@ def _compiled(plan, kern: bool = False, sig: tuple | None = None,
     profiler's XLA Modules line; `name` lets the ragged plane tell its
     extras program from the canonical one) — never for the plan's
     contents, so naming adds no program."""
-    sig = (repr(plan), kern) if sig is None else sig
+    sig = repr(plan) if sig is None else sig
     with _JIT_LOCK:
         ent = _JIT_CACHE.get(sig)
         if ent is not None:
             _JIT_CACHE.move_to_end(sig)
             return ent[0]
     fn = jax.jit(bm.named(name or "plan_" + plan[0])(
-        _plan_run(plan, kern)))
+        _plan_run(plan)))
     client = _jit_client()
     reserved = (_JIT_EST_BYTES
                 if client.reserve(_JIT_EST_BYTES) else 0)
@@ -2008,7 +1969,7 @@ def _plan_hbm_bytes(plan, leaves, params) -> int:
     return sum(getattr(a, "nbytes", 0) for a in leaves)
 
 
-def timed_dispatch(plan, kern, leaves, params):
+def timed_dispatch(plan, leaves, params):
     """Run a plan's jitted program with flight/span attribution:
     recompiles are timed distinctly from cached dispatches, and the
     clock stops only when the device result is ready.  Dispatches run
@@ -2016,8 +1977,8 @@ def timed_dispatch(plan, kern, leaves, params):
     triggers ledger-driven eviction + one retry, then a degraded-mode
     re-execution of the SAME plan on the host CPU backend — a slow
     answer instead of a failed query."""
-    sig = (repr(plan), kern)
-    fn = _compiled(plan, kern=kern, sig=sig)
+    sig = repr(plan)
+    fn = _compiled(plan, sig=sig)
     kind = _dispatch_kind(sig, leaves, params)
     oom0 = metrics.OOM_TOTAL.total(outcome="caught")
     with flight.stage(kind, kind=plan[0],
@@ -2741,9 +2702,7 @@ class StackedEngine:
     # -- execution entry points ----------------------------------------
 
     def _run(self, plan, builder):
-        return timed_dispatch(
-            plan, kernels.enabled() and not self.host_only,
-            builder.leaves, builder.params)
+        return timed_dispatch(plan, builder.leaves, builder.params)
 
     def _build_timed(self, builder, call):
         """PlanBuilder.build as the `plan_build` stage.  The stack/
@@ -3317,14 +3276,11 @@ class StackedEngine:
     def _onepass_host(self, multi: bool) -> bool:
         """Whether the one-pass histogram runs on the host (native C /
         numpy) instead of a device program.  A forced device arm
-        (PILOSA_TPU_GROUPBY_ONEPASS_ARM — bench A/B, interpret-mode
-        tests) overrides the CPU-backend host preference but never
-        host_only harnesses."""
-        import os
+        (_forced_arm) overrides the CPU-backend host preference but
+        never host_only harnesses."""
         if self.host_only:
             return True
-        if os.environ.get("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "") in (
-                "fused", "onehot", "xla"):
+        if _forced_arm():
             return False
         return not multi and jax.default_backend() != "tpu"
 
@@ -3460,12 +3416,8 @@ class StackedEngine:
             # combination and so runs the single-jit program over the
             # whole (mesh-sharded) stack (Min/Max traffic is the same
             # single pass; fleets beyond the reduce bound were gated)
-            arm = _onepass_arm(n_codes, depth, minmax=minmax)
-            if multi and arm == "fused":
-                # a pallas_call over a mesh-sharded operand would
-                # force a gather; the scatter reference shards under
-                # GSPMD — keep the rare mesh Min/Max on it
-                arm = "xla"
+            arm = _onepass_arm(n_codes, depth,
+                               mesh_minmax=multi and minmax)
             if arm == "fused":
                 GROUPBY_FUSED.inc(
                     path="onepass", body=kernels.fused_body(
@@ -3841,9 +3793,8 @@ class StackedEngine:
             combos, dtype=np.int32).reshape(n_combos, nf)
         # pad combos re-count combo 0; their rows are dropped below
         sel_all = combo_idx.reshape(n_chunks, combo_chunk, nf)
-        out = timed_dispatch(plan,
-                             kernels.enabled() and not self.host_only,
-                             b.leaves, tuple(b.params) + (sel_all,))
+        out = timed_dispatch(plan, b.leaves,
+                             tuple(b.params) + (sel_all,))
 
         def note_arm():
             stats.note_gate(

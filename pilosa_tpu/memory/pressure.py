@@ -15,10 +15,10 @@ on the stacked/serving paths with the recovery ladder:
    leaves fetched to host numpy), so the query answers slowly instead
    of erroring.
 
-``inject_oom(n)`` is the test/CI seam: the next ``n`` guarded
-dispatches raise a synthetic RESOURCE_EXHAUSTED before running, which
-is how check.sh's memory-pressure smoke proves absorption without a
-real 16 GiB working set.  Since ISSUE 6 the seam is a registered
+``inject_oom(n)`` is the test seam: the next ``n`` guarded dispatches
+raise a synthetic RESOURCE_EXHAUSTED before running, which is how
+tests/test_memory.py proves absorption without a real 16 GiB working
+set.  Since ISSUE 6 the seam is a registered
 fault point (``device-oom`` in obs/faults.py) — this function is the
 backward-compatible wrapper, and the fault can equally be armed via
 the registry's config/env spec alongside the rpc/node faults."""
@@ -47,9 +47,9 @@ class InjectedOOM(RuntimeError):
 
 def inject_oom(n: int = 1):
     """Make the next ``n`` guarded dispatches fail with a synthetic
-    RESOURCE_EXHAUSTED (test / smoke hook).  Registry-backed: arms
-    the ``device-oom`` fault point, replacing any prior arming (the
-    original seam's set-not-add semantics, which the smokes rely on)."""
+    RESOURCE_EXHAUSTED (test hook).  Registry-backed: arms the
+    ``device-oom`` fault point, replacing any prior arming (the
+    original seam's set-not-add semantics, which the tests rely on)."""
     faults.clear("device-oom")
     if int(n) > 0:
         faults.inject("device-oom", times=int(n))
@@ -147,5 +147,5 @@ def run_host_plan(plan, leaves, params):
     lv = tuple(np.asarray(x) for x in leaves)
     pv = tuple(np.asarray(x) for x in params)
     with jax.default_device(cpu):
-        fn = jax.jit(stacked._plan_run(plan, False))
+        fn = jax.jit(stacked._plan_run(plan))
         return jax.block_until_ready(fn(lv, pv))

@@ -44,9 +44,7 @@ traffic:
   federates it at ``/debug/cluster/audit``.
 
 ``PILOSA_TPU_AUDIT=0`` kills the whole plane at runtime; ``[audit]``
-config knobs (env twins ``PILOSA_TPU_AUDIT_*``) tune it.  The serve-
-time tap's fixed cost (the not-sampled path) is gated at <= 8us by
-``bench.py --audit-smoke``.
+config knobs (env twins ``PILOSA_TPU_AUDIT_*``) tune it.
 """
 
 from __future__ import annotations
@@ -276,7 +274,7 @@ class AuditPlane:
                      route, results, fl) -> None:
         """The serve-time sampling decision.  The not-sampled path —
         one rate lookup + one RNG draw — is the fixed cost every
-        served read pays and is gated <= 8us (bench/audit.py)."""
+        served read pays."""
         rate = _ROUTE_RATES.get(route, _SAMPLE_RATE)
         if rate <= 0.0 or self._rng.random() >= rate:
             return

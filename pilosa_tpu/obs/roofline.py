@@ -19,13 +19,11 @@ Peak comes from ``PILOSA_TPU_PEAK_GBPS`` (device spec) or a measured
 STREAM-style probe (:func:`ensure_peak`) run once at server startup —
 on CPU fallback the probe measures host memory bandwidth, so the
 fraction stays meaningful (if humble) off-TPU.  Per-query shares land
-in each flight record's ``roofline`` field (obs/flight.py), and the
-bench cells emit windowed snapshots (bench/headline.py, serving.py).
+in each flight record's ``roofline`` field (obs/flight.py).
 
 Always-on budget: :func:`note` is one dict update + two gauge sets on
 a path that just paid a device dispatch; the disabled path is a
-single module-global check (gated with the tracing-overhead smoke in
-check.sh).
+single module-global check.
 """
 
 from __future__ import annotations

@@ -12,9 +12,8 @@ live stack via ``sys._current_frames``, and raises an incident
 the stuck phase.
 
 The hot-path contract is the stamp: four attribute writes and one
-``time.monotonic()`` call, no lock, no allocation — measured well
-under the 8 µs budget check.sh's incident smoke gates (the same
-budget class as the flight recorder's disabled path).  ``idle()``
+``time.monotonic()`` call, no lock, no allocation (the same budget
+class as the flight recorder's disabled path).  ``idle()``
 disarms the watch while the loop is legitimately parked waiting for
 work, so an empty queue never reads as a stall.
 

@@ -1,43 +1,30 @@
-"""Pallas TPU kernels for the per-shard hot loops.
+"""Pallas TPU kernels: the GroupBy family and the BSI value histogram.
 
-SURVEY §3.2 names four hot loops in the reference; the three that are
-device-side here get hand-scheduled Pallas kernels (the fourth — RBF
-leaf-cell iteration — is the native C++ storage layer):
+The scans of the served path — Count of set algebra, TopN candidate
+counts, BSI Sum and range compares — are plain jnp (`ops.bitmap`,
+`ops.bsi`): XLA fuses the AND into the popcount-reduce and the operand's
+producer into the scan, which a pallas_call (a fusion barrier) cannot.
+What is here is what XLA does badly:
 
-- pairwise container ops + popcount  (roaring/roaring.go:927-1663, 542)
-  -> :func:`pair_popcount` — one fused AND+popcount+reduce pass.
-- BSI plane walks                    (fragment.go:724-1305)
-  -> :func:`bsi_sum_counts` — one pass over the plane stack computing
-  the filtered per-plane sign-split popcounts.
-- TopK candidate-row counting        (executor.go:2570-2777)
-  -> :func:`masked_popcount` — batched rows AND one filter, popcounts.
+- :func:`groupby_fused` — the one-pass GroupBy histogram over a dense
+  group code (counts, BSI Sum partials, Min/Max tables); two bodies,
+  chosen from the static shapes by :func:`fused_body`.  The default on
+  a TPU inside the executor's bounds.
+- :func:`groupby_sum` — the per-combo kernel (scalar-prefetch gather,
+  combos innermost) for GroupBy fields whose rows overlap.
+- :func:`groupby_codes_xla` — the XLA scatter form of the same
+  histogram: the oracle the kernels are tested against, the arm off a
+  TPU and past the kernel's bounds, and the mesh shard_map body.
+- :func:`bsi_value_hist` — Range / Distinct / Min / Max of an int field
+  as one run of the histogram with the value as the group code.
+- the `*_hbm_bytes` models the roofline plane notes from.
 
-Why Pallas instead of plain jnp: these ops are pure HBM-bandwidth
-streams (popcount is 1 VPU op/word).  The jnp forms are already good —
-XLA fuses AND into the popcount-reduce — so the kernels' win is
-schedule control: one grid walk per operand stream, explicit VMEM
-blocks sized to double-buffer, and accumulation in int32 without
-intermediate materialization.  Everything is wrapped so the jnp path
-(`ops.bitmap`/`ops.bsi`) stays the reference implementation; tests
-cross-check the two.
+The XLA GroupBy scan must materialize gathered (C, S, W) combo masks
+and re-read them once per BSI plane; the kernels read each operand
+stream about once.
 
-Speed against the XLA forms: not measured on today's code.  What
-holds by construction: the ops are bandwidth-bound streams, and a
-pallas_call is a fusion barrier — when the operand is produced by an
-upstream elementwise op XLA fuses producer and scan into one pass,
-while the kernel forces the intermediate through HBM.  Hence the
-dispatch rule in enabled(): kernels serve executor paths whose inputs
-are device-RESIDENT tiles (no producer to fuse); whole-pipeline jnp
-expressions stay with XLA.
-
-The exception is the GroupBy family, where a kernel is the DEFAULT on
-TPU: the XLA GroupBy scan must materialize gathered (C, S, W) combo
-masks and re-read them once per BSI plane, while :func:`groupby_sum`'s
-scalar-prefetch gather + plane-block reuse and the one-pass
-:func:`groupby_fused` read each operand stream about once.
-
-All kernels run in interpreter mode automatically off-TPU, so the same
-code path is exercised by the CPU test mesh (conftest.py).
+All kernels run in interpret mode off a TPU, so the CPU test mesh
+(conftest.py) runs the same code.
 """
 
 from __future__ import annotations
@@ -51,8 +38,6 @@ from jax.experimental.pallas import tpu as pltpu
 from pilosa_tpu.ops import bitmap as bm
 
 _LANES = 128          # TPU lane width (last-dim tile)
-_ROW_BLOCK = 8        # rows per grid step in batched kernels
-_WORD_BLOCK = 4096    # words per grid step in plane-stack kernels
 
 
 def _interpret() -> bool:
@@ -60,30 +45,9 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def enabled() -> bool:
-    """Whether the executor should route hot ops through these kernels.
-
-    Default OFF (an A/B against the XLA path is not measured on
-    today's code; ROADMAP C5): PILOSA_TPU_PALLAS=1 routes resident-
-    leaf plans through the kernels (and exercises the interpret path
-    in CPU tests); off-TPU the interpreter would be far slower than
-    XLA, so callers fall back regardless unless forced.
-    """
-    import os
-    return os.environ.get("PILOSA_TPU_PALLAS") == "1"
-
-
 def _pc(x):
     return jax.lax.convert_element_type(jax.lax.population_count(x),
                                         jnp.int32)
-
-
-def _pad_rows(x, block):
-    n = x.shape[0]
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-    return x, n
 
 
 def _pad_axis(x, axis, block):
@@ -96,253 +60,6 @@ def _pad_axis(x, axis, block):
         widths[axis] = (0, pad)
         x = jnp.pad(x, widths)
     return x
-
-
-# ---------------------------------------------------------------------------
-# popcount over rows: (N, W) -> (N,)
-# ---------------------------------------------------------------------------
-
-def _popcount_rows_kernel(x_ref, o_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.sum(_pc(x_ref[...]), axis=-1, keepdims=True)
-
-
-def _row_word_grid(w: int) -> int:
-    """Word-axis block: whole row when small, 8K-word chunks when a
-    row would not fit VMEM (arbitrarily wide flattened rows)."""
-    return min(_WORD_BLOCK * 2, w)
-
-
-def popcount_rows(x):
-    """Per-row popcount: x (N, W) uint32 -> (N,) int32."""
-    x, n = _pad_rows(x, _ROW_BLOCK)
-    bw = _row_word_grid(x.shape[1])
-    x = _pad_axis(x, 1, bw)
-    npad, w = x.shape
-    out = pl.pallas_call(
-        _popcount_rows_kernel,
-        grid=(npad // _ROW_BLOCK, w // bw),
-        in_specs=[pl.BlockSpec((_ROW_BLOCK, bw), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        name="popcount_rows",
-        interpret=_interpret(),
-    )(x)
-    return out[:n, 0]
-
-
-# ---------------------------------------------------------------------------
-# fused pairwise AND + popcount: (N, W), (N, W) -> (N,)
-# ---------------------------------------------------------------------------
-
-def _pair_popcount_kernel(a_ref, b_ref, o_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.sum(
-        _pc(a_ref[...] & b_ref[...]), axis=-1, keepdims=True)
-
-
-def pair_popcount(a, b):
-    """popcount(a & b) per row — the Count(Intersect) hot loop.
-
-    a, b: (N, W) uint32 -> (N,) int32.  One pass over each operand
-    stream; the intersection is never materialized in HBM (the analog
-    of roaring.IntersectionCount, roaring/roaring.go:711).
-    """
-    assert a.shape == b.shape, (a.shape, b.shape)
-    a, n = _pad_rows(a, _ROW_BLOCK)
-    b, _ = _pad_rows(b, _ROW_BLOCK)
-    bw = _row_word_grid(a.shape[1])
-    a = _pad_axis(a, 1, bw)
-    b = _pad_axis(b, 1, bw)
-    npad, w = a.shape
-    spec = pl.BlockSpec((_ROW_BLOCK, bw), lambda i, j: (i, j))
-    out = pl.pallas_call(
-        _pair_popcount_kernel,
-        grid=(npad // _ROW_BLOCK, w // bw),
-        in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        name="pair_popcount",
-        interpret=_interpret(),
-    )(a, b)
-    return out[:n, 0]
-
-
-# ---------------------------------------------------------------------------
-# masked popcount: rows (N, W) AND one filter (W,) -> (N,)
-# ---------------------------------------------------------------------------
-
-def _masked_popcount_kernel(x_ref, m_ref, o_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.sum(
-        _pc(x_ref[...] & m_ref[...]), axis=-1, keepdims=True)
-
-
-def masked_popcount(x, mask):
-    """popcount(x[i] & mask) for every row — TopK candidate counting.
-
-    x: (N, W) uint32, mask: (W,) uint32 -> (N,) int32.  The filter
-    block is loaded once per grid step and broadcast over the row
-    block (executor.go:2750 topKFilter semantics).
-    """
-    x, n = _pad_rows(x, _ROW_BLOCK)
-    bw = _row_word_grid(x.shape[1])
-    x = _pad_axis(x, 1, bw)
-    mask = _pad_axis(mask, 0, bw)
-    npad, w = x.shape
-    out = pl.pallas_call(
-        _masked_popcount_kernel,
-        grid=(npad // _ROW_BLOCK, w // bw),
-        in_specs=[
-            pl.BlockSpec((_ROW_BLOCK, bw), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bw), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((_ROW_BLOCK, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        name="masked_popcount",
-        interpret=_interpret(),
-    )(x, mask.reshape(1, w))
-    return out[:n, 0]
-
-
-# ---------------------------------------------------------------------------
-# BSI sum: one pass over the plane stack
-# ---------------------------------------------------------------------------
-
-def _bsi_sum_kernel(planes_ref, filt_ref, cnt_ref, pos_ref, neg_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        pos_ref[...] = jnp.zeros_like(pos_ref)
-        neg_ref[...] = jnp.zeros_like(neg_ref)
-
-    exists = planes_ref[0, :]
-    sign = planes_ref[1, :]
-    consider = exists & filt_ref[0, :]
-    pos = consider & ~sign
-    neg = consider & sign
-    mag = planes_ref[2:, :]                      # (depth, BW)
-    cnt_ref[...] += jnp.sum(_pc(consider)).reshape(1, 1)
-    pos_ref[...] += jnp.sum(_pc(mag & pos[None, :]), axis=-1, keepdims=True)
-    neg_ref[...] += jnp.sum(_pc(mag & neg[None, :]), axis=-1, keepdims=True)
-
-
-def bsi_sum_counts(planes, filter_words=None):
-    """Fused BSI Sum scan (fragment.sum, fragment.go:718-746).
-
-    planes: (2+depth, W) uint32, filter_words: (W,) uint32 or None.
-    Returns (count, pos_pc, neg_pc) matching ops.bsi.sum_counts — the
-    whole plane stack is streamed through VMEM exactly once, with the
-    sign/exists masking fused into the same pass.  Combine on host
-    with ops.bsi.host_sum for exact >2^53 totals.
-    """
-    p, w = planes.shape
-    depth = p - 2
-    assert depth >= 1
-    if filter_words is None:
-        filter_words = jnp.full((w,), np.uint32(0xFFFFFFFF), dtype=jnp.uint32)
-    bw = min(_WORD_BLOCK, w)
-    planes = _pad_axis(planes, 1, bw)
-    filter_words = _pad_axis(filter_words, 0, bw)
-    w = planes.shape[1]
-    cnt, pos, neg = pl.pallas_call(
-        _bsi_sum_kernel,
-        grid=(w // bw,),
-        in_specs=[
-            pl.BlockSpec((p, bw), lambda i: (0, i)),
-            pl.BlockSpec((1, bw), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((depth, 1), lambda i: (0, 0)),
-            pl.BlockSpec((depth, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((depth, 1), jnp.int32),
-            jax.ShapeDtypeStruct((depth, 1), jnp.int32),
-        ],
-        name="bsi_sum_counts",
-        interpret=_interpret(),
-    )(planes, filter_words.reshape(1, w))
-    return cnt[0, 0], pos[:, 0], neg[:, 0]
-
-
-# ---------------------------------------------------------------------------
-# Fused flagship query step (bench.py / __graft_entry__ workload)
-# ---------------------------------------------------------------------------
-
-def _rows_filter_kernel(rows_ref, filt_ref, rc_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init_rc():
-        rc_ref[...] = jnp.zeros_like(rc_ref)
-
-    # rows block: (R, BS, BW) & filt (BS, BW) -> counts (BS, R)
-    rc_ref[...] += jnp.sum(
-        _pc(rows_ref[...] & filt_ref[...][None]), axis=-1).T
-
-
-_ROWS_CHUNK = 16
-
-
-def rows_filter_counts(rows, filt):
-    """Per-(row, shard) filtered popcounts — the TopK candidate scan.
-
-    rows: (R, S, W), filt: (S, W) -> (R, S) int32.  The R axis is
-    processed in chunks of <= 16 candidate rows per pallas_call so the
-    VMEM block stays ~4 MB no matter how many candidates a query has
-    (Mosaic requires the output lane dim to equal the full array dim,
-    so R is chunked on the host rather than in the grid).
-    """
-    r_dim = rows.shape[0]
-    if r_dim == 0:
-        return jnp.zeros((0, filt.shape[0]), dtype=jnp.int32)
-    bs = _ROW_BLOCK
-    filt, s_dim = _pad_rows(filt, bs)
-    pad = filt.shape[0] - s_dim
-    if pad:
-        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
-    bw = min(8192, filt.shape[1])
-    filt = _pad_axis(filt, 1, bw)
-    rows = _pad_axis(rows, 2, bw)
-    spad, w = filt.shape
-    out = []
-    for lo in range(0, r_dim, _ROWS_CHUNK):
-        chunk = rows[lo:lo + _ROWS_CHUNK]
-        r = chunk.shape[0]
-        rc = pl.pallas_call(
-            _rows_filter_kernel,
-            grid=(spad // bs, w // bw),
-            in_specs=[
-                pl.BlockSpec((r, bs, bw), lambda s, j: (0, s, j)),
-                pl.BlockSpec((bs, bw), lambda s, j: (s, j)),
-            ],
-            out_specs=pl.BlockSpec((bs, r), lambda s, j: (s, 0)),
-            out_shape=jax.ShapeDtypeStruct((spad, r), jnp.int32),
-            name="rows_filter_counts",
-            interpret=_interpret(),
-        )(chunk, filt)
-        out.append(rc[:s_dim].T)
-    return jnp.concatenate(out, axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -493,10 +210,9 @@ def groupby_sum(stacks, sel, planes=None, signed=True):
 # combo count: per column, compose a dense group code from packed digit
 # planes (ops.bitmap.digit_planes — one digit per disjoint GroupBy
 # field), then accumulate counts and BSI sign-split plane partials into
-# a (K, G) table indexed by code.  groupby_onehot does the
-# accumulation with MXU matmuls (one-hot.T @ payload-bits);
-# groupby_codes_xla is the scatter-add XLA reference the kernel is
-# cross-checked against (and the mesh shard_map body).
+# a (K, G) table indexed by code.  groupby_fused (below) does the
+# accumulation in one kernel; groupby_codes_xla is the scatter-add XLA
+# form it is cross-checked against (and the mesh shard_map body).
 #
 # Output layout (shared): rows [counts, nn, pos_plane_0..d-1,
 # neg_plane_0..d-1] — identical per-plane sign-split partials to
@@ -614,119 +330,13 @@ def _valid_operand(valid, bw: int):
             pl.BlockSpec((1, 1, bw), lambda s, w: (s, 0, w)))
 
 
-def _gc_onehot_kernel(cb: int, depth: int, signed: bool, k: int,
-                      g_pad: int):
-    """Kernel body factory for groupby_onehot: per (shard, word-block)
-    grid step, decode the 32 bit positions of the block and accumulate
-    payload.T @ one-hot MXU matmuls into the VMEM-resident (K, G)
-    table.  Each dot's partial sums are <= BW < 2^24 so the f32 MXU
-    accumulator is exact; accumulation across dots is int32."""
-
-    def kernel(cp_ref, va_ref, *refs):
-        pl_ref = refs[0] if depth else None
-        out_ref = refs[-1]
-        s, wi = pl.program_id(0), pl.program_id(1)
-
-        @pl.when((s == 0) & (wi == 0))
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        iota_g = jax.lax.broadcasted_iota(jnp.int32, (1, g_pad), 1)
-
-        def bit(j, carry):
-            # rolled, so Mosaic sizes its VMEM stack for one bit
-            # position's temporaries, not for all 32
-            sh = j.astype(jnp.uint32)
-            va = ((va_ref[0, 0, :] >> sh) & 1).astype(jnp.int32)
-            code = jnp.zeros_like(va)
-            for b in range(cb):
-                code = code | (
-                    ((cp_ref[0, b, :] >> sh) & 1).astype(jnp.int32) << b)
-            ex = sg = None
-            mag = []
-            if depth:
-                ex = ((pl_ref[0, 0, :] >> sh) & 1).astype(jnp.int32) * va
-                sg = ((pl_ref[0, 1, :] >> sh) & 1).astype(jnp.int32)
-                mag = [((pl_ref[0, 2 + p, :] >> sh) & 1).astype(jnp.int32)
-                       for p in range(depth)]
-            rows = _gc_payload_rows(va, ex, sg, mag, depth, signed)
-            payload = jnp.stack(rows).astype(jnp.float32)      # (K, BW)
-            # invalid columns carry all-zero payload (every row has a
-            # `va` factor), so their arbitrary code contributes nothing
-            onehot = (code[:, None] == iota_g).astype(jnp.float32)
-            out_ref[...] += jnp.dot(payload, onehot,
-                                    preferred_element_type=jnp.float32
-                                    ).astype(jnp.int32)
-            return carry
-
-        jax.lax.fori_loop(0, 32, bit, 0)
-    return kernel
-
-
-def groupby_onehot(code_planes, valid, planes=None, n_codes: int = 1,
-                   signed: bool = True):
-    """One-pass GroupBy histogram with f32 MXU accumulation (the
-    first-generation one-pass kernel; superseded by the int8
-    :func:`groupby_fused` path but kept as a measured alternative and
-    A/B arm).
-
-    Same contract as :func:`groupby_codes_xla` (bit-exact against it
-    and against groupby_sum over the same data — tests cross-check all
-    three).  Schedule: grid (S, W/BW) with NO combo axis — each stack
-    word, valid word, and plane word streams through VMEM exactly once
-    and the (K, G) histogram table stays VMEM-resident for the whole
-    grid, so HBM traffic is O(S*W) for ANY combo count.  The combo
-    dimension only exists inside a grid step as the one-hot lane axis
-    of a (K, BW) @ (BW, G) matmul — work the MXU does for free next to
-    the bandwidth-bound stream.
-    """
-    s_dim, cb, w_dim = code_planes.shape
-    if cb == 0:                        # all fields single-row: code 0
-        code_planes = jnp.zeros((s_dim, 1, w_dim), dtype=jnp.uint32)
-        cb = 1
-    depth = 0 if planes is None else planes.shape[1] - 2
-    k = _payload_rows(depth, signed)
-    g_pad = max(-(-int(n_codes) // 128) * 128, 128)
-    # word block sized so the per-step (BW, G) one-hot stays ~2 MB f32
-    bw = min(w_dim, max(128, (1 << 19) // g_pad))
-    code_planes = _pad_axis(code_planes, 2, bw)
-    valid, valid_spec = _valid_operand(valid, bw)
-    arrays = [code_planes, valid]
-    in_specs = [pl.BlockSpec((1, cb, bw), lambda s, w: (s, 0, w)),
-                valid_spec]
-    if depth:
-        planes = _pad_axis(planes, 2, bw)
-        arrays.append(planes)
-        in_specs.append(
-            pl.BlockSpec((1, 2 + depth, bw), lambda s, w: (s, 0, w)))
-    wpad = code_planes.shape[2]
-    out = pl.pallas_call(
-        _gc_onehot_kernel(cb, depth, signed, k, g_pad),
-        grid=(s_dim, wpad // bw),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((k, g_pad), lambda s, w: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, g_pad), jnp.int32),
-        name="groupby_onehot",
-        interpret=_interpret(),
-    )(*arrays)
-    counts = out[0, :n_codes]
-    if depth == 0:
-        return counts, None, None, None
-    nn = out[1, :n_codes]
-    pos = out[2:2 + depth, :n_codes].T                 # (G, depth)
-    neg = (out[2 + depth:, :n_codes].T if signed
-           else jnp.zeros_like(pos))
-    return counts, nn, pos, neg
-
-
 # ---------------------------------------------------------------------------
 # fused single-pass GroupBy: per-group masks ANDed and popcounted on
 # packed words
 # ---------------------------------------------------------------------------
 #
-# One pass over the operands, like groupby_onehot above, but the inner
-# body never leaves the packed domain (ISSUE 32).  Per (shard, word
-# block) grid step:
+# One pass over the operands, and the inner body never leaves the
+# packed domain (ISSUE 32).  Per (shard, word block) grid step:
 #
 #   0. every plane of the block is re-laid out in VMEM so that its
 #      words fill whole (8, 128) vregs (the (1, P, BW) block arrives
@@ -1080,9 +690,9 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
                   digits=None):
     """Fused single-pass GroupBy histogram on packed words.
 
-    Same contract as :func:`groupby_codes_xla` (bit-exact against it,
-    against groupby_onehot, and against the host twins — the property
-    suite cross-checks all of them).  Returns (counts, nn, pos, neg)
+    Same contract as :func:`groupby_codes_xla` (bit-exact against it
+    and against the host twins — the property suite cross-checks all
+    of them).  Returns (counts, nn, pos, neg)
     and, with ``minmax=True`` (requires planes), additionally a
     (4, G) int32 table [max_mag_pos, min_mag_pos, max_mag_neg,
     min_mag_neg] with identities (-1 / 1<<depth) marking empty sides —
@@ -1258,26 +868,9 @@ def groupby_scan_hbm_bytes(n_shards: int, width_words: int,
     return b
 
 
-def fused_query_counts(a, b, filt, rows):
-    """Per-shard Count(Intersect) + TopK candidate counts.
-
-    a, b, filt: (S, W); rows: (R, S, W).  Returns (per-shard intersect
-    counts (S,) int32, row_counts (R, S) int32).  Cross-shard totals
-    must be combined on the host in int64/Python ints (the per-shard
-    count is < 2^20 so int32 is exact; a grand total may not be — see
-    ops.bitmap.count).  Each operand stream is read exactly once.
-    """
-    return pair_popcount(a, b), rows_filter_counts(rows, filt)
-
-
 __all__ = [
-    "popcount_rows",
-    "pair_popcount",
-    "masked_popcount",
-    "bsi_sum_counts",
     "groupby_sum",
     "groupby_codes_xla",
-    "groupby_onehot",
     "groupby_fused",
     "minmax_from_table",
     "bsi_value_hist",
@@ -1286,5 +879,4 @@ __all__ = [
     "groupby_onepass_hbm_bytes",
     "groupby_percombo_hbm_bytes",
     "groupby_scan_hbm_bytes",
-    "fused_query_counts",
 ]

@@ -114,7 +114,7 @@ def groupcode_hist(code_planes: np.ndarray, valid: np.ndarray,
     (n_codes,), nn (n_codes,) and sign-split per-plane partials
     pos/neg (n_codes, depth) int64 in place.  code_planes (CB, W)
     packed group-code bit-planes, valid (W,), bsi (2+depth, W) or
-    None.  Host twin of ops/kernels.groupby_onehot."""
+    None.  Host twin of ops/kernels.groupby_codes_xla."""
     code_planes = np.ascontiguousarray(code_planes, dtype=np.uint32)
     valid = np.ascontiguousarray(valid, dtype=np.uint32)
     depth = 0 if bsi is None else bsi.shape[0] - 2

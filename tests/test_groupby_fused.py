@@ -83,8 +83,8 @@ def _filtered(rng, args):
 
 
 class TestFusedKernelBitExact:
-    """groupby_fused == groupby_codes_xla == groupby_onehot == numpy
-    host twin, over randomized trials + named edge cases."""
+    """groupby_fused == groupby_codes_xla == numpy host twin, over
+    randomized trials + named edge cases."""
 
     # the packed body with the fields' digit layout handed over
     # (ISSUE 32): (nf_rows, depth, signed, s_dim, w, filtered,
@@ -174,10 +174,8 @@ class TestFusedKernelBitExact:
                             all_invalid=all_invalid, extreme=extreme)
         ref = [np.asarray(v) for v in kernels.groupby_codes_xla(*args)]
         fused = [np.asarray(v) for v in kernels.groupby_fused(*args)]
-        onehot = [np.asarray(v) for v in kernels.groupby_onehot(*args)]
-        for r, f, o in zip(ref, fused, onehot):
+        for r, f in zip(ref, fused):
             np.testing.assert_array_equal(r, f)
-            np.testing.assert_array_equal(r, o)
 
     @pytest.mark.parametrize("trial", range(4))
     def test_randomized_property(self, rng, trial):
@@ -434,6 +432,42 @@ QUERIES = [
     "GroupBy(Rows(g), Rows(d), filter=Row(flt=0), "
     "aggregate=Min(field=v))",
 ]
+
+
+# (backend, n_codes, depth, mesh Min/Max, PILOSA_TPU_GROUPBY_ONEPASS_ARM)
+# -> (arm, host histogram on one device): stacked._onepass_arm's whole
+# table.  The variable stands in for the backend and lifts no bound; a
+# name it no longer knows ("onehot") is as good as unset.
+ARM_TABLE = [
+    ("tpu", 64, 7, False, None, "fused", False),
+    ("cpu", 64, 7, False, None, "xla", True),
+    ("tpu", 8192, 7, False, None, "xla", False),       # taxi-1b's Q4
+    ("tpu", 64, 17, False, None, "xla", False),
+    ("tpu", 64, 7, True, None, "xla", False),
+    ("cpu", 64, 7, False, "fused", "fused", False),
+    ("tpu", 64, 7, False, "xla", "xla", False),
+    ("cpu", 8192, 7, False, "fused", "xla", False),
+    ("cpu", 64, 7, True, "fused", "xla", False),
+    ("tpu", 64, 7, False, "onehot", "fused", False),
+    ("cpu", 64, 7, False, "onehot", "xla", True),
+]
+
+
+@pytest.mark.parametrize("case", ARM_TABLE)
+def test_onepass_arm_table(monkeypatch, case):
+    import types
+
+    from pilosa_tpu.executor import stacked
+    backend, n_codes, depth, mesh_minmax, forced, arm, host = case
+    monkeypatch.setattr(stacked.jax, "default_backend", lambda: backend)
+    if forced is None:
+        monkeypatch.delenv("PILOSA_TPU_GROUPBY_ONEPASS_ARM", raising=False)
+    else:
+        monkeypatch.setenv("PILOSA_TPU_GROUPBY_ONEPASS_ARM", forced)
+    assert stacked._onepass_arm(n_codes, depth,
+                                mesh_minmax=mesh_minmax) == arm
+    eng = types.SimpleNamespace(host_only=False)
+    assert stacked.StackedEngine._onepass_host(eng, False) is host
 
 
 class TestEngineFusedArm:

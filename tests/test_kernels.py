@@ -20,142 +20,6 @@ def _rand_words(rng, shape, density=0.5):
     return words
 
 
-@pytest.mark.parametrize("n,w", [(1, 128), (7, 256), (16, 1024)])
-def test_popcount_rows(rng, n, w):
-    x = _rand_words(rng, (n, w))
-    got = np.asarray(kernels.popcount_rows(x))
-    want = np.bitwise_count(x).sum(axis=-1)
-    np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.parametrize("n,w", [(3, 128), (8, 512), (13, 1024)])
-def test_pair_popcount(rng, n, w):
-    a = _rand_words(rng, (n, w))
-    b = _rand_words(rng, (n, w))
-    got = np.asarray(kernels.pair_popcount(a, b))
-    want = np.bitwise_count(a & b).sum(axis=-1)
-    np.testing.assert_array_equal(got, want)
-    # agrees with the jnp reference path
-    np.testing.assert_array_equal(
-        got, np.asarray(bm.intersection_count(a, b)))
-
-
-@pytest.mark.parametrize("n,w", [(5, 128), (32, 2048)])
-def test_masked_popcount(rng, n, w):
-    x = _rand_words(rng, (n, w))
-    m = _rand_words(rng, (w,))
-    got = np.asarray(kernels.masked_popcount(x, m))
-    want = np.bitwise_count(x & m[None]).sum(axis=-1)
-    np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.parametrize("depth,w,filtered", [
-    (1, 128, False), (7, 4096, True), (13, 8192, True), (33, 128, False),
-])
-def test_bsi_sum_counts_kernel(rng, depth, w, filtered):
-    width = w * 32
-    n = min(width // 2, 3000)
-    cols = rng.choice(width, size=n, replace=False)
-    vals = rng.integers(-(2**depth) + 1, 2**depth, size=n)
-    planes = bsi.encode(cols, vals, depth=depth, width=width)
-    filt = _rand_words(rng, (w,)) if filtered else None
-
-    cnt, pos, neg = kernels.bsi_sum_counts(planes, filt)
-    total, count = bsi.host_sum(cnt, pos, neg)
-
-    rc, rpos, rneg = bsi.sum_counts(planes, filt)
-    rtotal, rcount = bsi.host_sum(rc, rpos, rneg)
-    assert (total, count) == (rtotal, rcount)
-
-    # and against exact numpy ground truth
-    if filtered:
-        mask_bits = bm.to_columns(filt)
-        sel = np.isin(cols, mask_bits)
-    else:
-        sel = np.ones(n, dtype=bool)
-    assert count == int(sel.sum())
-    assert total == int(vals[sel].sum())
-
-
-# r=37 exercises host-side R chunking; w=192 a non-multiple word width
-@pytest.mark.parametrize("s_dim,w,r", [(4, 256, 6), (9, 192, 37)])
-def test_fused_query_counts(rng, s_dim, w, r):
-    a = _rand_words(rng, (s_dim, w))
-    b = _rand_words(rng, (s_dim, w))
-    filt = _rand_words(rng, (s_dim, w))
-    rows = _rand_words(rng, (r, s_dim, w))
-    ci, rc = kernels.fused_query_counts(a, b, filt, rows)
-    np.testing.assert_array_equal(
-        np.asarray(ci), np.bitwise_count(a & b).sum(axis=-1))
-    want_rc = np.bitwise_count(rows & filt[None]).sum(axis=-1)
-    np.testing.assert_array_equal(np.asarray(rc), want_rc)
-
-
-def test_bsi_sum_counts_nonmultiple_width(rng):
-    # word width not a multiple of the 4096-word block: padding path
-    w = 6144
-    planes = _rand_words(rng, (5, w))
-    filt = _rand_words(rng, (w,))
-    got = kernels.bsi_sum_counts(planes, filt)
-    from pilosa_tpu.ops import bsi as bsi_ops
-    want = bsi_ops.sum_counts(planes, filt)
-    assert int(got[0]) == int(want[0])
-    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
-    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
-
-
-def test_kernels_under_jit(rng):
-    """Kernels compose under jax.jit like any other jax op."""
-    import jax
-
-    a = _rand_words(rng, (8, 512))
-    b = _rand_words(rng, (8, 512))
-
-    @jax.jit
-    def f(a, b):
-        return kernels.pair_popcount(a, b)
-
-    np.testing.assert_array_equal(
-        np.asarray(f(a, b)), np.bitwise_count(a & b).sum(axis=-1))
-
-
-def test_executor_pallas_dispatch(rng, monkeypatch):
-    """PILOSA_TPU_PALLAS=1 forces the executor hot paths through the
-    Pallas kernels (interpret mode on CPU) — results must be identical
-    to the jnp path."""
-    monkeypatch.setenv("PILOSA_TPU_PALLAS", "1")
-    from pilosa_tpu.models.holder import Holder
-    from pilosa_tpu.models.schema import FieldOptions, FieldType
-    from pilosa_tpu.executor.executor import Executor
-
-    width = 1 << 12
-    h = Holder(width=width)
-    idx = h.create_index("p")
-    fld = idx.create_field("f", FieldOptions(type=FieldType.SET))
-    val = idx.create_field("v", FieldOptions(type=FieldType.INT,
-                                             min=-1000, max=1000))
-    cols = rng.choice(3 * width, size=300, replace=False)
-    rows = rng.integers(0, 10, size=300)
-    vals = rng.integers(-1000, 1000, size=300)
-    fld.import_bits(rows, cols)
-    val.import_values(cols, vals.tolist())
-    idx.mark_columns_exist([int(c) for c in cols])
-    ex = Executor(h)
-    got_sum = ex.execute("p", "Sum(Row(f=1), field=v)")[0]
-    sel = rows == 1
-    assert got_sum.value == int(vals[sel].sum())
-    assert got_sum.count == int(sel.sum())
-    # filter as positional child => the masked_popcount kernel path
-    got_top = ex.execute("p", "TopN(f, Row(f=1), n=3)")[0]
-    monkeypatch.setenv("PILOSA_TPU_PALLAS", "0")
-    want_top = ex.execute("p", "TopN(f, Row(f=1), n=3)")[0]
-    # columns are unique per row here, so only row 1 intersects its
-    # own filter — the point is kernel/jnp agreement, not cardinality
-    assert [(p.id, p.count) for p in got_top] == \
-        [(p.id, p.count) for p in want_top]
-    assert got_top and got_top[0].id == 1
-
-
 class TestGroupbySum:
     """Fused GroupBy kernel vs a naive numpy evaluation."""
 
@@ -356,7 +220,7 @@ class TestGroupbyOnepass:
         (True, (3, 2, 4), 3),
     ])
     def test_kernel_vs_xla_vs_naive(self, rng, signed, nf_rows, depth):
-        """groupby_onehot (interpret) == groupby_codes_xla == numpy."""
+        """groupby_fused (interpret) == groupby_codes_xla == numpy."""
         import jax.numpy as jnp
         s_dim, w = 3, 16
         width = w * 32
@@ -384,8 +248,9 @@ class TestGroupbyOnepass:
                 jnp.asarray(planes), n_codes, signed)
         c_x, n_x, p_x, g_x = (np.asarray(v)
                               for v in kernels.groupby_codes_xla(*args))
-        c_k, n_k, p_k, g_k = (np.asarray(v)
-                              for v in kernels.groupby_onehot(*args))
+        c_k, n_k, p_k, g_k = (
+            np.asarray(v) for v in kernels.groupby_fused(
+                *args, digits=tuple(zip(bits, nf_rows))))
         np.testing.assert_array_equal(c_x, c_k)
         np.testing.assert_array_equal(n_x, n_k)
         np.testing.assert_array_equal(p_x, p_k)

@@ -255,12 +255,14 @@ def test_paged_bit_exact_under_budget_clamp(restore_memory):
     ws = plain.stacked.cache.nbytes
     assert ws > 0
     ex = Executor(h)
-    ex.stacked.cache = TileStackCache(
-        ledger=Ledger(budget_bytes=max(ws // 2, 4096)))
+    budget = max(ws // 2, 4096)
+    led = Ledger(budget_bytes=budget)
+    c = ex.stacked.cache = TileStackCache(ledger=led)
     for _ in range(3):
         got = [repr(ex.execute("i", q)) for q in queries]
         assert got == want
-    c = ex.stacked.cache
+        # what is accounted resident never exceeds the clamp
+        assert c.nbytes <= budget and led.total_bytes <= budget
     assert c.misses > 0  # the clamp produced genuine pressure
 
 

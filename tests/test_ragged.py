@@ -498,14 +498,14 @@ def test_canonical_composition_stabilizes_executable(holder):
     assert _run_one_batch(layer, batch2) == solo_expect(plain, batch2)
     assert len(layer._ragged_canon.slots) == 6
     union_sigs = {s for s in stk._JIT_CACHE
-                  if s[0].startswith("('ragged'")}
+                  if s.startswith("('ragged'")}
     assert union_sigs
     # steady state: both compositions now ride the ONE union plan —
     # no new executable for either sub-composition
     assert _run_one_batch(layer, batch1) == solo_expect(plain, batch1)
     assert _run_one_batch(layer, batch2) == solo_expect(plain, batch2)
     assert {s for s in stk._JIT_CACHE
-            if s[0].startswith("('ragged'")} == union_sigs
+            if s.startswith("('ragged'")} == union_sigs
     assert len(layer._ragged_canon.slots) == 6
 
 
@@ -516,9 +516,9 @@ def _spy_dispatches(monkeypatch):
     seen = []
     real = ragged._dispatch_served
 
-    def spy(eng, plan, leaves, params, *rest):
+    def spy(plan, leaves, params, *rest):
         seen.append((plan, list(leaves), list(params)))
-        return real(eng, plan, leaves, params, *rest)
+        return real(plan, leaves, params, *rest)
     monkeypatch.setattr(ragged, "_dispatch_served", spy)
     return seen
 
@@ -599,11 +599,11 @@ def test_same_structure_different_rows_share_one_executable(
     assert _run_one_batch(layer, batch(0)) == solo_expect(plain, batch(0))
     (plan, _leaves, _params), = seen
     assert any(sub[0] == "segcount" for sub in plan[3])
-    fn = stk._JIT_CACHE[(repr(plan), False)][0]
+    fn = stk._JIT_CACHE[repr(plan)][0]
     n_exec = fn._cache_size()
     for i in (1, 2, 3):
         assert _run_one_batch(layer, batch(i)) \
             == solo_expect(plain, batch(i))
     assert [p for p, _l, _p in seen] == [plan] * 4
-    assert stk._JIT_CACHE[(repr(plan), False)][0] is fn
+    assert stk._JIT_CACHE[repr(plan)][0] is fn
     assert fn._cache_size() == n_exec

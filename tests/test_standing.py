@@ -57,6 +57,30 @@ def test_count_incremental_bit_exact():
     assert sq.stats["incremental"] >= 4  # idempotent replays may noop
 
 
+def test_maintained_polls_build_no_stacks():
+    """Polls of maintained results ride the write-through cache:
+    between registration and quiesce, under interleaved writes, no
+    stack is built, rebuilt or patched for them (maintenance is
+    host-side)."""
+    from pilosa_tpu.obs import metrics
+    h, ex, srv = build()
+    qs = ["Count(Row(a=1))", "TopN(a, n=3)", "GroupBy(Rows(a), Rows(b))"]
+    for q in qs:
+        srv.standing.register("i", q)
+
+    def builds():
+        return sum(metrics.STACK_CACHE.value(outcome=oc)
+                   for oc in ("miss", "rebuild", "page_rebuild", "patch"))
+    b0 = builds()
+    for w in ["Set(3001, a=1)", "Set(3002, b=2)", "Clear(1, a=1)",
+              "Set(7, a=3)", "Clear(3002, b=2)"]:
+        ex.execute_serving("i", w)
+        got = [ex.execute_serving("i", q) for q in qs]
+    assert builds() == b0
+    cold_ex = Executor(h)
+    assert got == [cold_ex.execute("i", q) for q in qs]
+
+
 def test_property_interleaved_all_kinds():
     """Seeded property suite: randomized interleaved writes vs
     standing Count/TopN/GroupBy, bit-exact vs cold at every poll."""

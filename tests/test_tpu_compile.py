@@ -1,12 +1,15 @@
-"""The main path's Pallas kernels, compiled for a TPU v5e that is
-described, not attached (on-chip-measurement guide, section 2).
+"""The main path's programs, compiled for a TPU v5e that is described,
+not attached (on-chip-measurement guide, section 2).
 
 Interpret mode — what every other kernel test runs — checks neither
 Mosaic's block-shape rules nor its VMEM limit: both one-pass GroupBy
 kernels passed every test and were refused at lowering.  These cases
 compile each kernel at the real shard width (W = 32768 words) and
-assert the compiled program carries the kernel (``tpu_custom_call``).
-Nothing runs; a compile that passes is not a chip run.
+assert the compiled program carries the kernel (``tpu_custom_call``),
+and compile the program the default engine builds for each template
+of the benchmark's two traffic mixes: XLA serves the scans, one named
+kernel serves GroupBy.  Nothing runs; a compile that passes is not a
+chip run.
 
 One file, and the topology is described inside a fixture: only the
 xdist worker that is handed this file loads libtpu.
@@ -64,6 +67,12 @@ ABLE_REG = ABLE + ((2, 4),)           # the 240-group form
 V5E_SCOPED_VMEM = 16 << 20
 
 
+def _kernel_calls(text):
+    """The compiled program's Pallas calls, one HLO line each."""
+    return [ln for ln in text.split("\n")
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
 def _fused(n_codes, depth, minmax=False, cb=6, signed=True, digits=None,
            body="packed"):
     """groupby_fused at W = 32768; `body` is the one its shapes must
@@ -92,6 +101,7 @@ def _groupby_sum():
     return fn, [(6, S, W), (2, S, W), (5, S, W), (S, 10, W)]
 
 
+OVER_BOUNDS = "groupby_codes_xla_over_bounds"
 CASES = {
     "groupby_fused_count": lambda: _fused(64, 0),
     "groupby_fused_sum": lambda: _fused(64, 8),
@@ -106,18 +116,14 @@ CASES = {
                                                body="onehot"),
     "groupby_fused_minmax_bounds": lambda: _fused(4096, 16, minmax=True,
                                                   cb=12, body="onehot"),
-    "groupby_onehot": lambda: (
-        lambda cp, va, pl: kernels.groupby_onehot(cp, va, pl, 64, True),
-        [(S, 6, W), (S, W), (S, 10, W)]),
     "groupby_sum": _groupby_sum,
     "bsi_value_hist": lambda: (kernels.bsi_value_hist,
                                [(S, 10, W), (S, W)]),
-    "popcount_rows": lambda: (kernels.popcount_rows, [(S, W)]),
-    "pair_popcount": lambda: (kernels.pair_popcount, [(S, W), (S, W)]),
-    "masked_popcount": lambda: (kernels.masked_popcount, [(S, W), (W,)]),
-    "bsi_sum_counts": lambda: (kernels.bsi_sum_counts, [(10, W), (W,)]),
-    "rows_filter_counts": lambda: (kernels.rows_filter_counts,
-                                   [(8, S, W), (S, W)]),
+    # past stacked._ONEPASS_KERNEL_MAX_CODES a TPU takes the XLA
+    # scatter (taxi-1b's Q4: three fields, 8,192 codes, count-only)
+    OVER_BOUNDS: lambda: (
+        lambda cp, va: kernels.groupby_codes_xla(cp, va, None, 8192),
+        [(S, 13, W), (S, W)]),
 }
 
 
@@ -127,7 +133,7 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip)
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == (name != OVER_BOUNDS)
 
 
 # the able query's forms through the packed body (ISSUE 32): age is an
@@ -152,8 +158,7 @@ def test_packed_body_fits_v5e_vmem(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip)
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    calls = [ln for ln in text.split("\n")
-             if 'custom_call_target="tpu_custom_call"' in ln]
+    calls = _kernel_calls(text)
     assert len(calls) == 1 and "groupby_fused_" in calls[0]
     asked = [int(n) for n in re.findall(
         r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
@@ -162,10 +167,12 @@ def test_packed_body_fits_v5e_vmem(one_chip, name):
     assert asked[0] <= kernels._PACKED_VMEM_BYTES + (1 << 20), asked
 
 
-@pytest.mark.parametrize("arm", ["fused", "onehot"])
+@pytest.mark.parametrize("arm", ["fused", "xla"])
 def test_onepass_shard_map_compiles_for_four_chips(topo, arm):
     """The mesh GroupBy wrapper (stacked._groupby_onepass_shard_map)
-    over the four described chips: per-device kernel + psum."""
+    over the four described chips: per-device kernel — or, past the
+    kernel's bounds (8,192 codes), the XLA scatter — + psum."""
+    cb = {"fused": 6, "xla": 13}[arm]
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("rows", "shards"))
     flat = ("rows", "shards")
 
@@ -174,10 +181,106 @@ def test_onepass_shard_map_compiles_for_four_chips(topo, arm):
                                     sharding=NamedSharding(mesh, spec))
     fn = stacked._groupby_onepass_shard_map(
         mesh, arm, has_planes=True, has_filter=True, signed=True,
-        n_codes=64)
-    compiled = fn.lower(sds((S, 7, W), P(flat, None, None)),
+        n_codes=1 << cb)
+    compiled = fn.lower(sds((S, cb + 1, W), P(flat, None, None)),
                         sds((S, W), P(flat, None)),
                         sds((S, 10, W), P(flat, None, None))).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == (arm == "fused")
     assert "all-reduce" in text
+
+
+# -- the served programs ------------------------------------------------
+#
+# benchmark/traffic/points-zipf-solo.json's eight templates and
+# groupby60-distinct.json's four forms, as a lone caller sends them.
+_R = "Row(a=1), Row(edu=2)"
+_G = "GroupBy(Rows(edu), Rows(gen), Rows(dom), filter="
+SERVED = {
+    "count_intersect": (f"Count(Intersect({_R}))", None),
+    "count_union": (f"Count(Union({_R}))", None),
+    "count_xor": (f"Count(Xor({_R}))", None),
+    "count_difference": (f"Count(Difference({_R}))", None),
+    "count_intersect_range": (
+        f"Count(Intersect({_R}, Row(age > 40)))", None),
+    "count_range": ("Count(Row(age > 40))", None),
+    "topn_filtered": (f"TopN(t, Intersect({_R}), n=5)", None),
+    "sum_filtered": (f"Sum(Intersect({_R}), field=age)", None),
+    "g60_sum_row": (_G + "Row(t=3), aggregate=Sum(field=age))",
+                    "groupby_fused_sum"),
+    "g60_sum_range": (_G + "Row(age > 40), aggregate=Sum(field=age))",
+                      "groupby_fused_sum"),
+    "g60_count": (_G + "Row(t=3))", "groupby_fused_sum"),
+    "g60_min": (_G + "Row(t=3), aggregate=Min(field=age))",
+                "groupby_fused_minmax"),
+}
+
+
+@pytest.fixture(scope="module")
+def served(topo, one_chip):
+    """`programs(pql)`: the device programs one request of the default
+    serving engine dispatches, each compiled for the described chip.
+    able's field shapes (benchmark/configs/able-1b.json) on 3 shards
+    of 2^20 columns; every dispatch is caught where the engine makes
+    it (dispatch_ready) and answered with zeros of the right shapes,
+    so nothing runs here either.  The two GroupBy variables are the
+    arms a TPU picks by itself (chip_smoke.py sets the same)."""
+    from pilosa_tpu.executor import ragged, serving
+    from pilosa_tpu.executor.executor import Executor
+    from pilosa_tpu.models.holder import Holder
+    from pilosa_tpu.models.schema import FieldOptions, FieldType
+
+    rng = np.random.default_rng(33)
+    h = Holder()
+    idx = h.create_index("able", track_existence=True)
+    cols = np.arange(0, 3 * idx.width, 997)
+    for name, rows in (("a", 2), ("b", 2), ("t", 8), ("edu", 6),
+                       ("gen", 2), ("dom", 5), ("reg", 4)):
+        idx.create_field(name, FieldOptions(type=FieldType.SET)) \
+            .import_bits(rng.integers(0, rows, size=len(cols)), cols)
+    idx.create_field(
+        "age", FieldOptions(type=FieldType.INT, min=0, max=127)) \
+        .import_values(cols, rng.integers(0, 128, size=len(cols)).tolist())
+    idx.mark_columns_exist([int(c) for c in cols])
+    ex = Executor(h)
+    ex.enable_serving(cache_bytes=0)
+
+    seen = []
+
+    def catch(fn, *args):
+        seen.append((fn.__wrapped__, jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), args)))
+        return jax.tree_util.tree_map(
+            lambda o: np.zeros(o.shape, o.dtype),
+            jax.eval_shape(fn.__wrapped__, *args))
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "fused")
+    mp.setenv("PILOSA_TPU_GROUPBY_KERNEL", "1")
+    for mod in (stacked, ragged, serving):
+        mp.setattr(mod, "dispatch_ready", catch)
+
+    def programs(pql):
+        del seen[:]
+        ex.execute_serving("able", pql)
+        return [jax.jit(fn).lower(*args).compile().as_text()
+                for fn, args in seen]
+    yield programs
+    mp.undo()
+
+
+@pytest.mark.parametrize("template", list(SERVED))
+def test_served_program_compiles_for_v5e(served, template):
+    """One request of each template compiles for the chip; a point
+    read's programs hold no Pallas call, a GroupBy's exactly one, the
+    fused kernel under its own name."""
+    import re
+    pql, kernel = SERVED[template]
+    texts = served(pql)
+    assert texts
+    assert any("HloModule jit_plan_ragged" in t for t in texts) \
+        == (template != "g60_min")       # Min/Max: the solo one-pass
+    calls = [ln for t in texts for ln in _kernel_calls(t)]
+    assert [re.search(r"%(groupby_fused_[a-z]+)", c).group(1)
+            for c in calls] == ([kernel] if kernel else [])
