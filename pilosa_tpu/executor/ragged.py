@@ -18,7 +18,10 @@ per group, drive one kernel over a *page table* —
   INSIDE the fused program (the "ragged" plan kind in stacked.py) the
   leaf is assembled from that run exactly once, in the shape its
   consumers read (``ops.bitmap.concat_pages``: concatenate, trim the
-  last page's padding, reshape; a one-page leaf is the page itself),
+  last page's padding, reshape; a one-page leaf is the page itself;
+  a BSI field's (S, 2+depth, W) leaf that the compare or the sum
+  reads comes as its 2+depth planes, each a row gather out of the
+  concatenation, and is never reshaped),
   so the per-access assemble dispatch disappears and nothing is
   copied that the query does not read;
 - single-leaf Counts — the dominant point-read shape — skip operand

@@ -1506,26 +1506,49 @@ def _eval(node, leaves, params):
         return acc
     if k == "shift":
         return bm.shift(_eval(node[2], leaves, params), node[1])
+    # ops/bsi.py indexes a plane on the leading axis and is
+    # elementwise in the rest: the whole field goes through in one call
     if k == "bsi_cmp":
-        planes = leaves[node[1]]                      # (S, P, W)
+        planes = _planes(leaves[node[1]])
         fn = _BSI_CMP[node[2]]
         pb, neg = params[node[3]], params[node[4]]
         with jax.named_scope("bsi_compare"):
-            return jax.vmap(fn, in_axes=(0, None, None))(planes, pb, neg)
+            return fn(planes, pb, neg)
     if k == "bsi_between":
-        planes = leaves[node[1]]
+        planes = _planes(leaves[node[1]])
         ab, bb = params[node[2]], params[node[3]]
         an, bn = params[node[4]], params[node[5]]
         with jax.named_scope("bsi_compare"):
-            return jax.vmap(bsi_ops.range_between,
-                            in_axes=(0, None, None, None, None))(
-                planes, ab, bb, an, bn)
+            return bsi_ops.range_between(planes, ab, bb, an, bn)
     if k == "bsi_notnull":
-        return leaves[node[1]][:, 0]                  # exists plane
+        return _planes(leaves[node[1]])[bsi_ops.BSI_EXISTS_BIT]
     if k == "bsi_null":
-        planes = leaves[node[1]]
-        return bm.difference(leaves[node[2]], planes[:, 0])
+        exists = _planes(leaves[node[1]])[bsi_ops.BSI_EXISTS_BIT]
+        return bm.difference(leaves[node[2]], exists)
     raise AssertionError(f"bad IR node {k}")
+
+
+def _planes(leaf):
+    """A BSI leaf as ops/bsi.py reads it, plane first: the planes a
+    ragged program gathered out of its pages (bm.concat_pages), or
+    the resident (S, 2+depth, W) stack seen as (2+depth, S, W)."""
+    return leaf if isinstance(leaf, tuple) else jnp.swapaxes(leaf, 0, 1)
+
+
+def _plane_readers(plan, xla: set, kernel: set):
+    """Into `xla` the leaves that a compare, a null test or a sum
+    under `plan` reads as BSI planes, into `kernel` those a GroupBy
+    kernel blocks: each names its leaf in a fixed place of its
+    tuple."""
+    if isinstance(plan, tuple) and plan:
+        k = plan[0]
+        if k in ("bsi_cmp", "bsi_between", "bsi_notnull", "bsi_null",
+                 "bsi_sum"):
+            xla.add(plan[1])
+        elif k == "gb_hist" and plan[3] is not None:
+            kernel.add(plan[3])
+        for c in plan:
+            _plane_readers(c, xla, kernel)
 
 
 def _as_stack(out, leaves):
@@ -1578,7 +1601,9 @@ def _plan_run(plan):
         # vmeta = ((leaf_start, n_pages, shape), ...): each virtual
         # leaf owns a static run of the page leaves — its real pages,
         # each once — and is assembled from them exactly once, in the
-        # shape its consumers read (bm.concat_pages).  subs evaluate
+        # shape its consumers read (bm.concat_pages: a BSI leaf as
+        # its planes for the compare and the sum, as the resident
+        # stack where a GroupBy kernel blocks it).  subs evaluate
         # over the combined virtual+direct leaf space like "multi",
         # except ("segcount", ((leaf_start, n_pages), ...), sparam,
         # nseg) entries, which reduce a whole family of single-leaf
@@ -1588,11 +1613,16 @@ def _plan_run(plan):
         n_pages, vmeta, subs = plan[1], plan[2], plan[3]
         runs = tuple(None if s[0] == "segcount" else _plan_run(s)
                      for s in subs)
+        # virtual leaves come first in the combined leaf space
+        xla, kernel = set(), set()
+        _plane_readers(subs, xla, kernel)
+        gathered = xla - kernel
 
         def run(leaves, params):
             with jax.named_scope("page_gather"):
-                vl = tuple(bm.concat_pages(leaves[start:start + n], shape)
-                           for start, n, shape in vmeta)
+                vl = tuple(bm.concat_pages(leaves[start:start + n], shape,
+                                           planes=i in gathered)
+                           for i, (start, n, shape) in enumerate(vmeta))
             all_leaves = vl + tuple(leaves[n_pages:])
             outs = []
             for s, r in zip(subs, runs):
@@ -1699,14 +1729,10 @@ def _plan_run(plan):
         planes_i, tree, reduce_ = plan[1], plan[2], plan[3]
 
         def run(leaves, params):
-            planes = leaves[planes_i]                 # (S, P, W)
-            if tree is None:
-                cnt, pos, neg = jax.vmap(
-                    lambda p: bsi_ops.sum_counts(p, None))(planes)
-            else:
-                filt = _filter(tree, leaves, params)
-                cnt, pos, neg = jax.vmap(
-                    bsi_ops.sum_counts)(planes, filt)
+            filt = (None if tree is None
+                    else _filter(tree, leaves, params))
+            cnt, pos, neg = bsi_ops.sum_counts(
+                _planes(leaves[planes_i]), filt)      # (S,), (S, P) x2
             if reduce_:
                 return (jnp.sum(cnt), jnp.sum(pos, axis=0),
                         jnp.sum(neg, axis=0))         # scalar, (P,), (P,)

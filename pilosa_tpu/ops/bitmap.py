@@ -263,17 +263,28 @@ def named(name: str):
     return deco
 
 
-def concat_pages(pages, shape: tuple):
+def concat_pages(pages, shape: tuple, planes: bool = False):
     """The assembly graph, un-jitted: page blocks (each (page_lanes,
     W)) concatenated along the lane axis, the final page's padding
     trimmed, the logical shape restored.  A one-page operand is the
     page itself.  The ragged plan kind (executor/stacked.py) builds
-    each of its operands with this inside its own program."""
+    each of its operands with this inside its own program.
+
+    `planes` asks for a BSI leaf — resident (S, 2+depth, W), lane =
+    shard * (2+depth) + plane — as its 2+depth (S, W) planes, each a
+    row gather out of the page concatenation: what ops/bsi.py's
+    compare and sum read.  Slicing plane r out of the reshaped stack
+    instead costs a padded copy of the whole leaf (9 sublanes padded
+    to 16) and one T(1,128)-tiled copy per plane: 18.9 ms against 6.2
+    for a range Count over 954 shards (PERF.md §6 "PR 34")."""
     n_lanes = 1
     for d in shape[:-1]:
         n_lanes *= int(d)
     flat = jnp.concatenate(pages, axis=0) if len(pages) > 1 else pages[0]
-    return flat[:n_lanes].reshape(shape)
+    flat = flat[:n_lanes]
+    if planes:
+        return tuple(flat[r::shape[1]] for r in range(shape[1]))
+    return flat.reshape(shape)
 
 
 @_partial(jax.jit, static_argnums=(1,))

@@ -8,7 +8,12 @@ an integer field's shard is a stack of packed bit-planes —
 - plane 2+i: magnitude bit i, LSB first (bsiOffsetBit)
 
 i.e. sign-magnitude, NOT two's complement.  ``planes`` arrays have shape
-``(2 + depth, W)`` uint32 with W packed words per shard-row.
+``(2 + depth, W)`` uint32 with W packed words per shard-row.  The
+range and sum functions below index a plane on the LEADING axis and
+are elementwise in the rest, so they take a whole field as they take
+one shard: a ``(2 + depth, S, W)`` array, or the 2 + depth ``(S, W)``
+planes as a sequence (what a ragged program gathers out of its pages,
+ops/bitmap.py PlaneLeaf).
 
 The reference computes Range/Min/Max with data-dependent bitmap walks
 (fragment.go:937-1305).  Here the same semantics are expressed as
@@ -208,8 +213,7 @@ def cmp_unsigned(mag_planes, pbits):
     1158-1213, 968-1005): each of depth steps is 4 VPU ops on 32768
     lanes, with no data-dependent control flow.
     """
-    depth = mag_planes.shape[0]
-    w = mag_planes.shape[-1]
+    depth = len(mag_planes)
     lt = jnp.zeros_like(mag_planes[0])
     eq = jnp.full_like(mag_planes[0], _ONES)
     for i in range(depth - 1, -1, -1):
@@ -310,7 +314,8 @@ def sum_counts(planes, filter_words=None):
 
     Returns (count, pos_pc, neg_pc): count of non-null (filtered)
     columns, and per-magnitude-plane popcounts split by sign, each
-    (depth,) int32.  Host computes  sum = Σ (pos[i]-neg[i]) << i  in
+    (depth,) int32 — over a whole field's planes, (S,) and (S, depth).
+    Host computes  sum = Σ (pos[i]-neg[i]) << i  in
     exact Python ints — the analog of roaring.BitmapBSICountFilter
     (fragment.sum, fragment.go:718-746) with int64-exactness preserved.
     """
@@ -319,9 +324,15 @@ def sum_counts(planes, filter_words=None):
     pos = consider & ~sign
     neg = consider & sign
     mag = _mag(planes)
-    pos_pc = bm.count(mag & pos[None, :])
-    neg_pc = bm.count(mag & neg[None, :])
-    return bm.count(consider), pos_pc, neg_pc
+
+    def plane_counts(side):
+        if isinstance(mag, tuple):      # gathered planes, one by one
+            return jnp.stack([bm.count(m & side) for m in mag], axis=-1)
+        # one array, one fusion; plane by plane XLA would cut it into
+        # T(1,128)-tiled slices first (compiled for a v5e, PR 34)
+        return jnp.moveaxis(bm.count(mag & side[None]), 0, -1)
+
+    return bm.count(consider), plane_counts(pos), plane_counts(neg)
 
 
 def host_sum(count, pos_pc, neg_pc) -> tuple[int, int]:

@@ -239,6 +239,59 @@ def test_virtual_leaf_equals_assemble_pages(shape, page_lanes):
     assert (np.asarray(outs[0]) == want).all()
 
 
+@pytest.mark.parametrize("shards,planes,page_lanes", [
+    (5, 3, 4),      # 15 lanes: pages cut across shards and planes
+    (3, 9, 32),     # one page holds the leaf and padding
+    (16, 4, 8),     # whole pages, every page full
+    (33, 18, 32),   # deep field, many pages, a ragged tail
+], ids=["straddling", "one-page", "whole-pages", "deep"])
+def test_bsi_leaf_is_gathered_plane_by_plane(shards, planes, page_lanes):
+    """A BSI leaf that only the compare and the sum read is, inside
+    the program, its planes, each a row gather out of the page
+    concatenation (bm.concat_pages `planes`) — the last page's
+    padding, poisoned here, reaches no consumer — and the answers are
+    those of the resident (S, P, W) stack; a leaf a GroupBy kernel
+    blocks stays that stack."""
+    import numpy as np
+
+    from pilosa_tpu.executor import stacked as stk
+    from pilosa_tpu.ops import bitmap as bm
+    from pilosa_tpu.ops import bsi
+
+    shape = (shards, planes, 16)
+    pv = _page_view(np.random.default_rng(14), shape, page_lanes)
+    want = np.concatenate(pv.pages)[:pv.lanes].reshape(shape).copy()
+    pv.pages[-1][pv.lanes % page_lanes or page_lanes:] = 0xFFFFFFFF
+    got = bm.concat_pages(tuple(pv.pages), shape, planes=True)
+    assert isinstance(got, tuple) and len(got) == planes
+    assert all((np.asarray(g) == want[:, r]).all()
+               for r, g in enumerate(got))
+    assert (np.asarray(bm.concat_pages(tuple(pv.pages), shape))
+            == want).all()
+    row = np.random.default_rng(15).integers(
+        0, 1 << 32, size=(shards, 16), dtype=np.uint32)
+    subs = [("words", ("bsi_notnull", 0)),
+            ("words", ("bsi_null", 0, 1)),
+            ("bsi_sum", 0, ("leaf", 1), False)]
+    xla, kernel = set(), set()
+    stk._plane_readers(tuple(subs), xla, kernel)
+    assert (xla, kernel) == ({0}, set())
+    stk._plane_readers((("gb_hist", 2, None, 0, 4, False, "xla", None),),
+                       xla, kernel)
+    assert kernel == {0}
+    plan, pleaves, _params, outs = _finalize_one_group([pv, row], subs)
+    assert plan[2] == ((0, len(pv.pages), shape),)
+    assert [id(p) for p in pleaves[:-1]] == [id(p) for p in pv.pages]
+    assert (np.asarray(outs[0]) == want[:, 0]).all()
+    assert (np.asarray(outs[1]) == row & ~want[:, 0]).all()
+    cnt, pos, neg = (np.asarray(o) for o in outs[2])
+    for si in range(shards):
+        c, p_, n = bsi.sum_counts(want[si], row[si])
+        assert cnt[si] == c
+        assert (pos[si] == np.asarray(p_)).all()
+        assert (neg[si] == np.asarray(n)).all()
+
+
 def test_virtual_leaf_shared_by_two_subplans_is_assembled_once():
     """Two sub-plans over one leaf read ONE virtual leaf: its pages
     enter the program once, beside the other leaf's and before the

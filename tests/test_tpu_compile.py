@@ -216,6 +216,10 @@ SERVED = {
 }
 
 
+# the point reads of the int field
+BSI_POINTS = ("count_intersect_range", "count_range", "sum_filtered")
+
+
 @pytest.fixture(scope="module")
 def served(topo, one_chip):
     """`programs(pql)`: the device programs one request of the default
@@ -274,7 +278,10 @@ def served(topo, one_chip):
 def test_served_program_compiles_for_v5e(served, template):
     """One request of each template compiles for the chip; a point
     read's programs hold no Pallas call, a GroupBy's exactly one, the
-    fused kernel under its own name."""
+    fused kernel under its own name.  A point read of the age planes
+    (9 of them, 3 shards) gathers each out of the page concatenation:
+    it never re-lays the leaf out as the (S, P, W) stack nor slices a
+    plane into (1, 128) tiles."""
     import re
     pql, kernel = SERVED[template]
     texts = served(pql)
@@ -284,3 +291,8 @@ def test_served_program_compiles_for_v5e(served, template):
     calls = [ln for t in texts for ln in _kernel_calls(t)]
     assert [re.search(r"%(groupby_fused_[a-z]+)", c).group(1)
             for c in calls] == ([kernel] if kernel else [])
+    if template in BSI_POINTS:
+        assert not any(re.search(
+            r"= u32\[3,9,32768\]\S* (?!bitcast\()", t) for t in texts)
+        assert not any(re.search(
+            r"= u32\[3,1,32768\]\{[^}]*T\(1,128\)", t) for t in texts)
