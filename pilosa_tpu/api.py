@@ -249,6 +249,9 @@ class API:
                 fence_done()
         with flight.stage("result.encode"):
             resp = {"results": [serialize_result(r) for r in results]}
+            for r in results:
+                if isinstance(r, list) and r and isinstance(r[0], GroupCount):
+                    metrics.GROUPBY_REPLY_GROUPS.inc(len(r))
         if profile and tracer.roots:
             resp["profile"] = [s.to_dict() for s in tracer.roots]
         self._record_history(index, pql, t0, tracer)

@@ -58,7 +58,6 @@ from pilosa_tpu.obs import flight, metrics
 from pilosa_tpu.obs import stats as _stats
 from pilosa_tpu.obs.monitor import capture_exception
 from pilosa_tpu.obs.tracing import capture_context, start_span
-from pilosa_tpu.ops import kernels
 from pilosa_tpu.pql import parse
 from pilosa_tpu.pql.ast import Call, Query
 
@@ -1329,10 +1328,10 @@ class ServingLayer:
             _code_digits,
             _code_space,
             _combo_codes,
-            _onepass_arm,
+            _count_onepass,
+            _onepass_plan,
             _onepass_unpack,
         )
-        from pilosa_tpu.obs.metrics import GROUPBY_FUSED, GROUPBY_ONEPASS
 
         ex = self.executor
         eng = ex.stacked
@@ -1365,7 +1364,14 @@ class ServingLayer:
             raise Unstackable("groupby shape not one-pass batchable")
         bits, shifts, n_codes = _code_space(fields_rows)
         codes = _combo_codes(shifts, combos)
-        arm = _onepass_arm(n_codes, depth)
+        digits = _code_digits(fields_rows)
+        signed = False
+        if agg_field is not None:
+            frags = eng._frags(idx, agg_field, agg_field.bsi_view,
+                               list(skey))
+            signed = any(fr is not None and 1 in fr.row_ids
+                         for fr in frags)
+        arm, body, passes = _onepass_plan(n_codes, depth, digits, signed)
         if arm != "xla":
             from pilosa_tpu.memory import placement as _placement
             if (eng._n_total_devices() > 1
@@ -1376,12 +1382,6 @@ class ServingLayer:
                 # (or fail to lower and demote every rider in the
                 # batch); the scatter reference shards under GSPMD
                 arm = "xla"
-        signed = False
-        if agg_field is not None:
-            frags = eng._frags(idx, agg_field, agg_field.bsi_view,
-                               list(skey))
-            signed = any(fr is not None and 1 in fr.row_ids
-                         for fr in frags)
         filter_call = call.arg("filter")
         tree = None
         if filter_call is not None:
@@ -1392,11 +1392,7 @@ class ServingLayer:
         cg_i = b._groupcode_leaf(fields_rows)
         planes_i = (b._planes_leaf(agg_field)
                     if agg_field is not None else None)
-        GROUPBY_ONEPASS.inc()
-        digits = _code_digits(fields_rows)
-        if arm == "fused":
-            GROUPBY_FUSED.inc(path="batched", body=kernels.fused_body(
-                digits, depth, signed))
+        _count_onepass((arm, body, passes), "batched")
         has_planes = agg_field is not None
 
         def demux_groupby(out):

@@ -1213,7 +1213,7 @@ def _gb_jit_put(key, fn):
 # axis and unrolled payload stay within VMEM/compile budgets below
 # 4096 codes x depth 16
 _ONEPASS_MAX_CODES = 1 << 20
-_ONEPASS_KERNEL_MAX_CODES = 4096
+_ONEPASS_KERNEL_MAX_CODES = kernels.ONEHOT_MAX_CODES
 _ONEPASS_KERNEL_MAX_DEPTH = 16
 
 
@@ -1277,27 +1277,58 @@ def _forced_arm() -> str | None:
     return forced if forced in ("fused", "xla") else None
 
 
-def _onepass_arm(n_codes: int, depth: int,
-                 mesh_minmax: bool = False) -> str:
+def _onepass_arm(n_codes: int, depth: int, mesh_minmax: bool = False,
+                 body: str | None = None) -> str:
     """Which one-pass device program serves the histogram:
 
     - "fused" — the single-pass kernel on packed words
-      (groupby_fused; its body, packed or one-hot, is
-      kernels.fused_body's choice from the shapes): on a TPU, inside
-      _ONEPASS_KERNEL_MAX_*
+      (groupby_fused; `body` is the one its shapes take,
+      kernels.fused_plan's choice): on a TPU, inside
+      _ONEPASS_KERNEL_MAX_*; past _ONEPASS_KERNEL_MAX_CODES where the
+      packed body walks the live groups (in passes, if need be: a
+      10 x 8 x 60 GroupBy has 8,192 codes and 4,800 groups) — the
+      one-hot body's lane axis is the code space and never goes there
     - "xla"   — the scatter-add form (groupby_codes_xla): off a TPU
       (a CPU would only interpret the kernel), past the bounds (a
       2^20-code value histogram under the kernel would build a
-      ~128 MB per-chunk one-hot), and for a Min/Max over a mesh (a
-      pallas_call over a mesh-sharded operand would force a gather;
+      million-lane one-hot), and for Min/Max on a mesh (the Pallas
+      call is opaque to the partitioner and would replicate the stack;
       the scatter shards under GSPMD)
 
     _forced_arm() stands in for the backend and lifts no bound."""
-    if (mesh_minmax or n_codes > _ONEPASS_KERNEL_MAX_CODES
-            or depth > _ONEPASS_KERNEL_MAX_DEPTH):
+    if mesh_minmax or depth > _ONEPASS_KERNEL_MAX_DEPTH:
+        return "xla"
+    if n_codes > _ONEPASS_KERNEL_MAX_CODES and body != "packed":
         return "xla"
     return _forced_arm() or (
         "fused" if jax.default_backend() == "tpu" else "xla")
+
+
+def _onepass_plan(n_codes: int, depth: int, digits, signed: bool,
+                  minmax: bool = False, mesh_minmax: bool = False):
+    """(arm, body, passes) of one one-pass dispatch, derived once from
+    what the kernel would be handed: `depth` is the payload's (0 with
+    no aggregate), `digits` the fields' layout (_code_digits)."""
+    body, passes = kernels.fused_plan(digits, depth, signed, minmax)
+    return _onepass_arm(n_codes, depth, mesh_minmax, body), body, passes
+
+
+def _count_onepass(plan, path: str) -> None:
+    """One one-pass dispatch in the counters, where its arm is known:
+    pilosa_groupby_onepass_total{arm} always, and for the kernel the
+    body its shapes take and the walks it makes over its operands.
+    `plan` is _onepass_plan's; the host histogram counts as
+    ("host", None, 0)."""
+    from pilosa_tpu.obs.metrics import (
+        GROUPBY_FUSED,
+        GROUPBY_ONEPASS,
+        GROUPBY_PASSES,
+    )
+    arm, body, passes = plan
+    GROUPBY_ONEPASS.inc(arm=arm)
+    if arm == "fused":
+        GROUPBY_FUSED.inc(path=path, body=body)
+        GROUPBY_PASSES.inc(passes)
 
 
 def _onepass_gb(arm: str, digits=None):
@@ -3365,8 +3396,6 @@ class StackedEngine:
         magnitude Min/Max table out of the SAME tile walk (fused
         kernel masked reduce / XLA scatter / numpy twin) and returns
         (counts, (nn, values)) instead of Sum partials."""
-        from pilosa_tpu.obs.metrics import GROUPBY_FUSED, GROUPBY_ONEPASS
-        GROUPBY_ONEPASS.inc()
         minmax = agg_op in ("min", "max")
         bits, shifts, n_codes = _code_space(fields_rows)
         digits = _code_digits(fields_rows)
@@ -3398,7 +3427,9 @@ class StackedEngine:
             len(skey), idx.width // 32, sum(bits),
             depth if has_planes else 0, filt is not None)
         mm = None
+        kdepth = depth if has_planes else 0
         if host:
+            _count_onepass(("host", None, 0), "onepass")
             out = self._groupby_onepass_host(
                 idx, fields_rows, agg_field, skey, n_codes, depth,
                 signed, filt, minmax=minmax, op_bytes=op_bytes)
@@ -3406,11 +3437,9 @@ class StackedEngine:
             if minmax:
                 mm = out[4]
         elif multi and not minmax:
-            arm = _onepass_arm(n_codes, depth)
-            if arm == "fused":
-                GROUPBY_FUSED.inc(
-                    path="onepass_mesh", body=kernels.fused_body(
-                        digits, depth if has_planes else 0, signed))
+            plan = _onepass_plan(n_codes, kdepth, digits, signed)
+            arm = plan[0]
+            _count_onepass(plan, "onepass_mesh")
             cg = self.groupcode_stack(idx, fields_rows, skey,
                                       flat=True)
             planes = (self.plane_stack_flat(idx, agg_field, skey)
@@ -3442,13 +3471,10 @@ class StackedEngine:
             # combination and so runs the single-jit program over the
             # whole (mesh-sharded) stack (Min/Max traffic is the same
             # single pass; fleets beyond the reduce bound were gated)
-            arm = _onepass_arm(n_codes, depth,
-                               mesh_minmax=multi and minmax)
-            if arm == "fused":
-                GROUPBY_FUSED.inc(
-                    path="onepass", body=kernels.fused_body(
-                        digits, depth if has_planes else 0, signed,
-                        minmax))
+            plan = _onepass_plan(n_codes, kdepth, digits, signed, minmax,
+                                 mesh_minmax=multi and minmax)
+            arm = plan[0]
+            _count_onepass(plan, "onepass")
             cg = self.groupcode_stack(idx, fields_rows, skey)
             planes = (self.plane_stack(idx, agg_field, skey)
                       if has_planes else None)

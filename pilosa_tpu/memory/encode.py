@@ -81,8 +81,15 @@ def _pow2(n: int) -> int:
 def _positions(flat_words: np.ndarray) -> np.ndarray:
     """Sorted flat bit offsets of the set bits of a flat word array
     (LSB-first inside each word, matching ops/bitmap.py's layout)."""
-    bits = np.unpackbits(flat_words.view(np.uint8), bitorder="little")
-    return np.flatnonzero(bits).astype(np.uint32)
+    # only the words that hold a bit are unpacked: a page that is
+    # worth packing is at most 1/64 dense, and most of its words are 0
+    # (nonzero over booleans is numpy's fast path: a third of the time
+    # of the same over words and bytes, on the chip machine's host)
+    nz = np.flatnonzero(flat_words != 0).astype(np.uint32)
+    k = np.flatnonzero(np.unpackbits(
+        flat_words[nz].view(np.uint8), bitorder="little").view(np.bool_)
+    ).astype(np.uint32)
+    return (nz[k >> np.uint32(5)] << np.uint32(5)) | (k & np.uint32(31))
 
 
 class EncodedPage:
@@ -170,11 +177,35 @@ class EncodedPage:
         gather-expand at operand boundaries that need dense tiles)."""
         from pilosa_tpu.ops import bitmap as bm
         if self.kind == "packed":
+            _warm_expand(self.page_lanes, self.width_words)
             return bm.expand_coords(self.coords, self.page_lanes,
                                     self.width_words)
         return bm.expand_runs(self.run_starts, self.run_lens,
                               self.coords, self.page_lanes,
                               self.width_words)
+
+
+_EXPAND_WARM: set = set()
+
+
+def _warm_expand(page_lanes: int, width_words: int) -> None:
+    """Before the first packed page of a shape is expanded, run the
+    expand program of every padded length such a page can come in
+    (the powers of two from _PAD_FLOOR up to the page's words: at
+    most 18), on nothing but sentinels.  The family is closed, so a
+    server compiles all of it when it meets its first packed page, in
+    its warm-up, and not one length at a time as fresh rows of other
+    densities arrive later."""
+    shape = (int(page_lanes), int(width_words))
+    if shape in _EXPAND_WARM:
+        return
+    _EXPAND_WARM.add(shape)
+    from pilosa_tpu.ops import bitmap as bm
+    n_words = shape[0] * shape[1]
+    n = _PAD_FLOOR
+    while n <= n_words:
+        bm.expand_coords(np.full(n, n_words * 32, dtype=np.uint32), *shape)
+        n <<= 1
 
 
 def is_encoded(page) -> bool:

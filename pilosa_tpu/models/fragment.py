@@ -14,6 +14,13 @@ reference's array/bitmap container split (roaring/container_stash.go:
 
 Dense decode happens only at device-upload / read time
 (``row_words``); all mutators work on the compressed form.
+
+A fragment that was bulk-loaded with one row a column (a single-valued
+field: ``import_mutex`` into an empty fragment) may instead be
+**code-held**: one array of each column's row id, 1 or 2 bytes a
+column, where its rows would take more (ten or more rows over a full
+shard).  Reads decode a row from the codes; the first mutation turns
+the fragment back into rows (``_decode``).
 Device-side, a per-row tile cache feeds the XLA kernels, invalidated
 on write.  BSI views reuse the same row space: row 0 = exists, row 1 =
 sign, rows 2.. = magnitude planes (fragment.go:34-66), so BSI plane
@@ -99,6 +106,11 @@ class Fragment:
         self.width = width
         self._rows: dict[int, np.ndarray] = {}   # row id -> packed words
         self._sparse: dict[int, np.ndarray] = {}  # row id -> sorted cols
+        # code-held (see the module docstring): each column's row id,
+        # the dtype's largest value where it has none, and the rows'
+        # bit counts; both stores above are empty while this is set
+        self._codes: np.ndarray | None = None
+        self._code_counts: np.ndarray | None = None
         self._device: dict[int, jnp.ndarray] = {}
         self._planes_cache: jnp.ndarray | None = None
         # monotonically increasing write stamp: every host mutation
@@ -143,7 +155,11 @@ class Fragment:
 
     @property
     def sparse_row_count(self) -> int:
-        """Rows currently held in compressed (column-array) form."""
+        """Rows currently held in a compressed form, not as dense
+        words: the column arrays, or every row of a code-held
+        fragment that has a bit."""
+        if self._code_counts is not None:
+            return int(np.count_nonzero(self._code_counts))
         return len(self._sparse)
 
     def _densify(self, row: int) -> np.ndarray:
@@ -159,6 +175,18 @@ class Fragment:
         if arr.size > SPARSE_MAX:
             self._densify(row)
 
+    def _decode(self) -> None:
+        """A code-held fragment back to rows, each in the form its
+        cardinality asks for.  Every mutator but the bulk load calls
+        this first: they write rows.  The contents do not change, so
+        neither does the version."""
+        codes = self._codes
+        if codes is None:
+            return
+        cols = np.flatnonzero(codes != np.iinfo(codes.dtype).max)
+        self._store_grouped(codes[cols].astype(np.int64), cols)
+        self._codes = self._code_counts = None
+
     # -- host mutation ------------------------------------------------------
 
     def _row_mut(self, row: int, lo: int | None = None,
@@ -166,6 +194,7 @@ class Fragment:
         """Mutable DENSE words for a row (densifying if needed) —
         the bulk/word-level write path.  `lo`/`hi` bound the word span
         the caller is about to dirty (whole row when omitted)."""
+        self._decode()
         w = self._rows.get(row)
         if w is None:
             if row in self._sparse:
@@ -327,6 +356,12 @@ class Fragment:
         """Full-fragment invariant sweep (rbf Tx.Check analog)."""
         for r in set(self._rows) | set(self._sparse):
             self.check_row(r)
+        if self._codes is not None:
+            assert not self._rows and not self._sparse, \
+                "code-held fragment with rows besides"
+            assert self._codes.size == self.width and np.array_equal(
+                np.bincount(self._codes)[:self._code_counts.size],
+                self._code_counts), "codes and their counts differ"
         assert self.version >= 0
 
     def set_row_words(self, row: int, words) -> None:
@@ -334,6 +369,7 @@ class Fragment:
         result re-compresses when it lands under the threshold.  The
         old contents are fully replaced, so they are dropped without
         decoding."""
+        self._decode()
         self._invalidate(row)
         self._sparse.pop(row, None)
         w = self._rows.get(row)
@@ -350,6 +386,7 @@ class Fragment:
     def set_bit(self, row: int, col: int) -> bool:
         """Set one bit; returns True if it changed (fragment.setBit)."""
         assert 0 <= col < self.width
+        self._decode()
         wi = col >> 5
         words = self._rows.get(row)
         if words is None:
@@ -377,6 +414,7 @@ class Fragment:
         return True
 
     def clear_bit(self, row: int, col: int) -> bool:
+        self._decode()
         wi = col >> 5
         words = self._rows.get(row)
         if words is None:
@@ -398,6 +436,18 @@ class Fragment:
         self.touch(row, wi, wi + 1)
         return True
 
+    @staticmethod
+    def _by_row(rows: np.ndarray, cols: np.ndarray):
+        """(rows, cols) grouped by row with one stable sort (columns
+        keep their order within a row).  numpy's stable sort is radix
+        for <=16-bit ints (6x the int64 mergesort, measured r04) — row
+        ids are usually small category ids, so cast when they fit."""
+        key = rows
+        if rows.size and 0 <= rows.min() and rows.max() < 32767:
+            key = rows.astype(np.int16)
+        order = np.argsort(key, kind="stable")
+        return rows[order], cols[order]
+
     def import_bits(self, rows, cols, clear: bool = False,
                     presorted: bool = False):
         """Bulk set/clear: vectorized merge per distinct row
@@ -408,6 +458,7 @@ class Fragment:
         rows = np.asarray(rows, dtype=np.int64)
         cols = np.asarray(cols, dtype=np.int64)
         assert rows.shape == cols.shape
+        self._decode()
         if cols.size:
             # validate once up front: the sparse branches below bypass
             # bm.from_columns and would otherwise store bad ids whose
@@ -420,14 +471,7 @@ class Fragment:
         if presorted:
             rows_s, cols_s = rows, cols
         else:
-            # numpy's stable sort is radix for <=16-bit ints (6x the
-            # int64 mergesort, measured r04) — row ids are usually
-            # small category ids, so cast when they fit
-            key = rows
-            if rows.size and 0 <= rows[0] and rows.max() < 32767:
-                key = rows.astype(np.int16)
-            order = np.argsort(key, kind="stable")
-            rows_s, cols_s = rows[order], cols[order]
+            rows_s, cols_s = self._by_row(rows, cols)
         starts = np.flatnonzero(
             np.r_[True, rows_s[1:] != rows_s[:-1]]) if rows_s.size \
             else np.array([], dtype=np.int64)
@@ -484,6 +528,9 @@ class Fragment:
         self.touch(row)
 
     def contains(self, row: int, col: int) -> bool:
+        codes = self._codes
+        if codes is not None:
+            return self.row_count(row) > 0 and int(codes[col]) == row
         words = self._rows.get(row)
         if words is None:
             arr = self._sparse.get(row)
@@ -525,11 +572,19 @@ class Fragment:
         """Mutex/bool bulk write: clear-then-set with last-write-wins
         per column in ONE native reverse pass (pt_mutex_fill) — no
         np.unique sort (the r04 mutex-import hotspot)."""
-        rows = np.asarray(rows, dtype=np.int64)
+        rows = np.asarray(rows)
+        if rows.dtype.kind not in "iu":
+            rows = rows.astype(np.int64)
         cols = np.asarray(cols, dtype=np.int64)
         assert rows.shape == cols.shape
         if cols.size == 0:
             return
+        if self._codes is None and not self._rows and not self._sparse \
+                and bool((cols[1:] > cols[:-1]).all()):
+            # nothing to clear and one write a column: a bulk load
+            self._load(rows, cols)
+            return
+        rows = rows.astype(np.int64, copy=False)
         if rows.min() >= 0 and rows.max() < 32767:
             # O(n) distinct + inverse via bincount — no sort
             cnt = np.bincount(rows)
@@ -561,6 +616,75 @@ class Fragment:
                                          dtype=np.int64).tolist()):
             self._row_mut(int(r), wlo, whi)[:] |= scratch[k]
             self.touch(int(r), wlo, whi)
+
+    def _load(self, rows: np.ndarray, cols: np.ndarray) -> None:
+        """Bulk load of an EMPTY fragment from (row, column) pairs
+        whose columns strictly increase, into the smaller of the two
+        forms: the codes (1 or 2 bytes a column of the shard) or the
+        rows (128 KB a row past SPARSE_MAX bits, 8 bytes a bit under
+        it).  Twenty single-valued fields over a full shard are 21 MB
+        so, not 68-120.
+        One invalidation for the whole load, not one per row: the
+        version moves before and after the contents land, as _row_mut
+        and touch() move it, and the delta floor rises to it, so a
+        reader that snapshot an earlier version rebuilds (there is no
+        row it could patch)."""
+        assert 0 <= cols[0] and cols[-1] < self.width, \
+            "column id out of range"
+        bump_mutation_epoch()
+        self.version += 1
+        counts = None
+        if 0 <= rows.min() and rows.max() < np.iinfo(np.uint16).max:
+            counts = np.bincount(rows)
+            dtype = np.uint8 if counts.size <= np.iinfo(np.uint8).max \
+                else np.uint16
+            by_rows = int(np.where(counts > SPARSE_MAX, self.width // 8,
+                                   8 * counts).sum())
+            if self.width * dtype().itemsize + counts.nbytes >= by_rows:
+                counts = None
+        if counts is not None:
+            codes = np.full(self.width, np.iinfo(dtype).max, dtype=dtype)
+            codes[cols] = rows
+            self._codes, self._code_counts = codes, counts
+            uniq = np.flatnonzero(counts).tolist()
+        else:
+            uniq = self._store_grouped(rows.astype(np.int64, copy=False),
+                                       cols)
+        self.dirty_rows.update(uniq)
+        if self._cache is not None:
+            self._cache_stale.update(dict.fromkeys(uniq))
+        self._planes_cache = None
+        bump_mutation_epoch()
+        self.version += 1
+        self._delta_log.clear()
+        self._delta_floor = self.version
+        if PARANOIA:
+            self.check()
+
+    def _store_grouped(self, rows: np.ndarray, cols: np.ndarray) -> list:
+        """Store (row, column) pairs of distinct columns into the
+        empty row stores and return the row ids: one stable sort
+        groups the columns by row, and each row is stored in the form
+        its cardinality asks for (sparse up to SPARSE_MAX bits: a
+        10,000-row field's million bits are 8 MB, not 10,000 dense
+        rows; the sparse rows are slices of one array that holds
+        their columns and no dense row's)."""
+        rows_s, cols_s = self._by_row(rows, cols)
+        starts = np.flatnonzero(np.r_[True, rows_s[1:] != rows_s[:-1]])
+        sizes = np.diff(np.append(starts, rows_s.size))
+        uniq = rows_s[starts].tolist()
+        thin = sizes <= SPARSE_MAX
+        thin_cols = cols_s[np.repeat(thin, sizes)]
+        at = 0
+        for r, lo, n, sparse in zip(uniq, starts.tolist(), sizes.tolist(),
+                                    thin.tolist()):
+            if sparse:
+                self._sparse[r] = thin_cols[at:at + n]
+                at += n
+            else:
+                self._rows[r] = bm.from_columns(cols_s[lo:lo + n],
+                                                self.width)
+        return uniq
 
     def import_values(self, cols, values, depth: int, clear: bool = False):
         """Bulk BSI write (fragment.importValue semantics): last-write-
@@ -611,6 +735,7 @@ class Fragment:
     def clear_columns(self, mask_words: np.ndarray) -> bool:
         """Clear every bit in the masked columns across ALL rows
         (Delete-records path).  Returns True if anything changed."""
+        self._decode()
         mask = np.asarray(mask_words, dtype=np.uint32)
         inv = ~mask
         nz = np.flatnonzero(mask)
@@ -641,9 +766,13 @@ class Fragment:
         cached = self._row_ids_cache
         if cached is not None and cached[0] == self.version:
             return list(cached[1])
-        ids = [r for r, w in self._rows.items() if w.any()]
-        ids += [r for r, a in self._sparse.items() if a.size]
-        ids.sort()
+        counts = self._code_counts
+        if counts is not None:
+            ids = np.flatnonzero(counts).tolist()
+        else:
+            ids = [r for r, w in self._rows.items() if w.any()]
+            ids += [r for r, a in self._sparse.items() if a.size]
+            ids.sort()
         self._row_ids_cache = (self.version, ids)
         return list(ids)
 
@@ -655,6 +784,12 @@ class Fragment:
         """Packed host words for a row (zeros if absent).  Sparse rows
         decode to a fresh dense array — the decode-at-upload boundary;
         treat the result as read-only."""
+        codes = self._codes
+        if codes is not None:
+            if self.row_count(row) == 0:
+                return bm.empty(self.width)
+            return np.packbits(codes == row,
+                               bitorder="little").view(np.uint32)
         w = self._rows.get(row)
         if w is not None:
             return w
@@ -664,6 +799,9 @@ class Fragment:
         return bm.empty(self.width)
 
     def row_count(self, row: int) -> int:
+        counts = self._code_counts
+        if counts is not None:
+            return int(counts[row]) if 0 <= row < counts.size else 0
         w = self._rows.get(row)
         if w is not None:
             return int(np.bitwise_count(w).sum())
@@ -706,6 +844,9 @@ class Fragment:
         return p
 
     def memory_bytes(self) -> int:
+        codes, counts = self._codes, self._code_counts
+        if codes is not None and counts is not None:
+            return codes.nbytes + counts.nbytes
         return (sum(w.nbytes for w in self._rows.values())
                 + sum(a.nbytes for a in self._sparse.values()))
 
@@ -730,7 +871,10 @@ class Fragment:
             if h is None:
                 h = acc[b] = hashlib.blake2b(digest_size=16)
             h.update(int(r).to_bytes(8, "little"))
-            arr = self._sparse.get(r)
+            if self._codes is not None:
+                arr = np.flatnonzero(self._codes == r)
+            else:
+                arr = self._sparse.get(r)
             if arr is None:
                 arr = bm.to_columns(self._rows[r]).astype(np.int64)
             h.update(np.ascontiguousarray(arr).tobytes())

@@ -178,6 +178,7 @@ class Index:
                     # rewrite every row under the new bitmap name
                     frag.dirty_rows.update(frag._rows)
                     frag.dirty_rows.update(frag._sparse)
+                    frag.dirty_rows.update(frag.row_ids)
                 if nvn != vn:
                     v.name = nvn
                     f.views[nvn] = f.views.pop(vn)

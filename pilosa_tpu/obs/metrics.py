@@ -373,7 +373,9 @@ GROUPBY_KERNEL = registry.counter(
     "GroupBy queries served by the fused Pallas kernel path")
 GROUPBY_ONEPASS = registry.counter(
     "pilosa_groupby_onepass_total",
-    "GroupBy queries served by the one-pass group-code histogram")
+    "GroupBy dispatches of the one-pass group-code histogram, by the "
+    "arm that served them (fused: the kernel / xla: the scatter-add "
+    "/ host: the native histogram off a device)")
 GROUPBY_FUSED = registry.counter(
     "pilosa_groupby_fused_total",
     "One-pass GroupBy dispatches served by the fused single-pass "
@@ -381,6 +383,15 @@ GROUPBY_FUSED = registry.counter(
     "the shapes take (packed: masks ANDed and popcounted on packed "
     "words / onehot: the one-hot MXU body, where the packed "
     "accumulators would not fit VMEM)")
+
+GROUPBY_PASSES = registry.counter(
+    "pilosa_groupby_fused_passes_total",
+    "Walks over the operands that the fused GroupBy dispatches made: "
+    "1 a dispatch, more where the packed body takes the live groups "
+    "in passes over slices of the widest field's rows")
+GROUPBY_REPLY_GROUPS = registry.counter(
+    "pilosa_groupby_reply_groups_total",
+    "Groups returned in GroupBy replies")
 
 # -- tile-stack maintenance (executor/stacked.py TileStackCache) --
 # Outcomes: hit (fresh entry), miss (any non-hit), patch (stale entry

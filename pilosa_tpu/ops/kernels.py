@@ -29,6 +29,8 @@ All kernels run in interpret mode off a TPU, so the CPU test mesh
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -358,10 +360,16 @@ def _valid_operand(valid, bw: int):
 # table of them; codes whose digit exceeds its field's row count are
 # never visited and stay 0.
 #
-# The accumulators grow with live groups x payload rows.  Where they
-# do not fit the VMEM a kernel may ask for (_PACKED_VMEM_BYTES), and
-# for Min/Max (which the packed body does not compute: ROADMAP A3),
-# the earlier body serves:
+# The accumulators grow with live groups x payload rows.  Where one
+# walk's do not fit the VMEM a kernel may ask for (_PACKED_VMEM_BYTES)
+# the groups are walked in passes over slices of the widest field's
+# rows (taxi-1b's 10 x 8 x 60 = 4,800 groups: 20 passes of 240): a
+# leading grid axis, the slice's rows read off the pass index, the
+# accumulators flushed into their pass's part of the output.  Every
+# pass streams the operands again; the popcount work is what it was.
+# Where not even one row of the widest field fits, and for Min/Max
+# (which the packed body does not compute: ROADMAP A3), the earlier
+# body serves:
 # every column unpacked to an int32 code, compared with a 128-lane
 # iota into a one-hot and contracted against the 0/1 payload rows on
 # the MXU (int8 @ int8 -> int32), Min/Max as masked reductions over
@@ -378,16 +386,13 @@ _VREG_WORDS = 8 * _LANES      # one (8, 128) vreg of packed words
 # kernel gets by default; the rest is Mosaic's own
 _PACKED_VMEM_BYTES = 13 << 20
 _PACKED_BLOCK_VREGS = 16      # vregs of each plane per grid step, at most
-
-
-def _live_codes(digits) -> list[int]:
-    """Dense codes of the groups the packed body visits, in the order
-    it visits them (first field fastest)."""
-    codes, shift = [0], 0
-    for bits, rows in digits:
-        codes = [c | (r << shift) for r in range(rows) for c in codes]
-        shift += bits
-    return codes
+# passes are cut so that a plane's block keeps this many vregs where
+# it can: narrower blocks pay the grid step's overhead more often than
+# wider slices save passes
+_PACKED_PASS_VREGS = 8
+# the code space the one-hot body takes (its lane axis, 128 codes a
+# block); stacked's _ONEPASS_KERNEL_MAX_CODES is this number
+ONEHOT_MAX_CODES = 4096
 
 
 def _payload_rows(depth: int, signed: bool) -> int:
@@ -417,34 +422,102 @@ def _packed_block_vregs(digits, depth: int, signed: bool) -> int:
     return nv
 
 
+@functools.lru_cache(maxsize=256)
+def _packed_passes(digits, depth: int, signed: bool):
+    """How the packed body walks the live groups of `digits`:
+    (block vregs, split field, rows a pass, passes).  One walk
+    (split field None) where the accumulators fit; else slices of the
+    widest field's rows — the most rows at which a block keeps
+    _PACKED_PASS_VREGS vregs, else the most that fit at all; block
+    vregs 0 where not even one row does."""
+    nv = _packed_block_vregs(digits, depth, signed)
+    if nv:
+        return nv, None, 0, 1
+    fi = max(range(len(digits)), key=lambda i: digits[i][1])
+    bits, rows = digits[fi]
+
+    def block(rp):
+        return _packed_block_vregs(
+            digits[:fi] + ((bits, rp),) + digits[fi + 1:], depth, signed)
+
+    for want in (_PACKED_PASS_VREGS, 1):
+        rp = 0
+        while rp + 1 < rows and block(rp + 1) >= want:
+            rp += 1
+        if rp:
+            return block(rp), fi, rp, -(-rows // rp)
+    return 0, None, 0, 1
+
+
+def _pass_codes(digits, fi, rp: int, n_pass: int) -> np.ndarray:
+    """Dense codes of the groups the packed body visits, (passes,
+    groups a pass) in the order it visits them: the unsplit fields
+    multiply out first (first field fastest), the split field's rows
+    last; -1 where a slot's row is past its field's last (the last
+    pass of a row count the slice does not divide)."""
+    shifts = np.cumsum([0] + [b for b, _ in digits])
+    codes = np.zeros(1, np.int64)
+    for i, (_bits, rows) in enumerate(digits):
+        if i != fi:
+            codes = (codes[None, :]
+                     | (np.arange(rows)[:, None] << shifts[i])).ravel()
+    if fi is None:
+        return codes[None, :]
+    r = np.arange(n_pass * rp).reshape(n_pass, rp, 1)
+    out = codes[None, None, :] | (r << shifts[fi])
+    return np.where(r < digits[fi][1], out, -1).reshape(n_pass, -1)
+
+
+def fused_plan(digits, depth: int, signed: bool = True,
+               minmax: bool = False) -> tuple:
+    """(body, walks over the operands) of groupby_fused for these
+    static arguments: "packed" for counts and Sum where its
+    accumulators fit the kernel's VMEM in one walk, or in passes
+    (_packed_passes) where the code space is past ONEHOT_MAX_CODES —
+    else "onehot" in one walk (Min/Max always).
+    `digits` is ((bits, rows), ...) per GroupBy field (the _code_space
+    layout with each field's row count).  Called at trace time by
+    groupby_fused and once a dispatch by stacked._onepass_plan, which
+    picks the arm and counts pilosa_groupby_fused_total{body=} and
+    the passes from it, so all agree."""
+    if not minmax:
+        nv, _fi, _rp, n_pass = _packed_passes(tuple(digits), depth, signed)
+        # in passes only past the code space the one-hot body takes:
+        # inside it the one-hot is the faster of the two where one
+        # walk does not fit (64 x 64 groups, a signed 16-bit Sum, 64
+        # shards: 0.193 s against 0.424 in 64 passes; chip, PR 36)
+        if nv and (n_pass == 1 or 1 << sum(
+                b for b, _ in digits) > ONEHOT_MAX_CODES):
+            return "packed", n_pass
+    return "onehot", 1
+
+
 def fused_body(digits, depth: int, signed: bool = True,
                minmax: bool = False) -> str:
-    """Which body of groupby_fused serves these static arguments:
-    "packed" for counts and Sum where its accumulators fit the
-    kernel's VMEM, else "onehot" (Min/Max always).  `digits` is
-    ((bits, rows), ...) per GroupBy field (the _code_space layout with
-    each field's row count).  Called at trace time by groupby_fused
-    and at dispatch by the pilosa_groupby_fused_total{body=} sites, so
-    both agree."""
-    if minmax:
-        return "onehot"
-    return ("packed" if _packed_block_vregs(digits, depth, signed)
-            else "onehot")
+    return fused_plan(digits, depth, signed, minmax)[0]
 
 
-def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int):
-    """Packed body factory (see the block comment above)."""
+def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
+                      fi=None, rp: int = 0):
+    """Packed body factory (see the block comment above).  With a
+    split field `fi` the grid leads with the pass axis and a pass
+    takes rows [pass * rp, (pass + 1) * rp) of that field."""
     cb = sum(bits for bits, _ in digits)
     # lax primitives, not jnp's jitted wrappers (each a nested pjit to
     # trace and lower), and `step` vregs of words to an operation:
     # what Mosaic has to lower stays in the hundreds of operations
     _and, _not = jax.lax.bitwise_and, jax.lax.bitwise_not
     step = next(u for u in (4, 2, 1) if nv % u == 0)
+    lead = 0 if fi is None else 1
+    starts = np.cumsum([0] + [bits for bits, _ in digits])
+    # the split field's rows multiply out last
+    order = [i for i in range(len(digits)) if i != fi] + (
+        [] if fi is None else [fi])
 
     def kernel(cp_ref, va_ref, *refs):
         pl_ref = refs[0] if depth else None
         out_ref, acc_ref, masks_ref, dense_ref = refs[1 if depth else 0:]
-        s, wi = pl.program_id(0), pl.program_id(1)
+        s, wi = pl.program_id(lead), pl.program_id(lead + 1)
 
         @pl.when((s == 0) & (wi == 0))
         def _init():
@@ -456,26 +529,43 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int):
         for i, (ref, p) in enumerate(srcs):
             dense_ref[i] = ref[0, p, :].reshape(8 * nv, _LANES)
 
+        # this pass's rows of the split field, read off the pass index:
+        # bit b of a row keeps plane b or flips it
+        flips = [[jnp.where(((pl.program_id(0) * rp + j) >> b) & 1 == 1,
+                            jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
+                  for b in range(digits[fi][0])]
+                 for j in range(rp)] if fi is not None else None
+
         # 1. the live groups' masks, one vreg of words at a time.  A
         # level of the tree is one array of masks, (n, 8, 128): the
         # operations to lower number the fields' rows, not the groups
         def mask_step(v, carry):
             rs = pl.ds(pl.multiple_of(v * 8, 8), 8)
-            masks, b0 = dense_ref[pl.ds(cb, 1), rs, :], 0
-            for bits, rows in digits:
-                if bits:
-                    one = [dense_ref[pl.ds(b0 + b, 1), rs, :]
-                           for b in range(bits)]
+            masks = dense_ref[pl.ds(cb, 1), rs, :]
+            for i in order:
+                bits, rows = digits[i]
+                if not bits:
+                    continue
+                one = [dense_ref[pl.ds(int(starts[i]) + b, 1), rs, :]
+                       for b in range(bits)]
+                level = []
+                if i == fi:
+                    for j in range(rp):
+                        lit = None
+                        for b in range(bits):
+                            t = jax.lax.bitwise_xor(
+                                one[b], jnp.full_like(one[b], flips[j][b]))
+                            lit = t if lit is None else _and(lit, t)
+                        level.append(_and(masks, lit))
+                else:
                     zero = [_not(x) for x in one]
-                    level = []
                     for r in range(rows):
                         lit = None
                         for b in range(bits):
                             t = one[b] if (r >> b) & 1 else zero[b]
                             lit = t if lit is None else _and(lit, t)
                         level.append(_and(masks, lit))
-                    masks = jax.lax.concatenate(level, 0)
-                b0 += bits
+                masks = jax.lax.concatenate(level, 0)
             masks_ref[:, rs, :] = masks
             return carry
 
@@ -513,10 +603,12 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int):
 
         jax.lax.fori_loop(0, masks_ref.shape[0], group, 0)
 
-        @pl.when((s == pl.num_programs(0) - 1)
-                 & (wi == pl.num_programs(1) - 1))
+        dst = out_ref if fi is None else out_ref.at[pl.program_id(0)]
+
+        @pl.when((s == pl.num_programs(lead) - 1)
+                 & (wi == pl.num_programs(lead + 1) - 1))
         def _flush():
-            pltpu.sync_copy(acc_ref, out_ref)
+            pltpu.sync_copy(acc_ref, dst)
     return kernel
 
 
@@ -525,33 +617,47 @@ def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
     """groupby_fused's packed body: returns the dense (K, G) table."""
     s_dim, _cb, w_dim = code_planes.shape
     k = _payload_rows(depth, signed)
-    live = _live_codes(digits)
-    nv = min(_packed_block_vregs(digits, depth, signed),
-             -(-w_dim // _VREG_WORDS))
+    nv, fi, rp, n_pass = _packed_passes(digits, depth, signed)
+    codes = _pass_codes(digits, fi, rp, n_pass)
+    nv = min(nv, -(-w_dim // _VREG_WORDS))
     bw = nv * _VREG_WORDS
     # zero padding is neutral: valid is ANDed into every mask
     arrays = [_pad_axis(x, 2, bw) for x in (
         code_planes, valid[:, None, :]) + ((planes,) if depth else ())]
     # the accumulators are scratch, so the VMEM asked for is what
     # _packed_block_vregs counted; they leave by one copy at the end
-    table = (len(live), k, 8, _LANES)
+    # (of each pass)
+    n_live = codes.shape[1]
+    table = (n_live, k, 8, _LANES)
+    grid = (s_dim, arrays[0].shape[2] // bw)
+    at = lambda s, w: (s, 0, w)
+    if fi is not None:
+        grid = (n_pass,) + grid
+        at = lambda p, s, w: (s, 0, w)
     out = pl.pallas_call(
-        _gb_packed_kernel(digits, depth, signed, k, nv),
-        grid=(s_dim, arrays[0].shape[2] // bw),
-        in_specs=[pl.BlockSpec((1, x.shape[1], bw),
-                               lambda s, w: (s, 0, w)) for x in arrays],
+        _gb_packed_kernel(digits, depth, signed, k, nv, fi, rp),
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, x.shape[1], bw), at) for x in arrays],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(table, jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            table if fi is None else (n_pass,) + table, jnp.int32),
         scratch_shapes=[
             pltpu.VMEM(table, jnp.int32),
-            pltpu.VMEM((len(live), 8 * nv, _LANES), jnp.uint32),
+            pltpu.VMEM((n_live, 8 * nv, _LANES), jnp.uint32),
             pltpu.VMEM((sum(x.shape[1] for x in arrays), 8 * nv,
                         _LANES), jnp.uint32)],
-        name="groupby_fused_sum",
+        # one factory, two schedules, a name each on the device plane:
+        # a reader of "groupby_fused_sum" times one walk over the
+        # operands, as it did before there were passes
+        name="groupby_fused_sum" if fi is None else "groupby_fused_passes",
         interpret=_interpret(),
     )(*arrays)
-    return jnp.zeros((k, n_codes), jnp.int32).at[:, np.asarray(live)].set(
-        jnp.sum(out, axis=(2, 3)).T)
+    sums = jnp.sum(out, axis=(-2, -1)).reshape(-1, k)
+    live = np.flatnonzero(codes.ravel() >= 0)
+    if live.size < codes.size:
+        sums = sums[live]
+    return jnp.zeros((k, n_codes), jnp.int32).at[
+        :, codes.ravel()[live]].set(sums.T)
 
 
 def _gb_fused_onehot_kernel(cb: int, depth: int, signed: bool, k: int,

@@ -371,11 +371,11 @@ class TestGroupbyOnepass:
         idx.field("g").import_bits([0] * len(extra), extra)
         idx.field("g").import_bits([1] * len(extra), extra)
         q = "GroupBy(Rows(g), Rows(d), aggregate=Sum(field=v))"
-        before = GROUPBY_ONEPASS.value()
+        before = GROUPBY_ONEPASS.total()
         monkeypatch.setenv("PILOSA_TPU_GROUPBY_ONEPASS", "1")
         got = Executor(h).execute("i", q)[0]
         monkeypatch.delenv("PILOSA_TPU_GROUPBY_ONEPASS")
-        assert GROUPBY_ONEPASS.value() == before  # fell back
+        assert GROUPBY_ONEPASS.total() == before  # fell back
         ex_loop = Executor(h)
         ex_loop.use_stacked = False
         assert self._as_t(got) == self._as_t(ex_loop.execute("i", q)[0])
@@ -389,13 +389,13 @@ class TestGroupbyOnepass:
         from pilosa_tpu.obs.metrics import GROUPBY_ONEPASS
         h = self._engine(rng, 1 << 12)
         q = "GroupBy(Rows(g), Rows(d), previous=[4, 1])"  # tail: 2
-        before = GROUPBY_ONEPASS.value()
+        before = GROUPBY_ONEPASS.total()
         got = Executor(h).execute("i", q)[0]
-        assert GROUPBY_ONEPASS.value() == before
+        assert GROUPBY_ONEPASS.total() == before
         monkeypatch.setenv("PILOSA_TPU_GROUPBY_ONEPASS", "1")
         forced = Executor(h).execute("i", q)[0]
         monkeypatch.delenv("PILOSA_TPU_GROUPBY_ONEPASS")
-        assert GROUPBY_ONEPASS.value() == before + 1
+        assert GROUPBY_ONEPASS.total() == before + 1
         assert self._as_t(got) == self._as_t(forced)
 
     def test_numpy_fallback_histogram(self, rng, monkeypatch):

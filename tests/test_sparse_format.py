@@ -343,3 +343,39 @@ def test_stats_encoding_breakdown(monkeypatch):
     Executor(h).execute("i", "Count(Row(f=0))")
     fs = stats.get().field_stats("i", "f")
     assert fs is not None and fs.get("encodings", {}).get("packed")
+
+
+@pytest.mark.parametrize("density", [0.0, 0.0005, 0.004, 0.015, 0.5])
+def test_positions_unpack_only_the_words_that_hold_a_bit(density):
+    """encode._positions reads the set bits' flat offsets off the
+    nonzero words alone; the same offsets, sorted, as unpacking every
+    word gives."""
+    r = np.random.default_rng(int(density * 1e4))
+    bits = r.random(4 * 4096 * 32) < density
+    flat = np.packbits(bits, bitorder="little").view(np.uint32)
+    got = encode._positions(flat)
+    assert got.dtype == np.uint32
+    assert np.array_equal(got, np.flatnonzero(bits))
+
+
+def test_the_first_packed_page_compiles_every_length_of_its_shape():
+    """EncodedPage.expand runs the expand program of every padded
+    length a page of its shape can have before the first real one: a
+    later page of another density finds its program there."""
+    from pilosa_tpu.ops import bitmap as bm
+    pl, w = 3, 64                      # a shape no other test expands
+    r = np.random.default_rng(5)
+    block = np.zeros((pl, w), dtype=np.uint32)
+    block[1, 7] = 1 << 9
+    page = encode.encode_block(block)
+    assert page is not None and page.kind == "packed"
+    before = bm._expand_coords_jit._cache_size()
+    assert np.array_equal(np.asarray(page.expand()), block)
+    lengths = [n for n in (8 << k for k in range(32)) if n <= pl * w]
+    assert bm._expand_coords_jit._cache_size() == before + len(lengths)
+    bits = r.random(pl * w * 32) < 0.004
+    block = np.packbits(bits, bitorder="little").view(np.uint32).reshape(pl, w)
+    page = encode.encode_block(block)
+    assert page.kind == "packed" and page.coords.size > 8
+    assert np.array_equal(np.asarray(page.expand()), block)
+    assert bm._expand_coords_jit._cache_size() == before + len(lengths)

@@ -62,6 +62,7 @@ def one_chip(topo):
 
 ABLE = ((3, 6), (1, 2), (3, 5))      # edu, gen, dom: (bits, rows)
 ABLE_REG = ABLE + ((2, 4),)           # the 240-group form
+TAXI_Q4 = ((4, 10), (3, 8), (6, 60))
 # scoped VMEM a v5e kernel may use unless it asks for more
 # (CompilerParams(vmem_limit_bytes=), which groupby_fused does not)
 V5E_SCOPED_VMEM = 16 << 20
@@ -119,8 +120,9 @@ CASES = {
     "groupby_sum": _groupby_sum,
     "bsi_value_hist": lambda: (kernels.bsi_value_hist,
                                [(S, 10, W), (S, W)]),
-    # past stacked._ONEPASS_KERNEL_MAX_CODES a TPU takes the XLA
-    # scatter (taxi-1b's Q4: three fields, 8,192 codes, count-only)
+    # past stacked._ONEPASS_KERNEL_MAX_CODES a histogram whose groups
+    # the packed body cannot walk takes the XLA scatter (8,192 codes
+    # with no digit layout: a value histogram)
     OVER_BOUNDS: lambda: (
         lambda cp, va: kernels.groupby_codes_xla(cp, va, None, 8192),
         [(S, 13, W), (S, W)]),
@@ -145,6 +147,11 @@ PACKED = {
     "able_count": dict(digits=ABLE, depth=0),
     "able_reg_sum": dict(digits=ABLE_REG, depth=7),
     "vhist_1024": dict(digits=((1, 2),) * 10, depth=0),
+    # taxi-1b's Q4 (passenger_count x pickup_year x dist_miles: 8,192
+    # codes, 4,800 groups): 20 passes of 240 groups, and with the
+    # 9-bit amount summed 60 passes of 80
+    "taxi_q4_count": dict(digits=TAXI_Q4, depth=0),
+    "taxi_q4_sum": dict(digits=TAXI_Q4, depth=9),
 }
 
 
@@ -159,7 +166,11 @@ def test_packed_body_fits_v5e_vmem(one_chip, name):
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = _kernel_calls(text)
-    assert len(calls) == 1 and "groupby_fused_" in calls[0]
+    _body, passes = kernels.fused_plan(PACKED[name]["digits"],
+                                       PACKED[name]["depth"], False)
+    assert (passes > 1) == name.startswith("taxi_q4")
+    assert len(calls) == 1 and ("groupby_fused_passes" if passes > 1
+                                else "groupby_fused_sum") in calls[0]
     asked = [int(n) for n in re.findall(
         r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
         r'"offset":"0","size":"(\d+)"', calls[0])]
@@ -167,12 +178,15 @@ def test_packed_body_fits_v5e_vmem(one_chip, name):
     assert asked[0] <= kernels._PACKED_VMEM_BYTES + (1 << 20), asked
 
 
-@pytest.mark.parametrize("arm", ["fused", "xla"])
-def test_onepass_shard_map_compiles_for_four_chips(topo, arm):
+@pytest.mark.parametrize("case", ["fused", "xla", "fused_passes"])
+def test_onepass_shard_map_compiles_for_four_chips(topo, case):
     """The mesh GroupBy wrapper (stacked._groupby_onepass_shard_map)
-    over the four described chips: per-device kernel — or, past the
-    kernel's bounds (8,192 codes), the XLA scatter — + psum."""
-    cb = {"fused": 6, "xla": 13}[arm]
+    over the four described chips: per-device kernel — past 4,096
+    codes in passes where the fields' digits are known (taxi-1b's Q4
+    with the amount summed), else the XLA scatter — + psum."""
+    cb = {"fused": 6, "xla": 13, "fused_passes": 13}[case]
+    arm = case.split("_")[0]
+    digits = TAXI_Q4 if case == "fused_passes" else None
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("rows", "shards"))
     flat = ("rows", "shards")
 
@@ -181,7 +195,7 @@ def test_onepass_shard_map_compiles_for_four_chips(topo, arm):
                                     sharding=NamedSharding(mesh, spec))
     fn = stacked._groupby_onepass_shard_map(
         mesh, arm, has_planes=True, has_filter=True, signed=True,
-        n_codes=1 << cb)
+        n_codes=1 << cb, digits=digits)
     compiled = fn.lower(sds((S, cb + 1, W), P(flat, None, None)),
                         sds((S, W), P(flat, None)),
                         sds((S, 10, W), P(flat, None, None))).compile()
@@ -246,6 +260,16 @@ def served(topo, one_chip):
         "age", FieldOptions(type=FieldType.INT, min=0, max=127)) \
         .import_values(cols, rng.integers(0, 128, size=len(cols)).tolist())
     idx.mark_columns_exist([int(c) for c in cols])
+    # taxi-1b's group fields, one filter field and the amount
+    taxi = h.create_index("taxi", track_existence=True)
+    for name, rows in (("passenger_count", 10), ("pickup_year", 8),
+                       ("dist_miles", 60), ("pickup_month", 12)):
+        taxi.create_field(name, FieldOptions(type=FieldType.MUTEX)) \
+            .import_bits(rng.integers(0, rows, size=len(cols)), cols)
+    taxi.create_field("total_amount_dollars", FieldOptions(
+        type=FieldType.INT, min=0, max=500)).import_values(
+            cols, rng.integers(0, 501, size=len(cols)).tolist())
+    taxi.mark_columns_exist([int(c) for c in cols])
     ex = Executor(h)
     ex.enable_serving(cache_bytes=0)
 
@@ -265,9 +289,9 @@ def served(topo, one_chip):
     for mod in (stacked, ragged, serving):
         mp.setattr(mod, "dispatch_ready", catch)
 
-    def programs(pql):
+    def programs(pql, index="able"):
         del seen[:]
-        ex.execute_serving("able", pql)
+        ex.execute_serving(index, pql)
         return [jax.jit(fn).lower(*args).compile().as_text()
                 for fn, args in seen]
     yield programs
@@ -296,3 +320,31 @@ def test_served_program_compiles_for_v5e(served, template):
             r"= u32\[3,9,32768\]\S* (?!bitcast\()", t) for t in texts)
         assert not any(re.search(
             r"= u32\[3,1,32768\]\{[^}]*T\(1,128\)", t) for t in texts)
+
+
+_T = "GroupBy(Rows(passenger_count), Rows(pickup_year)"
+SERVED_TAXI = {
+    "q2_amount": ("GroupBy(Rows(passenger_count), filter=Row(pickup_month=3),"
+                  " aggregate=Sum(field=total_amount_dollars))",
+                  "groupby_fused_sum"),
+    "q3_year": (_T + ", filter=Row(pickup_month=3))", "groupby_fused_sum"),
+    "q4_dist": (_T + ", Rows(dist_miles), filter=Row(pickup_month=3))",
+                "groupby_fused_passes"),
+    "q4_dist_range": (_T + ", Rows(dist_miles), filter=Intersect("
+                      "Row(pickup_month=3), Row(total_amount_dollars > 9)))",
+                      "groupby_fused_passes"),
+}
+
+
+@pytest.mark.parametrize("template", list(SERVED_TAXI))
+def test_served_taxi_groupby_compiles_for_v5e(served, template):
+    """taxi-1b's GroupBys as a lone caller sends them: one ragged
+    program each with exactly one kernel — Q4's 8,192 codes under the
+    passes' own name."""
+    import re
+    pql, kernel = SERVED_TAXI[template]
+    texts = served(pql, index="taxi")
+    assert texts and any("HloModule jit_plan_ragged" in t for t in texts)
+    calls = [ln for t in texts for ln in _kernel_calls(t)]
+    assert [re.search(r"%(groupby_fused_[a-z]+)", c).group(1)
+            for c in calls] == [kernel]
