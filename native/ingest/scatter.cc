@@ -10,6 +10,9 @@
 // fallback so the engine still runs without a toolchain.
 
 #include <cstdint>
+#if defined(__SSE2__) && !defined(PT_PORTABLE)
+#include <emmintrin.h>
+#endif
 
 extern "C" {
 
@@ -141,6 +144,260 @@ void pt_groupcode_hist(const uint32_t *__restrict code_planes,
                 tgt[p] += (magw[p] >> j) & 1;
         }
     }
+}
+
+// ---------------------------------------------------------------------
+// Fresh stack pages, straight from the fragments' storage
+// (memory/encode.py encode_lanes, through storage/native_ingest.py).
+// A page is n_lanes rows of `width` columns; lane k is read where its
+// fragment holds it: kinds[k] says how, addrs[k] where, sizes[k] how
+// many (the sorted columns of a PT_COLS lane).  One call makes one
+// page, and ctypes holds no interpreter lock while it runs.
+
+enum { PT_NONE = 0, PT_CODES8 = 1, PT_CODES16 = 2, PT_COLS = 3,
+       PT_WORDS = 4 };
+
+}  // extern "C"
+
+namespace {
+
+// Eq8 / Eq16: one word (32 columns) of `codes == row`, column j of
+// the 32 to bit j.  With SSE2 (every x86-64) a compare and a
+// movemask take 16 codes at once; elsewhere, or with -DPT_PORTABLE
+// (the tests build that too), eight or four codes a 64-bit word.
+#if defined(__SSE2__) && !defined(PT_PORTABLE)
+
+inline __m128i load128(const void *p) {
+    return _mm_loadu_si128((const __m128i *)p);
+}
+
+struct Eq8 {
+    __m128i pat;
+    explicit Eq8(int64_t row) : pat(_mm_set1_epi8((char)row)) {}
+    inline uint32_t word(const uint8_t *c) const {
+        uint32_t lo = _mm_movemask_epi8(_mm_cmpeq_epi8(load128(c), pat));
+        uint32_t hi = _mm_movemask_epi8(
+            _mm_cmpeq_epi8(load128(c + 16), pat));
+        return lo | hi << 16;
+    }
+};
+
+struct Eq16 {
+    __m128i pat;
+    explicit Eq16(int64_t row) : pat(_mm_set1_epi16((short)row)) {}
+    inline uint32_t half(const uint16_t *c) const {  // 16 codes
+        return _mm_movemask_epi8(_mm_packs_epi16(
+            _mm_cmpeq_epi16(load128(c), pat),
+            _mm_cmpeq_epi16(load128(c + 8), pat)));
+    }
+    inline uint32_t word(const uint16_t *c) const {
+        return half(c) | half(c + 16) << 16;
+    }
+};
+
+#else
+
+inline uint64_t load64(const void *p) {
+    uint64_t x;
+    __builtin_memcpy(&x, p, 8);
+    return x;
+}
+
+const uint64_t LO7_8 = 0x7F7F7F7F7F7F7F7FULL;
+const uint64_t LO15_16 = 0x7FFF7FFF7FFF7FFFULL;
+
+struct Eq8 {
+    uint64_t pat;
+    explicit Eq8(int64_t row)
+        : pat((uint64_t)(row & 0xFF) * 0x0101010101010101ULL) {}
+    // 8 codes to 8 bits: 0x80 in every byte that equals pat's (the
+    // add cannot carry out of a byte), gathered by one multiply
+    inline uint32_t byte(const uint8_t *c) const {
+        uint64_t y = load64(c) ^ pat;
+        uint64_t m = ~(((y & LO7_8) + LO7_8) | y | LO7_8);
+        return (uint32_t)(((m >> 7) * 0x0102040810204080ULL) >> 56);
+    }
+    inline uint32_t word(const uint8_t *c) const {
+        return byte(c) | byte(c + 8) << 8 | byte(c + 16) << 16
+             | byte(c + 24) << 24;
+    }
+};
+
+struct Eq16 {
+    uint64_t pat;
+    explicit Eq16(int64_t row)
+        : pat((uint64_t)(row & 0xFFFF) * 0x0001000100010001ULL) {}
+    inline uint32_t nibble(const uint16_t *c) const {  // 4 codes
+        uint64_t y = load64(c) ^ pat;
+        uint64_t m = ~(((y & LO15_16) + LO15_16) | y | LO15_16);
+        return (uint32_t)(((m >> 15) * 0x0001000200040008ULL) >> 48)
+             & 15u;
+    }
+    inline uint32_t word(const uint16_t *c) const {
+        uint32_t w = 0;
+        for (int k = 0; k < 8; k++) w |= nibble(c + 4 * k) << (4 * k);
+        return w;
+    }
+};
+
+#endif
+
+// Where a page's all-ones words lie, as memory/encode.py's run
+// analysis counts them: how many, and in how many runs over the flat
+// word space of the page.
+struct Runs {
+    int64_t n_full = 0, n_runs = 0, last = -2;
+    inline void full(int64_t flat) {
+        n_full++;
+        if (flat != last + 1) n_runs++;
+        last = flat;
+    }
+};
+
+// The coordinates base + column of `codes == row` over one lane,
+// appended at coords[n]; the new n, or -1 where they pass cap.
+// `fill` is what the unwritten part of coords holds.
+template <class Eq, class T>
+int64_t lane_coords(const T *c, int64_t width, const Eq &eq,
+                    uint32_t base, uint32_t *coords, int64_t n,
+                    int64_t cap, uint32_t fill) {
+    int64_t i = 0;
+    // a row worth packing has a bit in one word of five, at random,
+    // and a second in one of fifty: the first is stored without a
+    // branch (a word with none stores over the next free place, which
+    // is put right below), the others in a loop rarely entered
+    for (; i < width && n + 32 <= cap; i += 32) {
+        uint32_t m = eq.word(c + i);
+        coords[n] = base + (uint32_t)(i + __builtin_ctz(m | 0x80000000u));
+        n += m != 0;
+        for (m &= m - 1; m; m &= m - 1)
+            coords[n++] = base + (uint32_t)(i + __builtin_ctz(m));
+    }
+    if (n < cap) coords[n] = fill;
+    for (; i < width; i += 32) {      // the last 32 places: counted
+        uint32_t m = eq.word(c + i);
+        if (n + __builtin_popcount(m) > cap) return -1;
+        for (; m; m &= m - 1)
+            coords[n++] = base + (uint32_t)(i + __builtin_ctz(m));
+    }
+    return n;
+}
+
+// The packed words of `codes == row` over one lane, `flat` the first
+// word's place in the page.
+template <class Eq, class T>
+void lane_words(const T *c, int64_t w, const Eq &eq, uint32_t *out,
+                int64_t flat, Runs &runs) {
+    for (int64_t i = 0; i < w; i++) {
+        uint32_t v = eq.word(c + 32 * i);
+        out[i] = v;
+        if (v == 0xFFFFFFFFu) runs.full(flat + i);
+    }
+}
+
+}  // namespace
+
+extern "C" {
+
+// (a) The sorted coordinates `lane * width + column` of the row's bits
+// into coords (cap entries, sentinels already there), each lane's
+// count into lane_counts (n_lanes entries).  Returns how many were
+// written, or -1 where they pass cap or a lane is held as words (the
+// caller then fills the dense block): nothing past cap is written.
+// A row no code of the lane's width can hold has no bit there.
+int64_t pt_page_coords(const uint64_t *addrs, const int32_t *kinds,
+                       const int64_t *sizes, int64_t n_lanes,
+                       int64_t width, int64_t row,
+                       uint32_t *coords, int64_t cap,
+                       int64_t *lane_counts) {
+    int64_t n = 0;
+    const uint32_t fill = cap > 0 ? coords[cap - 1] : 0;
+    for (int64_t k = 0; k < n_lanes; k++) {
+        const int64_t before = n;
+        const uint32_t base = (uint32_t)(k * width);
+        const void *p = (const void *)(uintptr_t)addrs[k];
+        switch (kinds[k]) {
+        case PT_NONE:
+            break;
+        case PT_CODES8:
+            if (row >= 0 && row < 0xFF)
+                n = lane_coords((const uint8_t *)p, width, Eq8(row),
+                                base, coords, n, cap, fill);
+            break;
+        case PT_CODES16:
+            if (row >= 0 && row < 0xFFFF)
+                n = lane_coords((const uint16_t *)p, width, Eq16(row),
+                                base, coords, n, cap, fill);
+            break;
+        case PT_COLS: {
+            const int64_t *cols = (const int64_t *)p;
+            if (n + sizes[k] > cap) return -1;
+            for (int64_t j = 0; j < sizes[k]; j++)
+                coords[n++] = base + (uint32_t)cols[j];
+            break;
+        }
+        default:
+            return -1;
+        }
+        if (n < 0) return -1;
+        lane_counts[k] = n - before;
+    }
+    return n;
+}
+
+// (b) The row's packed words into block: page_lanes lanes of width/32
+// words, every one written (zeros past n_lanes and for a lane nobody
+// holds).  stats[0], stats[1]: the all-ones words and their runs
+// among the lanes made here from codes or columns; a PT_WORDS lane is
+// one copy and is not looked at (stats[2] counts such lanes, and the
+// caller analyses the block as it always did).
+void pt_page_fill(const uint64_t *addrs, const int32_t *kinds,
+                  const int64_t *sizes, int64_t n_lanes,
+                  int64_t page_lanes, int64_t width, int64_t row,
+                  uint32_t *block, int64_t *stats) {
+    const int64_t w = width >> 5;
+    Runs runs;
+    int64_t copied = 0;
+    for (int64_t k = 0; k < page_lanes; k++) {
+        uint32_t *out = block + k * w;
+        int kind = k < n_lanes ? kinds[k] : PT_NONE;
+        const void *p = k < n_lanes
+            ? (const void *)(uintptr_t)addrs[k] : nullptr;
+        if ((kind == PT_CODES8 && (row < 0 || row >= 0xFF)) ||
+            (kind == PT_CODES16 && (row < 0 || row >= 0xFFFF)))
+            kind = PT_NONE;
+        switch (kind) {
+        case PT_CODES8:
+            lane_words((const uint8_t *)p, w, Eq8(row), out, k * w,
+                       runs);
+            break;
+        case PT_CODES16:
+            lane_words((const uint16_t *)p, w, Eq16(row), out, k * w,
+                       runs);
+            break;
+        case PT_COLS: {
+            const int64_t *cols = (const int64_t *)p;
+            __builtin_memset(out, 0, w * 4);
+            for (int64_t j = 0; j < sizes[k]; j++) {
+                int64_t col = cols[j];
+                uint32_t v = out[col >> 5] |= (uint32_t)1 << (col & 31);
+                // sorted distinct columns: a word is full when its
+                // last column lands, and the words fill in order
+                if (v == 0xFFFFFFFFu) runs.full(k * w + (col >> 5));
+            }
+            break;
+        }
+        case PT_WORDS:
+            __builtin_memcpy(out, p, w * 4);
+            copied++;
+            break;
+        default:
+            __builtin_memset(out, 0, w * 4);
+        }
+    }
+    stats[0] = runs.n_full;
+    stats[1] = runs.n_runs;
+    stats[2] = copied;
 }
 
 }  // extern "C"

@@ -209,6 +209,12 @@ def _assemble_permuted(pages, lane_page, lane_slot, page_lanes,
     return cat[jnp.asarray(inv)].reshape(shape)
 
 
+def _note_rebuild_time(seconds: float) -> None:
+    """One stack_rebuild / stack_page_rebuild, on the wall clock."""
+    metrics.STACK_REBUILD_SECONDS.inc(seconds)
+    metrics.STACK_REBUILD_TIMED.inc()
+
+
 def _same_lane_device(a, b) -> bool:
     """Structural placement compare for PagedStack reuse (None =
     single-device layout)."""
@@ -446,8 +452,10 @@ class TileStackCache:
                 metrics.STACK_CACHE.inc(outcome="patch")
                 metrics.STACK_MAINT_BYTES.inc(pbytes, kind="patched")
         if arr is None:
+            t0 = time.perf_counter()
             with flight.annotate("stack_rebuild"):
                 arr = build()
+            _note_rebuild_time(time.perf_counter() - t0)
             nb = int(np.prod(arr.shape)) * arr.dtype.itemsize
             moved = nb
             with self._lock:
@@ -525,6 +533,7 @@ class TileStackCache:
             pl * w * 4)
         # named for the profiler while it runs; the stage that times
         # it is the one around TileStackCache.get
+        t0 = time.perf_counter()
         with flight.annotate(
                 "stack_rebuild" if ps is None or dirty is None
                 else "stack_patch" if old_versions != versions
@@ -535,18 +544,15 @@ class TileStackCache:
                 ps = PagedStack(shape, pl, weight=recipe.weight,
                                 lane_device=recipe.lane_device,
                                 shard_axis=recipe.shard_axis)
-                host = np.asarray(recipe.build_host(),
-                                  dtype=np.uint32).reshape(-1, w)
+                # the whole stack as one host array, for a recipe
+                # with no page source
+                host = None if recipe.build_page is not None else (
+                    np.asarray(recipe.build_host(),
+                               dtype=np.uint32).reshape(-1, w))
                 retained = 0
                 for pi in range(ps.n_pages):
-                    ids = ps.page_lane_ids(pi)
-                    block = host[ids]
-                    if block.shape[0] < pl:
-                        block = np.concatenate(
-                            [block, np.zeros((pl - block.shape[0], w),
-                                             np.uint32)])
-                    local[pi] = self._commit_page(
-                        block, key, device=self._page_jdev(ps, pi))
+                    local[pi] = self._fresh_page(key, ps, pi, recipe,
+                                                 host)
                     # true encoded page bytes — both for the admission
                     # cap and the maintenance-traffic attribution (a
                     # packed page uploads its coordinates, not the dense
@@ -576,9 +582,7 @@ class TileStackCache:
                 retained = ps.resident_bytes()
                 for pi in range(ps.n_pages):
                     if pi not in local:
-                        block = ps.build_page_host(pi, recipe.lane_words)
-                        local[pi] = self._commit_page(
-                            block, key, device=self._page_jdev(ps, pi))
+                        local[pi] = self._fresh_page(key, ps, pi, recipe)
                         nb_pi = encode.page_nbytes(local[pi])
                         if (retained + nb_pi <= resident_cap
                                 and self._page_install(key, ps, pi,
@@ -616,6 +620,8 @@ class TileStackCache:
                     if rebuilt_b:
                         metrics.STACK_MAINT_BYTES.inc(rebuilt_b,
                                                       kind="rebuilt")
+        if outcome != "patch":
+            _note_rebuild_time(time.perf_counter() - t0)
         repl = None
         with self._lock:
             old = self._entries.get(key)
@@ -708,6 +714,38 @@ class TileStackCache:
             return key[1], key[2]
         return None
 
+    def _density_hint(self, key, width_words: int):
+        """The stats catalog's density of the key's field (None where
+        the key names none or the catalog cannot say)."""
+        ident = self._stats_ident(key)
+        if ident is None:
+            return None
+        return stats.field_density(ident[0], ident[1], width_words * 32)
+
+    def _fresh_page(self, key, ps: PagedStack, pi: int,
+                    recipe: StackRecipe, host=None):
+        """One fresh page on its device: made by the recipe's page
+        source where it has one, else cut out of `host` (the whole
+        stack, a rebuild's) or read lane by lane (a page a fresh entry
+        lost).  The one way a page that is not a patch is made."""
+        device = self._page_jdev(ps, pi)
+        ids = ps.page_lane_ids(pi)
+        pl, w = ps.page_lanes, ps.width_words
+        if recipe.build_page is not None:
+            metrics.STACK_FRESH_PAGES.inc(source="direct")
+            page = recipe.build_page(ids, pl, self._density_hint(key, w))
+            return self._commit_made(page, key, device=device)
+        metrics.STACK_FRESH_PAGES.inc(source="host")
+        if host is None:
+            block = ps.build_page_host(pi, recipe.lane_words)
+        else:
+            block = host[ids]
+            if block.shape[0] < pl:
+                block = np.concatenate(
+                    [block, np.zeros((pl - block.shape[0], w),
+                                     np.uint32)])
+        return self._commit_page(block, key, device=device)
+
     def _commit_page(self, block: np.ndarray, key, prev=None,
                      reason: str = "build", device=None):
         """Encode-or-dense commit of one host page block
@@ -718,30 +756,34 @@ class TileStackCache:
         prev_kind = encode.page_kind(prev) if prev is not None else None
         enc = None
         if encode.enabled():
-            hint = None
+            enc = encode.encode_block(
+                block, prev_kind=prev_kind,
+                density_hint=self._density_hint(key, block.shape[1]))
+        return self._commit_made(block if enc is None else enc, key,
+                                 prev_kind=prev_kind, reason=reason,
+                                 device=device)
+
+    def _commit_made(self, page, key, prev_kind: str | None = None,
+                     reason: str = "build", device=None):
+        """A page whose form is decided — an EncodedPage or the dense
+        host block — counted by its encoding and put on its device."""
+        enc = page if encode.is_encoded(page) else None
+        if encode.enabled():
             ident = self._stats_ident(key)
-            if ident is not None:
-                hint = stats.field_density(
-                    ident[0], ident[1], block.shape[1] * 32)
-            enc = encode.encode_block(block, prev_kind=prev_kind,
-                                      density_hint=hint)
             if enc is None:
                 if prev_kind not in (None, "dense"):
                     metrics.PAGE_ENCODE.inc(**{
                         "from": prev_kind, "to": "dense",
                         "reason": reason})
-                if ident is not None:
-                    stats.note_page_encoding(ident[0], ident[1],
-                                             "dense")
             else:
                 metrics.PAGE_ENCODE.inc(**{
                     "from": prev_kind or "none", "to": enc.kind,
                     "reason": reason})
-                if ident is not None:
-                    stats.note_page_encoding(ident[0], ident[1],
-                                             enc.kind)
+            if ident is not None:
+                stats.note_page_encoding(ident[0], ident[1],
+                                         encode.page_kind(page))
         if enc is None:
-            return self._commit_block(block, device=device)
+            return self._commit_block(page, device=device)
         return pressure.guarded(lambda: enc.to_device(device),
                                 host_fallback=lambda: enc)
 
@@ -2609,7 +2651,8 @@ class StackedEngine:
 
     def _cached_stack(self, key, versions, build, *, frags, lanes,
                       logical_lead, lane_words, width_words,
-                      build_host=None, versions_fn=None,
+                      build_host=None, build_page=None,
+                      versions_fn=None,
                       weight: float = 1.0, pageable: bool = True,
                       alive_fn=None, lane_device=None,
                       shard_axis: int | None = None):
@@ -2648,6 +2691,7 @@ class StackedEngine:
                 width_words=int(width_words),
                 lane_words=lane_words,
                 build_host=build_host,
+                build_page=build_page,
                 versions_fn=versions_fn,
                 deltas_fn=deltas_fn,
                 weight=weight,
@@ -2687,6 +2731,15 @@ class StackedEngine:
                     out |= fr.row_words(row_id)
             return out
 
+        def build_page(ids, page_lanes, density_hint):
+            # a lane is a shard: each page straight from what its
+            # fragments hold, in its final form
+            frags = per_view[0]
+            return encode.encode_lanes(
+                [None if frags[si] is None
+                 else frags[si].row_source(row_id) for si in ids],
+                row_id, page_lanes, width // 32, density_hint)
+
         frags_flat = [fr for frags in per_view for fr in frags]
         lanes = [{row_id: (si,)} for _ in per_view
                  for si in range(len(shards))]
@@ -2695,6 +2748,9 @@ class StackedEngine:
             frags=frags_flat, lanes=lanes,
             logical_lead=(len(shards),), lane_words=lane_words,
             width_words=width // 32, build_host=build_host,
+            # several views (a time quantum's cover) are ORed lane by
+            # lane: that stays build_host's
+            build_page=build_page if len(per_view) == 1 else None,
             versions_fn=versions_fn,
             alive_fn=lambda: idx.fields.get(field.name) is field,
             lane_device=self._lane_devices(idx, skey,

@@ -34,9 +34,14 @@ must not re-encode every patch).  The stats catalog's per-
 (index, field) density (obs/stats.py) short-circuits the analysis for
 clearly-dense fields; pages of unknown fields always analyze.
 
+A FRESH page need not be a host block first: where every lane's
+fragment says how it holds the row and how many bits it has
+(``Fragment.row_source``), :func:`encode_lanes` decides the form from
+the counts and has one native call write the coordinates, or the
+words, straight from that storage.
+
 Kill switch: ``PILOSA_TPU_SPARSE_FORMAT=0`` (config twin
-``[stacked] sparse-format``) restores the all-dense format — the
-bench A/B arm.
+``[stacked] sparse-format``) restores the all-dense format.
 """
 
 from __future__ import annotations
@@ -76,6 +81,32 @@ def configure(dense_frac: float | None = None):
 def _pow2(n: int) -> int:
     n = max(int(n), _PAD_FLOOR)
     return 1 << (n - 1).bit_length()
+
+
+def _packed_bytes(nbits: int) -> int:
+    return 4 * _pow2(nbits)
+
+
+def _run_bytes(n_runs: int, n_resid: int) -> int:
+    return 8 * _pow2(n_runs) + 4 * _pow2(n_resid)
+
+
+def _pays(enc_bytes: int, dense_bytes: int,
+          prev_kind: str | None = None) -> bool:
+    """The entry rule: an encoding of `enc_bytes` may stand for a page
+    of `dense_bytes` (a page that is sparse already stays so up to the
+    looser leave threshold)."""
+    limit = _DENSE_FRAC if prev_kind in (None, "dense") else min(
+        _DENSE_FRAC * _LEAVE_RATIO, 0.95)
+    return enc_bytes <= limit * dense_bytes
+
+
+def _packed_page(page_lanes: int, width_words: int, coords: np.ndarray,
+                 n_valid: int, lane_counts: np.ndarray) -> "EncodedPage":
+    enc = EncodedPage("packed", page_lanes, width_words, coords, None,
+                      None, lane_counts, n_valid, 0)
+    enc.host_positions = coords[:n_valid].astype(np.int64)
+    return enc
 
 
 def _positions(flat_words: np.ndarray) -> np.ndarray:
@@ -255,22 +286,17 @@ def encode_block(block: np.ndarray, prev_kind: str | None = None,
     edges = np.flatnonzero(np.diff(
         np.concatenate(([False], full, [False])).astype(np.int8)))
     n_runs = edges.size // 2
-    packed_b = 4 * _pow2(nbits)
-    run_b = 8 * _pow2(n_runs) + 4 * _pow2(n_resid)
+    packed_b = _packed_bytes(nbits)
+    run_b = _run_bytes(n_runs, n_resid)
     kind, best_b = (("packed", packed_b) if packed_b <= run_b
                     else ("run", run_b))
-    limit = _DENSE_FRAC if prev_kind in (None, "dense") else min(
-        _DENSE_FRAC * _LEAVE_RATIO, 0.95)
-    if best_b > limit * dense_b:
+    if not _pays(best_b, dense_b, prev_kind):
         return None
     if kind == "packed":
         pos = _positions(flat)
         coords = np.full(_pow2(pos.size), total_bits, dtype=np.uint32)
         coords[:pos.size] = pos
-        enc = EncodedPage("packed", pl, w, coords, None, None,
-                          lane_counts, pos.size, 0)
-        enc.host_positions = pos.astype(np.int64)
-        return enc
+        return _packed_page(pl, w, coords, pos.size, lane_counts)
     starts, ends = edges[0::2], edges[1::2]
     run_starts = np.full(_pow2(starts.size), pl * w, dtype=np.int32)
     run_lens = np.zeros(_pow2(starts.size), dtype=np.int32)
@@ -283,3 +309,47 @@ def encode_block(block: np.ndarray, prev_kind: str | None = None,
     coords[:pos.size] = pos
     return EncodedPage("run", pl, w, coords, run_starts, run_lens,
                        lane_counts, pos.size, int(starts.size))
+
+
+def encode_lanes(lanes: list, row: int, page_lanes: int,
+                 width_words: int, density_hint: float | None = None):
+    """One FRESH page of a row's stack, made from its lanes' storage
+    and not from a host block: ``lanes[k]`` is what
+    ``Fragment.row_source(row)`` gives for the page's k-th lane (None:
+    nobody holds the row there).  Returns the page in its final form,
+    an :class:`EncodedPage` or the dense ``(page_lanes, W)`` block.
+
+    Where every lane is counted (codes or columns) the counts decide
+    before a byte is read: under :func:`encode_block`'s packing limit
+    the page is packed, even where runs would have been smaller, and
+    one call writes its coordinates; else one call fills the block and
+    says where its all-ones words lie, which is the rest of that
+    analysis.  A lane held as words is one copy, and such a block goes
+    through :func:`encode_block` as a host block always did
+    (``density_hint`` skips that scan for a clearly dense field)."""
+    from pilosa_tpu.storage import native_ingest as ni
+    w = int(width_words)
+    dense_b = page_lanes * w * 4
+    total_bits = page_lanes * w * 32
+    can_encode = enabled() and total_bits < 1 << 32
+    counted = all(ln is None or ln[2] >= 0 for ln in lanes)
+    nbits = sum(ln[2] for ln in lanes if ln is not None) if counted else 0
+    if counted and can_encode and _pays(_packed_bytes(nbits), dense_b):
+        coords = np.full(_pow2(nbits), total_bits, dtype=np.uint32)
+        lane_counts = np.zeros(page_lanes, dtype=np.int64)
+        n = ni.page_coords(lanes, row, w * 32, coords, lane_counts)
+        if n >= 0:
+            return _packed_page(page_lanes, w, coords, n, lane_counts)
+        # more bits than were counted: a write is racing this build
+        # (the cache's versions discard it); the block below is safe
+    block = np.empty((page_lanes, w), dtype=np.uint32)
+    n_full, n_runs, copied = ni.page_fill(lanes, row, w * 32, block)
+    if not can_encode:
+        return block
+    if copied:
+        enc = encode_block(block, density_hint=density_hint)
+    elif _pays(_run_bytes(n_runs, nbits - 32 * n_full), dense_b):
+        enc = encode_block(block)
+    else:
+        enc = None
+    return block if enc is None else enc

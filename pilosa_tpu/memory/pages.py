@@ -52,7 +52,16 @@ class StackRecipe:
       (re-read from live fragments — page rebuilds and patches share
       one source of truth with the whole-stack patcher)
     - ``build_host()``: the full host (lead..., W) array (bulk cold
-      builds beat L lane_words calls)
+      builds beat L lane_words calls).  Called for a rebuild only
+      where the recipe has no page source
+    - ``build_page(lane_ids, page_lanes, density_hint)``: the page
+      source — one fresh page in its final form, a dense
+      (page_lanes, W) host block or an ``EncodedPage`` with its
+      ``lane_counts``, made straight from the fragments' storage
+      (memory/encode.py encode_lanes).  Optional: ``row_stack`` over
+      one view has one; with it a rebuild never calls ``build_host``
+      and a lost page of a fresh entry never calls ``lane_words``
+      (patches still do)
     - ``versions_fn()``: the entry's CURRENT fragment stamp tuple
       (prefetch warms against live versions, never a stale snapshot)
     - ``deltas_fn(old_versions)``: dirty lane map (lane -> [(lo, hi)]
@@ -78,6 +87,7 @@ class StackRecipe:
     lane_words: object
     build_host: object
     versions_fn: object
+    build_page: object = None
     deltas_fn: object = None
     weight: float = 1.0
     alive_fn: object = None

@@ -798,6 +798,30 @@ class Fragment:
             return bm.from_columns(arr, self.width)
         return bm.empty(self.width)
 
+    def row_source(self, row: int):
+        """How the row is held, for a reader that builds from the
+        storage itself (memory/encode.py encode_lanes): None where it
+        has no bit, else ``(kind, array, bits)`` — ``"codes"`` with
+        the fragment's codes and the row's count, ``"cols"`` with its
+        sorted columns, ``"words"`` with its packed words and -1 (not
+        counted).  The array is the live one: read-only, and held by
+        the caller while it reads (a writer or _decode replaces the
+        stores' arrays, it does not free what a reader holds)."""
+        # counts before codes: _decode clears them in the other order,
+        # so both set means the codes still hold the fragment
+        counts, codes = self._code_counts, self._codes
+        if counts is not None and codes is not None:
+            n = int(counts[row]) if 0 <= row < counts.size else 0
+            return ("codes", codes, n) if n else None
+        w = self._rows.get(row)
+        if w is not None:
+            return ("words", w, -1)
+        arr = self._sparse.get(row)
+        if arr is not None and arr.size:
+            return ("cols", np.ascontiguousarray(arr, dtype=np.int64),
+                    int(arr.size))
+        return None
+
     def row_count(self, row: int) -> int:
         counts = self._code_counts
         if counts is not None:

@@ -410,6 +410,20 @@ STACK_MAINT_BYTES = registry.counter(
     "pilosa_stack_maintenance_bytes_total",
     "Device stack maintenance traffic by kind (patched/rebuilt)")
 
+# how a fresh page (a rebuild's, or a missing page of a fresh entry)
+# was made: by its recipe's page source, straight from the fragments'
+# storage ("direct"), or out of a whole-stack host array / lane by
+# lane ("host"); and what that work cost on the wall clock
+STACK_FRESH_PAGES = registry.counter(
+    "pilosa_stack_fresh_pages_total",
+    "Fresh paged-stack pages by how they were made (direct/host)")
+STACK_REBUILD_SECONDS = registry.counter(
+    "pilosa_stack_rebuild_seconds_total",
+    "Wall seconds of stack_rebuild and stack_page_rebuild work")
+STACK_REBUILD_TIMED = registry.counter(
+    "pilosa_stack_rebuild_timed_total",
+    "Stack accesses timed into pilosa_stack_rebuild_seconds_total")
+
 # -- HBM residency (memory/: budget ledger, paged stacks, OOM backstop) --
 MEM_BUDGET = registry.gauge(
     "pilosa_memory_budget_bytes",

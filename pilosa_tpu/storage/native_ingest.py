@@ -28,6 +28,29 @@ _lib_failed = False
 
 _I64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 _U32 = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
+_U64 = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
+_I32 = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+
+
+def _declare(lib):
+    """The entry points' signatures on a loaded libingest_tpu."""
+    lib.pt_or_bits.argtypes = [_U32, _I64, ct.c_int64]
+    lib.pt_bsi_fill_t.argtypes = [_U32, ct.c_int64, _I64, _I64,
+                                  ct.c_int64]
+    lib.pt_mutex_fill.argtypes = [_U32, _U32, ct.c_int64, _I64, _I64,
+                                  ct.c_int64]
+    lib.pt_groupcode_hist.argtypes = [
+        _U32, ct.c_int64, _U32, ct.c_void_p, ct.c_int64,
+        ct.c_int64, ct.c_int64, ct.c_int64,
+        _I64, _I64, _I64, _I64]
+    lib.pt_page_coords.argtypes = [
+        _U64, _I32, _I64, ct.c_int64, ct.c_int64, ct.c_int64,
+        _U32, ct.c_int64, _I64]
+    lib.pt_page_coords.restype = ct.c_int64
+    lib.pt_page_fill.argtypes = [
+        _U64, _I32, _I64, ct.c_int64, ct.c_int64, ct.c_int64,
+        ct.c_int64, _U32, _I64]
+    return lib
 
 
 def _load():
@@ -43,17 +66,7 @@ def _load():
                 subprocess.run(
                     ["sh", os.path.join(_NATIVE, "build.sh")],
                     check=True, capture_output=True)
-            lib = ct.CDLL(_SO)
-            lib.pt_or_bits.argtypes = [_U32, _I64, ct.c_int64]
-            lib.pt_bsi_fill_t.argtypes = [_U32, ct.c_int64, _I64,
-                                          _I64, ct.c_int64]
-            lib.pt_mutex_fill.argtypes = [_U32, _U32, ct.c_int64,
-                                          _I64, _I64, ct.c_int64]
-            lib.pt_groupcode_hist.argtypes = [
-                _U32, ct.c_int64, _U32, ct.c_void_p, ct.c_int64,
-                ct.c_int64, ct.c_int64, ct.c_int64,
-                _I64, _I64, _I64, _I64]
-            _lib = lib
+            _lib = _declare(ct.CDLL(_SO))
         except Exception:
             _lib_failed = True  # no toolchain: numpy fallbacks
     return _lib
@@ -200,3 +213,109 @@ def mutex_fill(written: np.ndarray, scratch: np.ndarray,
     or_bits(written, cols)
     for r in np.unique(rowidx):
         or_bits(scratch[int(r)], cols[rowidx == r])
+
+
+# -- fresh stack pages from the fragments' storage ----------------------
+# A lane is None (nobody holds the row there) or (kind, array, bits) as
+# Fragment.row_source gives it: "codes" (uint8 / uint16, one a column),
+# "cols" (sorted int64 columns) or "words" (packed uint32, bits -1).
+
+_LANE_KINDS = {("codes", 1): 1, ("codes", 2): 2, ("cols", 8): 3,
+               ("words", 4): 4}
+
+
+def _lane_args(lanes, width: int):
+    """(addrs, kinds, sizes) of a page's lanes for the native calls.
+    The arrays stay referenced by `lanes` while the call reads them."""
+    n = len(lanes)
+    addrs = np.zeros(n, np.uint64)
+    kinds = np.zeros(n, np.int32)
+    sizes = np.zeros(n, np.int64)
+    for k, lane in enumerate(lanes):
+        if lane is None:
+            continue
+        kind, arr, _bits = lane
+        want = (width if kind == "codes" else
+                width // 32 if kind == "words" else arr.size)
+        if not arr.flags.c_contiguous or arr.size != want:
+            raise ValueError("lane %d: %s array of %d does not hold a "
+                             "row of %d columns" % (k, kind, arr.size,
+                                                    width))
+        kinds[k] = _LANE_KINDS[kind, arr.dtype.itemsize]
+        addrs[k] = arr.__array_interface__["data"][0]
+        sizes[k] = arr.size
+    return addrs, kinds, sizes
+
+
+def _codes_eq(codes: np.ndarray, row: int) -> np.ndarray:
+    """`codes == row`, where the codes' width can hold the row at all
+    (its largest value is the sentinel of a column with no row)."""
+    if 0 <= row < np.iinfo(codes.dtype).max:
+        return codes == row
+    return np.zeros(codes.shape, dtype=bool)
+
+
+def page_coords(lanes, row: int, width: int, coords: np.ndarray,
+                lane_counts: np.ndarray) -> int:
+    """Sorted coordinates `lane * width + column` of `row`'s bits over
+    a page's lanes, written into the sentinel-filled `coords`; each
+    lane's count into `lane_counts`.  Returns how many, or -1 where
+    they do not fit `coords` or a lane is held as words."""
+    lib = _load()
+    if lib is not None:
+        addrs, kinds, sizes = _lane_args(lanes, width)
+        return int(lib.pt_page_coords(
+            addrs, kinds, sizes, len(lanes), width, int(row), coords,
+            coords.size, lane_counts))
+    n = 0
+    for k, lane in enumerate(lanes):
+        if lane is None:
+            continue
+        if lane[0] == "words":
+            return -1
+        cols = (lane[1] if lane[0] == "cols"
+                else np.flatnonzero(_codes_eq(lane[1], row)))
+        if n + cols.size > coords.size:
+            return -1
+        coords[n:n + cols.size] = cols + k * width
+        lane_counts[k] = cols.size
+        n += cols.size
+    return n
+
+
+def page_fill(lanes, row: int, width: int,
+              block: np.ndarray) -> tuple[int, int, int]:
+    """`row`'s packed words over a page's lanes into every word of
+    `block` (page_lanes, width / 32), zeros where no lane holds it.
+    Returns (all-ones words, their runs over the flat block, lanes
+    copied as words): the first two count the lanes made from codes
+    or columns only, so they describe the block where the third is 0."""
+    lib = _load()
+    if lib is not None:
+        addrs, kinds, sizes = _lane_args(lanes, width)
+        stats = np.zeros(3, np.int64)
+        lib.pt_page_fill(addrs, kinds, sizes, len(lanes),
+                         block.shape[0], width, int(row),
+                         block.reshape(-1), stats)
+        return int(stats[0]), int(stats[1]), int(stats[2])
+    block[:] = 0
+    made = np.zeros(block.shape[0], bool)
+    copied = 0
+    for k, lane in enumerate(lanes):
+        if lane is None:
+            continue
+        if lane[0] == "words":
+            block[k] = lane[1]
+            copied += 1
+        elif lane[0] == "codes":
+            block[k] = np.packbits(_codes_eq(lane[1], row),
+                                   bitorder="little").view(np.uint32)
+            made[k] = True
+        else:
+            or_bits(block[k], lane[1])
+            made[k] = True
+    full = (block == np.uint32(0xFFFFFFFF)) & made[:, None]
+    edges = np.diff(np.concatenate(
+        ([False], full.reshape(-1), [False])).astype(np.int8))
+    return (int(np.count_nonzero(full)),
+            int(np.count_nonzero(edges == 1)), copied)
