@@ -1327,9 +1327,10 @@ def _onepass_arm(n_codes: int, depth: int, mesh_minmax: bool = False,
       (groupby_fused; `body` is the one its shapes take,
       kernels.fused_plan's choice): on a TPU, inside
       _ONEPASS_KERNEL_MAX_*; past _ONEPASS_KERNEL_MAX_CODES where the
-      packed body walks the live groups (in passes, if need be: a
-      10 x 8 x 60 GroupBy has 8,192 codes and 4,800 groups) — the
-      one-hot body's lane axis is the code space and never goes there
+      packed body walks the live groups (a 10 x 8 x 60 GroupBy has
+      8,192 codes and 4,800 groups: one walk, and 12 over slices of
+      the 60 rows with a 9-bit Sum) — the one-hot body's lane axis is
+      the code space and never goes there
     - "xla"   — the scatter-add form (groupby_codes_xla): off a TPU
       (a CPU would only interpret the kernel), past the bounds (a
       2^20-code value histogram under the kernel would build a

@@ -387,8 +387,8 @@ GROUPBY_FUSED = registry.counter(
 GROUPBY_PASSES = registry.counter(
     "pilosa_groupby_fused_passes_total",
     "Walks over the operands that the fused GroupBy dispatches made: "
-    "1 a dispatch, more where the packed body takes the live groups "
-    "in passes over slices of the widest field's rows")
+    "1 a dispatch, more where the packed body walks the widest "
+    "field's rows in slices")
 GROUPBY_REPLY_GROUPS = registry.counter(
     "pilosa_groupby_reply_groups_total",
     "Groups returned in GroupBy replies")

@@ -338,38 +338,50 @@ def _valid_operand(valid, bw: int):
 # ---------------------------------------------------------------------------
 #
 # One pass over the operands, and the inner body never leaves the
-# packed domain (ISSUE 32).  Per (shard, word block) grid step:
+# packed domain (ISSUE 32; the last level counted where it is formed,
+# ISSUE 40).  Per (shard, word block) grid step:
 #
 #   0. every plane of the block is re-laid out in VMEM so that its
 #      words fill whole (8, 128) vregs (the (1, P, BW) block arrives
 #      with the planes along sublanes: one sublane of eight per plane);
-#   1. every LIVE group's column mask is formed from the fields' digit
-#      planes — a row of a field is the AND of its digit planes or
-#      their complements, `valid` is ANDed into the first field's rows,
-#      and the fields multiply out as a tree (6 -> 12 -> 60 masks for
-#      the able query), each level one array of masks — and parked in
-#      a VMEM scratch;
-#   2. per group the payload rows are popcounts of ANDs: popcount(m),
+#   1. the UPPER masks are formed and parked in a VMEM scratch: every
+#      field but the widest multiplied out as a tree, each level one
+#      array of masks — a row of a field is the AND of its digit
+#      planes or their complements, `valid` is ANDed into the first
+#      level (2 x 5 = 10 masks for the able query, 10 x 8 = 80 for
+#      taxi-1b's Q4);
+#   2. the widest field's rows are walked in a rolled loop.  A row's
+#      literal is formed once a turn (the row index's bits keep a
+#      digit plane or flip it) and kept in a block-wide scratch; then,
+#      for a handful of upper masks at a time (_PACKED_INNER, a static
+#      inner group inside a second rolled loop), a group's mask is
+#      `upper & literal` in registers and its payload rows are
+#      popcounts of ANDs off that value — popcount(m),
 #      popcount(m & exists), popcount(m & exists [& ~sign | & sign] &
-#      plane_p) — three word operations per (group, row) for 32
-#      columns — folded over the block's vregs in registers and added
-#      once per grid step into the group's int32 lane partials;
+#      plane_p) — folded over the block's vregs in registers and added
+#      once per grid step into the group's int32 lane partials.  A
+#      (group, vreg) unit of a count is two loads, one AND, one
+#      popcount and one add; no group's mask is ever stored.
 #
-# The lane partials leave the kernel as they are, by one copy on the
-# last grid step, and one small XLA reduce makes the dense (K, G)
-# table of them; codes whose digit exceeds its field's row count are
-# never visited and stay 0.
+# Kept in VMEM: the accumulators (live groups x payload rows, a vreg
+# each), the upper masks and one literal (a block each), the operand
+# blocks and their re-laid-out copy.  The lane partials leave the
+# kernel as they are, by one copy on the last grid step, and one small
+# XLA reduce makes the dense (K, G) table of them; codes whose digit
+# exceeds its field's row count are never visited and stay 0.
 #
 # The accumulators grow with live groups x payload rows.  Where one
-# walk's do not fit the VMEM a kernel may ask for (_PACKED_VMEM_BYTES)
-# the groups are walked in passes over slices of the widest field's
-# rows (taxi-1b's 10 x 8 x 60 = 4,800 groups: 20 passes of 240): a
-# leading grid axis, the slice's rows read off the pass index, the
-# accumulators flushed into their pass's part of the output.  Every
-# pass streams the operands again; the popcount work is what it was.
-# Where not even one row of the widest field fits, and for Min/Max
-# (which the packed body does not compute: ROADMAP A3), the earlier
-# body serves:
+# walk's do not fit the VMEM the kernel asks for (_PACKED_VMEM_LIMIT;
+# _PACKED_VMEM_BYTES of it are the body's to count) the widest
+# field's rows are walked in slices: a leading grid axis, a walk per
+# slice, the slice's first row read off the walk index, the
+# accumulators flushed into their walk's part of the output
+# (taxi-1b's 10 x 8 x 60 = 4,800 groups are one walk; with its 9-bit
+# amount summed, 12 walks of 5 rows).  Every walk streams the operands
+# and forms the upper masks again; the popcount work is what it was.
+# Where not even one row of the widest field fits at the full block
+# width, and for Min/Max (which the packed body does not compute:
+# ROADMAP A3), the earlier body serves:
 # every column unpacked to an int32 code, compared with a 128-lane
 # iota into a one-hot and contracted against the 0/1 payload rows on
 # the MXU (int8 @ int8 -> int32), Min/Max as masked reductions over
@@ -382,14 +394,20 @@ def _valid_operand(valid, bw: int):
 # < 2^24 terms.
 
 _VREG_WORDS = 8 * _LANES      # one (8, 128) vreg of packed words
-# what the packed body may ask of the 16 MiB of scoped VMEM a v5e
-# kernel gets by default; the rest is Mosaic's own
-_PACKED_VMEM_BYTES = 13 << 20
+# the scoped VMEM every packed call asks Mosaic for
+# (CompilerParams(vmem_limit_bytes=): a v5e core has 128 MiB and gives
+# a kernel 16 by default), and what the body may count of it — the
+# rest is Mosaic's own (taxi-1b's Q4 counts 27.7 MiB and asks 26.7).
+# Chosen on the chip (PR 40; Q4's 4,800 groups alone at 263 shards):
+# one walk of 16-vreg blocks 0.0297 s; inside 13 MiB five walks of 12
+# rows at 16 vregs 0.0346, three of 20 rows at 8 vregs 0.0387
+_PACKED_VMEM_LIMIT = 32 << 20
+_PACKED_VMEM_BYTES = 29 << 20
 _PACKED_BLOCK_VREGS = 16      # vregs of each plane per grid step, at most
-# passes are cut so that a plane's block keeps this many vregs where
-# it can: narrower blocks pay the grid step's overhead more often than
-# wider slices save passes
-_PACKED_PASS_VREGS = 8
+# upper masks an inner turn takes: that many independent
+# AND -> popcount -> add chains overlap, their partials in registers
+# (Q4 alone: 8 a turn 0.0297 s, 4 a turn 0.0321, 16 a turn 0.0308)
+_PACKED_INNER = 8
 # the code space the one-hot body takes (its lane axis, 128 codes a
 # block); stacked's _ONEPASS_KERNEL_MAX_CODES is this number
 ONEHOT_MAX_CODES = 4096
@@ -401,19 +419,41 @@ def _payload_rows(depth: int, signed: bool) -> int:
     return 1 if depth == 0 else 2 + (2 if signed else 1) * depth
 
 
-def _packed_block_vregs(digits, depth: int, signed: bool) -> int:
-    """Vregs of each plane the packed body takes per grid step: the
-    most (a power of two up to _PACKED_BLOCK_VREGS) at which what it
-    keeps in VMEM fits _PACKED_VMEM_BYTES, 0 when not even one does.
-    Counted in vregs: the accumulators (live groups x payload rows, +
-    one each for the mask tree's inner levels), and per block vreg the
-    mask scratch (live groups), the operand blocks (double-buffered,
-    planes padded to 8 sublanes) and their re-laid-out copy."""
-    n_live = int(np.prod([rows for _, rows in digits], dtype=np.int64))
+def dense_digits(cb: int) -> tuple:
+    """The digit layout of a code space whose every code is live (a
+    value histogram's): the bits as two fields, so that the packed
+    body parks 2^(cb - cb // 2) upper masks and walks 2^(cb // 2)
+    rows against them."""
+    lo = cb // 2
+    return tuple((b, 1 << b) for b in (cb - lo, lo) if b) or ((0, 1),)
+
+
+def _last_field(digits) -> int:
+    """The field whose rows the packed body walks: the widest."""
+    return max(range(len(digits)), key=lambda i: digits[i][1])
+
+
+def _n_upper(digits, fi: int) -> int:
+    """Upper masks: the product of every other field's rows."""
+    return int(np.prod([rows for i, (_, rows) in enumerate(digits)
+                        if i != fi], dtype=np.int64))
+
+
+def _packed_block_vregs(digits, fi: int, rp: int, depth: int,
+                        signed: bool) -> int:
+    """Vregs of each plane the packed body takes per grid step when a
+    walk holds `rp` rows of field `fi`: the most (a power of two up
+    to _PACKED_BLOCK_VREGS) at which what it keeps in VMEM fits
+    _PACKED_VMEM_BYTES, 0 when not even one does.  Counted in vregs:
+    the accumulators (upper masks x rows of the walk x payload rows),
+    and per block vreg the parked upper masks, the row's literal, the
+    operand blocks (double-buffered, planes padded to 8 sublanes) and
+    their re-laid-out copy."""
+    n_upper = _n_upper(digits, fi)
     cb = max(sum(bits for bits, _ in digits), 1)
     groups = [cb, 1] + ([2 + depth] if depth else [])
-    acc = n_live * (_payload_rows(depth, signed) + 1)
-    per_vreg = (n_live + sum(groups)
+    acc = n_upper * rp * _payload_rows(depth, signed)
+    per_vreg = (n_upper + 1 + sum(groups)
                 + 2 * sum(-(-g // 8) * 8 for g in groups))
     nv = _PACKED_BLOCK_VREGS
     while nv and 4 * _VREG_WORDS * (
@@ -425,44 +465,45 @@ def _packed_block_vregs(digits, depth: int, signed: bool) -> int:
 @functools.lru_cache(maxsize=256)
 def _packed_passes(digits, depth: int, signed: bool):
     """How the packed body walks the live groups of `digits`:
-    (block vregs, split field, rows a pass, passes).  One walk
-    (split field None) where the accumulators fit; else slices of the
-    widest field's rows — the most rows at which a block keeps
-    _PACKED_PASS_VREGS vregs, else the most that fit at all; block
-    vregs 0 where not even one row does."""
-    nv = _packed_block_vregs(digits, depth, signed)
-    if nv:
-        return nv, None, 0, 1
-    fi = max(range(len(digits)), key=lambda i: digits[i][1])
-    bits, rows = digits[fi]
+    (block vregs, last field, rows a walk, walks).  The last field is
+    the widest; one walk takes all its rows where the accumulators
+    fit, else the walks take slices of them, as few as keep the block
+    at its full width (_PACKED_BLOCK_VREGS), the rows spread evenly
+    over them.  Block vregs 0 where not even one row fits at that
+    width: every walk forms the upper masks again, and narrow blocks
+    under many walks lose to the scatter (three fields of 60 rows,
+    count-only, 263 shards: 60 walks of 1-vreg blocks 8.92 s, the XLA
+    scatter 1.91; chip, PR 40)."""
+    fi = _last_field(digits)
+    rows = digits[fi][1]
 
     def block(rp):
-        return _packed_block_vregs(
-            digits[:fi] + ((bits, rp),) + digits[fi + 1:], depth, signed)
+        return _packed_block_vregs(digits, fi, rp, depth, signed)
 
-    for want in (_PACKED_PASS_VREGS, 1):
-        rp = 0
-        while rp + 1 < rows and block(rp + 1) >= want:
-            rp += 1
-        if rp:
-            return block(rp), fi, rp, -(-rows // rp)
-    return 0, None, 0, 1
+    nv = block(rows)
+    if nv:
+        return nv, fi, rows, 1
+    if block(1) < _PACKED_BLOCK_VREGS:
+        return 0, fi, 0, 1
+    rp = 1
+    while block(rp + 1) == _PACKED_BLOCK_VREGS:
+        rp += 1
+    n_pass = -(-rows // rp)
+    return _PACKED_BLOCK_VREGS, fi, -(-rows // n_pass), n_pass
 
 
-def _pass_codes(digits, fi, rp: int, n_pass: int) -> np.ndarray:
-    """Dense codes of the groups the packed body visits, (passes,
-    groups a pass) in the order it visits them: the unsplit fields
-    multiply out first (first field fastest), the split field's rows
+def _pass_codes(digits, fi: int, rp: int, n_pass: int) -> np.ndarray:
+    """Dense codes of the groups the packed body visits, (walks,
+    groups a walk) in the order it visits them: the upper fields
+    multiply out first (first field fastest), the last field's rows
     last; -1 where a slot's row is past its field's last (the last
-    pass of a row count the slice does not divide)."""
+    walk of a row count the slice does not divide)."""
     shifts = np.cumsum([0] + [b for b, _ in digits])
     codes = np.zeros(1, np.int64)
     for i, (_bits, rows) in enumerate(digits):
         if i != fi:
             codes = (codes[None, :]
                      | (np.arange(rows)[:, None] << shifts[i])).ravel()
-    if fi is None:
-        return codes[None, :]
     r = np.arange(n_pass * rp).reshape(n_pass, rp, 1)
     out = codes[None, None, :] | (r << shifts[fi])
     return np.where(r < digits[fi][1], out, -1).reshape(n_pass, -1)
@@ -472,19 +513,19 @@ def fused_plan(digits, depth: int, signed: bool = True,
                minmax: bool = False) -> tuple:
     """(body, walks over the operands) of groupby_fused for these
     static arguments: "packed" for counts and Sum where its
-    accumulators fit the kernel's VMEM in one walk, or in passes
+    accumulators fit the kernel's VMEM in one walk, or in several
     (_packed_passes) where the code space is past ONEHOT_MAX_CODES —
     else "onehot" in one walk (Min/Max always).
     `digits` is ((bits, rows), ...) per GroupBy field (the _code_space
     layout with each field's row count).  Called at trace time by
     groupby_fused and once a dispatch by stacked._onepass_plan, which
     picks the arm and counts pilosa_groupby_fused_total{body=} and
-    the passes from it, so all agree."""
+    the walks from it, so all agree."""
     if not minmax:
         nv, _fi, _rp, n_pass = _packed_passes(tuple(digits), depth, signed)
-        # in passes only past the code space the one-hot body takes:
-        # inside it the one-hot is the faster of the two where one
-        # walk does not fit (64 x 64 groups, a signed 16-bit Sum, 64
+        # in several walks only past the code space the one-hot body
+        # takes: inside it the one-hot was the faster of the two where
+        # one walk did not fit (64 x 64 groups, a signed 16-bit Sum, 64
         # shards: 0.193 s against 0.424 in 64 passes; chip, PR 36)
         if nv and (n_pass == 1 or 1 << sum(
                 b for b, _ in digits) > ONEHOT_MAX_CODES):
@@ -497,31 +538,49 @@ def fused_body(digits, depth: int, signed: bool = True,
     return fused_plan(digits, depth, signed, minmax)[0]
 
 
+def _inner_groups(n_upper: int, k: int) -> tuple:
+    """(upper masks an inner iteration, whole iterations, masks left
+    over): at most _PACKED_INNER x k partials stay in registers, and
+    the masks are spread evenly over the iterations."""
+    most = max(1, _PACKED_INNER // k)
+    u = -(-n_upper // -(-n_upper // most))
+    return u, n_upper // u, n_upper % u
+
+
 def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
-                      fi=None, rp: int = 0):
-    """Packed body factory (see the block comment above).  With a
-    split field `fi` the grid leads with the pass axis and a pass
-    takes rows [pass * rp, (pass + 1) * rp) of that field."""
+                      fi: int, rp: int, n_pass: int):
+    """Packed body factory (see the block comment above): a walk takes
+    rows [walk * rp, (walk + 1) * rp) of field `fi`; with more walks
+    than one the grid leads with the walk axis."""
     cb = sum(bits for bits, _ in digits)
     # lax primitives, not jnp's jitted wrappers (each a nested pjit to
     # trace and lower), and `step` vregs of words to an operation:
     # what Mosaic has to lower stays in the hundreds of operations
     _and, _not = jax.lax.bitwise_and, jax.lax.bitwise_not
     step = next(u for u in (4, 2, 1) if nv % u == 0)
-    lead = 0 if fi is None else 1
-    starts = np.cumsum([0] + [bits for bits, _ in digits])
-    # the split field's rows multiply out last
-    order = [i for i in range(len(digits)) if i != fi] + (
-        [] if fi is None else [fi])
+    chunks = [slice(8 * step * c, 8 * step * (c + 1))
+              for c in range(nv // step)]
+    lead = 1 if n_pass > 1 else 0
+    starts = [int(x) for x in np.cumsum([0] + [b for b, _ in digits])]
+    last_bits = digits[fi][0]
+    n_upper = _n_upper(digits, fi)
+    inner, whole, left = _inner_groups(n_upper, k)
 
     def kernel(cp_ref, va_ref, *refs):
         pl_ref = refs[0] if depth else None
-        out_ref, acc_ref, masks_ref, dense_ref = refs[1 if depth else 0:]
+        (out_ref, acc_ref, masks_ref, lit_ref,
+         dense_ref) = refs[1 if depth else 0:]
         s, wi = pl.program_id(lead), pl.program_id(lead + 1)
 
         @pl.when((s == 0) & (wi == 0))
         def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+            # slot by slot: one store of the whole table would be
+            # unrolled into a store a vreg (4,800 for taxi-1b's Q4)
+            def clear(i, carry):
+                acc_ref[i] = jnp.zeros(acc_ref.shape[1:], jnp.int32)
+                return carry
+
+            jax.lax.fori_loop(0, acc_ref.shape[0], clear, 0)
 
         # 0. planes along sublanes -> each plane's words in whole vregs
         srcs = [(cp_ref, b) for b in range(cb)] + [(va_ref, 0)] + [
@@ -529,81 +588,103 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
         for i, (ref, p) in enumerate(srcs):
             dense_ref[i] = ref[0, p, :].reshape(8 * nv, _LANES)
 
-        # this pass's rows of the split field, read off the pass index:
-        # bit b of a row keeps plane b or flips it
-        flips = [[jnp.where(((pl.program_id(0) * rp + j) >> b) & 1 == 1,
-                            jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
-                  for b in range(digits[fi][0])]
-                 for j in range(rp)] if fi is not None else None
-
-        # 1. the live groups' masks, one vreg of words at a time.  A
-        # level of the tree is one array of masks, (n, 8, 128): the
-        # operations to lower number the fields' rows, not the groups
-        def mask_step(v, carry):
+        # 1. the upper masks, one vreg of words at a time: `valid` and
+        # every field but the last multiplied out as a tree.  A level
+        # is one array of masks, (n, 8, 128): the operations to lower
+        # number the fields' rows, not the groups
+        def upper_step(v, carry):
             rs = pl.ds(pl.multiple_of(v * 8, 8), 8)
             masks = dense_ref[pl.ds(cb, 1), rs, :]
-            for i in order:
-                bits, rows = digits[i]
-                if not bits:
+            for i, (bits, rows) in enumerate(digits):
+                if i == fi or not bits:
                     continue
-                one = [dense_ref[pl.ds(int(starts[i]) + b, 1), rs, :]
+                one = [dense_ref[pl.ds(starts[i] + b, 1), rs, :]
                        for b in range(bits)]
+                zero = [_not(x) for x in one]
                 level = []
-                if i == fi:
-                    for j in range(rp):
-                        lit = None
-                        for b in range(bits):
-                            t = jax.lax.bitwise_xor(
-                                one[b], jnp.full_like(one[b], flips[j][b]))
-                            lit = t if lit is None else _and(lit, t)
-                        level.append(_and(masks, lit))
-                else:
-                    zero = [_not(x) for x in one]
-                    for r in range(rows):
-                        lit = None
-                        for b in range(bits):
-                            t = one[b] if (r >> b) & 1 else zero[b]
-                            lit = t if lit is None else _and(lit, t)
-                        level.append(_and(masks, lit))
+                for r in range(rows):
+                    lit = None
+                    for b in range(bits):
+                        t = one[b] if (r >> b) & 1 else zero[b]
+                        lit = t if lit is None else _and(lit, t)
+                    level.append(_and(masks, lit))
                 masks = jax.lax.concatenate(level, 0)
             masks_ref[:, rs, :] = masks
             return carry
 
-        jax.lax.fori_loop(0, nv, mask_step, 0)
+        jax.lax.fori_loop(0, nv, upper_step, 0)
 
-        # 2. per group: popcounts of ANDs, `step` vregs of words to an
-        # operation, folded over the block in registers; one VMEM add
-        # per (group, row)
-        def group(j, carry):
-            hist = [None] * k
-            for c in range(nv // step):
-                rs = slice(8 * step * c, 8 * step * (c + 1))
-                m = masks_ref[j, rs, :]
-                words = [m]
+        # 2. the last field's rows, one a turn of a rolled loop.  The
+        # row's literal — its digit planes or their complements, as
+        # the row index's bits say — is formed once and kept for the
+        # turn; a group's mask is an upper mask ANDed with it, in
+        # registers, and the payload rows are popcounts of ANDs off
+        # that value, `step` vregs of words to an operation, folded
+        # over the block in registers: one VMEM add per (group, row)
+        first = pl.program_id(0) * rp if lead else 0
+
+        def some(slot, base, n):
+            hist = [[None] * k for _ in range(n)]
+            for rs in chunks:
+                lit = lit_ref[rs, :] if last_bits else None
+                ex = sg = mags = None
                 if depth:
-                    em = _and(m, dense_ref[cb + 1, rs, :])
-                    words.append(em)
+                    ex = dense_ref[cb + 1, rs, :]
                     mags = [dense_ref[cb + 3 + p, rs, :]
                             for p in range(depth)]
-                    sides = [em]
                     if signed:
                         sg = dense_ref[cb + 2, rs, :]
-                        sides = [_and(em, _not(sg)), _and(em, sg)]
-                    for side in sides:
-                        words += [_and(side, mg) for mg in mags]
-                for r, x in enumerate(words):
-                    n = _pc(x)
-                    if step > 1:
-                        n = jnp.sum(n.reshape(step, 8, _LANES), axis=0)
-                    hist[r] = n if hist[r] is None \
-                        else jax.lax.add(hist[r], n)
-            for r in range(k):
-                acc_ref[j, r] += hist[r]
+                for u in range(n):
+                    m = masks_ref[base + u, rs, :]
+                    if lit is not None:
+                        m = _and(m, lit)
+                    words = [m]
+                    if depth:
+                        em = _and(m, ex)
+                        words.append(em)
+                        sides = [em]
+                        if signed:
+                            sides = [_and(em, _not(sg)), _and(em, sg)]
+                        for side in sides:
+                            words += [_and(side, mg) for mg in mags]
+                    for r, x in enumerate(words):
+                        c = _pc(x)
+                        if step > 1:
+                            c = jnp.sum(c.reshape(step, 8, _LANES), axis=0)
+                        hist[u][r] = c if hist[u][r] is None \
+                            else jax.lax.add(hist[u][r], c)
+            for u in range(n):
+                for r in range(k):
+                    acc_ref[slot + base + u, r] += hist[u][r]
+
+        def row(j, carry):
+            if last_bits:
+                # bit b of the row keeps plane b or flips it
+                flips = [jnp.where(((first + j) >> b) & 1 == 1,
+                                   jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
+                         for b in range(last_bits)]
+                for rs in chunks:
+                    lit = None
+                    for b in range(last_bits):
+                        x = dense_ref[starts[fi] + b, rs, :]
+                        t = jax.lax.bitwise_xor(
+                            x, jnp.full_like(x, flips[b]))
+                        lit = t if lit is None else _and(lit, t)
+                    lit_ref[rs, :] = lit
+            slot = j * n_upper
+
+            def turn(g, carry):
+                some(slot, g * inner, inner)
+                return carry
+
+            jax.lax.fori_loop(0, whole, turn, 0)
+            if left:
+                some(slot, whole * inner, left)
             return carry
 
-        jax.lax.fori_loop(0, masks_ref.shape[0], group, 0)
+        jax.lax.fori_loop(0, rp, row, 0)
 
-        dst = out_ref if fi is None else out_ref.at[pl.program_id(0)]
+        dst = out_ref.at[pl.program_id(0)] if lead else out_ref
 
         @pl.when((s == pl.num_programs(lead) - 1)
                  & (wi == pl.num_programs(lead + 1) - 1))
@@ -626,30 +707,34 @@ def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
         code_planes, valid[:, None, :]) + ((planes,) if depth else ())]
     # the accumulators are scratch, so the VMEM asked for is what
     # _packed_block_vregs counted; they leave by one copy at the end
-    # (of each pass)
-    n_live = codes.shape[1]
-    table = (n_live, k, 8, _LANES)
+    # (of each walk)
+    n_slots = codes.shape[1]
+    table = (n_slots, k, 8, _LANES)
     grid = (s_dim, arrays[0].shape[2] // bw)
     at = lambda s, w: (s, 0, w)
-    if fi is not None:
+    if n_pass > 1:
         grid = (n_pass,) + grid
         at = lambda p, s, w: (s, 0, w)
     out = pl.pallas_call(
-        _gb_packed_kernel(digits, depth, signed, k, nv, fi, rp),
+        _gb_packed_kernel(digits, depth, signed, k, nv, fi, rp, n_pass),
         grid=grid,
         in_specs=[pl.BlockSpec((1, x.shape[1], bw), at) for x in arrays],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(
-            table if fi is None else (n_pass,) + table, jnp.int32),
+            table if n_pass == 1 else (n_pass,) + table, jnp.int32),
         scratch_shapes=[
             pltpu.VMEM(table, jnp.int32),
-            pltpu.VMEM((n_live, 8 * nv, _LANES), jnp.uint32),
+            pltpu.VMEM((_n_upper(digits, fi), 8 * nv, _LANES),
+                       jnp.uint32),
+            pltpu.VMEM((8 * nv, _LANES), jnp.uint32),
             pltpu.VMEM((sum(x.shape[1] for x in arrays), 8 * nv,
                         _LANES), jnp.uint32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PACKED_VMEM_LIMIT),
         # one factory, two schedules, a name each on the device plane:
         # a reader of "groupby_fused_sum" times one walk over the
         # operands, as it did before there were passes
-        name="groupby_fused_sum" if fi is None else "groupby_fused_passes",
+        name="groupby_fused_sum" if n_pass == 1 else "groupby_fused_passes",
         interpret=_interpret(),
     )(*arrays)
     sums = jnp.sum(out, axis=(-2, -1)).reshape(-1, k)
@@ -809,22 +894,24 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
     count): only codes whose every digit is below its field's row
     count are visited, the others stay 0 — which is what they hold
     anyway when `valid` is the AND of the field unions.  Without it
-    every code of the CB planes is live (bsi_value_hist).
+    every code of the CB planes is live (bsi_value_hist:
+    dense_digits).
 
     Schedule: grid (S, W/BW) with NO combo axis — every code plane,
     valid word, and BSI plane word streams through VMEM exactly once
     and the accumulators stay VMEM-resident for the whole walk.  Per
     group the kernel ANDs the group's mask with the payload planes
-    and popcounts, 32 columns per word operation (the packed body);
-    shapes whose accumulators would not fit VMEM, and ``minmax``,
-    take the one-hot body instead (see the block comment above, and
-    fused_body).
+    and popcounts, 32 columns per word operation (the packed body;
+    in several walks where one walk's accumulators would not fit);
+    shapes of which not one row of the widest field fits at the full
+    block width, and ``minmax``, take the one-hot body instead (see
+    the block comment above, and fused_plan).
     """
     s_dim, cb, w_dim = code_planes.shape
     depth = 0 if planes is None else planes.shape[1] - 2
     assert not (minmax and depth == 0), "minmax requires BSI planes"
     if digits is None:
-        digits = ((1, 2),) * cb
+        digits = dense_digits(cb)
     digits = tuple((int(b), int(r)) for b, r in digits)
     assert sum(b for b, _ in digits) == cb, (digits, cb)
     if cb == 0:                        # all fields single-row: code 0

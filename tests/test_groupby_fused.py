@@ -98,6 +98,9 @@ class TestFusedKernelBitExact:
         ((3, 2), 5, True, 1, 40, True, None),          # one shard
         ((5, 3), 4, True, 2, 2100, False, 1),          # W not a multiple
         ((6, 2, 5), 7, False, 1, 1030, True, 1),       # of the block
+        # blocks of 8 vregs (two chunks of 4 to an operation) and of 2
+        ((6, 2, 5), 0, False, 1, 8192, True, None),
+        ((5, 3), 2, True, 1, 2048, False, None),
     ]
 
     @pytest.mark.parametrize("case", PACKED_CASES)
@@ -156,6 +159,14 @@ class TestFusedKernelBitExact:
         assert kernels.fused_body(bound, 16, True) == "onehot"
         assert kernels.fused_body(bound, 16, True, True) == "onehot"
         assert kernels.fused_body(((1, 2),) * 13, 0) == "onehot"
+        # a value histogram's codes as two fields (dense_digits): 4,096
+        # codes are 64 upper masks x 64 rows, one walk
+        assert kernels.dense_digits(12) == ((6, 64), (6, 64))
+        assert kernels.dense_digits(7) == ((4, 16), (3, 8))
+        assert kernels.dense_digits(1) == ((1, 2),)
+        assert kernels.dense_digits(0) == ((0, 1),)
+        assert kernels.fused_plan(kernels.dense_digits(12), 0) \
+            == ("packed", 1)
 
     CASES = [
         # (nf_rows, depth, signed, all_invalid, extreme)
@@ -342,7 +353,8 @@ class TestValueHistByproduct:
         import jax.numpy as jnp
         if block is not None:
             monkeypatch.setattr(kernels, "_PACKED_BLOCK_VREGS", block)
-        assert kernels.fused_body(((1, 2),) * (depth + 1), 0) == "packed"
+        assert kernels.fused_body(kernels.dense_digits(depth + 1),
+                                  0) == "packed"
         planes = jnp.asarray(rng.integers(
             0, 2**32, size=(s_dim, 2 + depth, w), dtype=np.uint32))
         filt = jnp.asarray(rng.integers(0, 2**32, size=(s_dim, w),

@@ -180,11 +180,13 @@ def _counters():
 
 
 @pytest.mark.parametrize("path", ["solo", "served"])
-def test_a_groupby_past_4096_codes_takes_the_kernel_in_passes(taxi, path):
-    """Q4's code space is 8,192: the packed body serves it in 20
-    passes of 240 groups, and the counters say so."""
+def test_a_groupby_past_4096_codes_takes_the_packed_kernel(taxi, path):
+    """Q4's code space is 8,192: the packed body serves its 4,800
+    groups — in one walk since PR 40 (20 passes of 240 before) — and
+    the counters say what kernels.fused_plan says."""
     apis, columns, _hot, _params = taxi
     q = QUERIES["q4_dist"].replace("{F}", "Row(pickup_month=5)")
+    assert kernels.fused_plan(Q4_DIGITS, 0, False) == ("packed", 1)
     before = _counters()
     got = apis[path].query("taxi", q)["results"][0]
     want = reference_taxi.answer(columns, pql.parse(q))
@@ -192,7 +194,7 @@ def test_a_groupby_past_4096_codes_takes_the_kernel_in_passes(taxi, path):
     moved = _counters()
     assert {k: moved[k] - before[k] for k in moved} == {
         "loop": 0, "fused_arm": 1, "onepass": 1, "packed": 1, "fused": 1,
-        "passes": 20, "groups": len(want)}
+        "passes": 1, "groups": len(want)}
 
 
 def test_a_small_groupby_is_one_pass_and_a_topn_counts_no_groups(taxi):
@@ -207,33 +209,68 @@ def test_a_small_groupby_is_one_pass_and_a_topn_counts_no_groups(taxi):
     assert metrics.GROUPBY_REPLY_GROUPS.total() - groups == 80
 
 
-# -- the packed body in passes ------------------------------------------
+# -- the packed body's walks ----------------------------------------------
 
+MIB = 1 << 20
+PAIRS = ((3, 7), (4, 11))
 PASS_CASES = {
-    # widest field last, first, in the middle; a row count the slice
-    # does not divide (7 rows a pass of 60: the last pass has 4)
-    "q4_count": (Q4_DIGITS, 0, False),
-    "widest_first": (((6, 60), (4, 10), (3, 7)), 0, False),
-    "widest_middle": (((3, 8), (6, 50), (4, 10)), 0, False),
-    # with payload planes (one walk of Q4's 4,800 groups x 11 rows takes
-    # the interpreter minutes: 960 groups here)
-    "sum_unsigned": (((4, 10), (3, 8), (6, 12)), 9, False),
-    "sum_signed": (((4, 10), (3, 8), (6, 12)), 3, True),
+    # name: (digits, depth, signed, VMEM the body may count and block
+    # vregs at most (None: the kernel's own), the plan that gives:
+    # (block vregs, last field, rows a walk, walks)).  The count-only
+    # forms are one walk under the kernel's own 29 MiB; 13 MiB is what
+    # a kernel had before PR 40.  Widest field last, first, in the
+    # middle; 50 rows in 4 walks of 13: the last walk's slots past row
+    # 49 are dead
+    "q4_count": (Q4_DIGITS, 0, False, (13 * MIB, 16), (16, 2, 12, 5)),
+    "widest_first": (((6, 60), (4, 10), (3, 7)), 0, False,
+                     (13 * MIB, 16), (16, 0, 15, 4)),
+    "widest_middle": (((3, 8), (6, 50), (4, 10)), 0, False,
+                      (13 * MIB, 16), (16, 1, 13, 4)),
+    # with payload planes, off the register-formed mask (one walk of
+    # Q4's 4,800 groups x 11 rows takes the interpreter minutes: 960
+    # groups here)
+    "sum_unsigned": (((4, 10), (3, 8), (6, 12)), 9, False, None,
+                     (16, 2, 4, 3)),
+    "sum_signed": (((4, 10), (3, 8), (6, 12)), 3, True, None,
+                   (16, 2, 6, 2)),
+    # one field: no upper level, `valid` is the one upper mask
+    "single_field": (((6, 60),), 0, False, (MIB // 4, 1), (1, 0, 20, 3)),
+    # 11 upper masks: an inner turn of 6 and 5 left over; 13 rows in 3
+    # walks of 5: two dead slots
+    "inner_left_over": (((4, 11), (4, 13)), 0, False, (MIB // 2, 1),
+                        (1, 1, 5, 3)),
+    # three and four payload rows: two upper masks an inner turn, one
+    # left over; 11 rows in 6 walks of 2
+    "sum_pairs": (PAIRS, 1, False, (MIB // 2, 1), (1, 1, 2, 6)),
+    "sum_signed_pairs": (PAIRS, 1, True, (MIB // 2, 1), (1, 1, 2, 6)),
+    # every column of the first shard (a whole block) invalid
+    "valid_zero_block": (PAIRS, 1, True, (MIB // 2, 1), (1, 1, 2, 6)),
 }
 
 
 def test_inside_the_one_hot_bodys_code_space_nothing_goes_in_passes():
     """A shape of up to 4,096 codes whose accumulators do not fit one
-    walk keeps the one-hot body, as before PR 36: on the chip it is
+    walk keeps the one-hot body, as before PR 36: on the chip it was
     the faster there (kernels.fused_plan)."""
     bound = ((6, 64), (6, 64))
-    assert kernels._packed_passes(bound, 16, True)[3] == 64
+    assert kernels._packed_passes(bound, 16, True)[3] == 32
     assert kernels.fused_plan(bound, 16, True) == ("onehot", 1)
-    assert kernels.fused_plan(((4, 10), (3, 8), (4, 12)), 9, False) \
+    assert kernels.fused_plan(((4, 10), (3, 8), (5, 32)), 9, False) \
         == ("onehot", 1)
-    assert kernels.fused_plan(((4, 10), (3, 8), (6, 12)), 9, False)[0] \
-        == "packed"
+    assert kernels.fused_plan(((4, 10), (3, 8), (6, 32)), 9, False) \
+        == ("packed", 7)
     assert stacked._ONEPASS_KERNEL_MAX_CODES == kernels.ONEHOT_MAX_CODES
+
+
+def test_several_walks_only_at_the_full_block_width():
+    """Three fields of 60 rows, count-only: one row's 3,600 upper masks
+    and accumulators fit 29 MiB at one vreg a block, and 60 such walks
+    read 8.92 s on the chip where the scatter reads 1.91 (PR 40): the
+    packed body does not take what it cannot walk at full width."""
+    wide = ((6, 60),) * 3
+    assert kernels._packed_block_vregs(wide, 0, 1, 0, False) == 1
+    assert kernels._packed_passes(wide, 0, False)[0] == 0
+    assert kernels.fused_plan(wide, 0, False) == ("onehot", 1)
 
 
 def _operands(rng, digits, depth, s_dim=2, w_dim=1024):
@@ -254,22 +291,36 @@ def _operands(rng, digits, depth, s_dim=2, w_dim=1024):
             for a in (cp, valid, planes)]
 
 
+def _budget(monkeypatch, nbytes, block=None):
+    monkeypatch.setattr(kernels, "_PACKED_VMEM_BYTES", nbytes)
+    if block is not None:
+        monkeypatch.setattr(kernels, "_PACKED_BLOCK_VREGS", block)
+    kernels._packed_passes.cache_clear()
+
+
 @pytest.mark.parametrize("case", list(PASS_CASES))
 def test_passes_add_up_to_one_walk(rng, monkeypatch, case):
-    digits, depth, signed = PASS_CASES[case]
+    digits, depth, signed, budget, plan = PASS_CASES[case]
     cp, valid, planes = _operands(rng, digits, depth)
+    if case == "valid_zero_block":
+        valid = valid.at[0].set(0)
     n_codes = 1 << sum(b for b, _ in digits)
-    nv, fi, rp, n_pass = kernels._packed_passes(digits, depth, signed)
-    assert nv and fi == max(range(3), key=lambda i: digits[i][1])
-    assert n_pass > 1 and n_pass == -(-digits[fi][1] // rp)
-    assert kernels.fused_plan(digits, depth, signed) == ("packed", n_pass)
-    split = kernels.groupby_fused(cp, valid, planes, n_codes, signed,
-                                  digits=digits)
     scatter = kernels.groupby_codes_xla(cp, valid, planes, n_codes, signed)
-    # one walk: the same body with room for every group's accumulators
-    monkeypatch.setattr(kernels, "_PACKED_VMEM_BYTES", 1 << 34)
-    kernels._packed_passes.cache_clear()
+    # the walk itself is the same inside the one-hot body's code space,
+    # where fused_plan would not take several
+    monkeypatch.setattr(kernels, "ONEHOT_MAX_CODES", 0)
     try:
+        if budget is not None:
+            _budget(monkeypatch, *budget)
+        nv, fi, rp, n_pass = kernels._packed_passes(digits, depth, signed)
+        assert (nv, fi, rp, n_pass) == plan
+        assert fi == max(range(len(digits)), key=lambda i: digits[i][1])
+        assert n_pass > 1 and n_pass == -(-digits[fi][1] // rp)
+        assert kernels.fused_plan(digits, depth, signed) == ("packed", n_pass)
+        split = kernels.groupby_fused(cp, valid, planes, n_codes, signed,
+                                      digits=digits)
+        # one walk: the same body with room for every accumulator
+        _budget(monkeypatch, 1 << 34)
         assert kernels.fused_plan(digits, depth, signed) == ("packed", 1)
         whole = kernels.groupby_fused(cp, valid, planes, n_codes, signed,
                                       digits=digits)
@@ -309,16 +360,25 @@ def test_onepass_arm_past_the_code_bound(monkeypatch, case):
 
 
 def test_pass_plan_of_the_dashboards_groupbys():
-    """Q4 in 20 passes of 3 rows of dist_miles with 8-vreg blocks; with
-    the amount summed, 60 of one row; Q2 and Q3 in one walk."""
-    assert kernels._packed_passes(Q4_DIGITS, 0, False) == (8, 2, 3, 20)
-    assert kernels._packed_passes(Q4_DIGITS, 9, False) == (8, 2, 1, 60)
-    assert kernels._packed_passes(Q4_DIGITS[:2], 0, False)[1:] == (None, 0, 1)
-    assert kernels._packed_passes(Q4_DIGITS[:1], 9, False)[1:] == (None, 0, 1)
+    """Q4 in one walk of dist_miles' 60 rows against 80 parked upper
+    masks, blocks of 16 vregs; with the amount summed, 12 walks of 5
+    rows; Q2 and Q3 in one walk."""
+    assert kernels._packed_passes(Q4_DIGITS, 0, False) == (16, 2, 60, 1)
+    assert kernels._packed_passes(Q4_DIGITS, 9, False) == (16, 2, 5, 12)
+    assert kernels._packed_passes(Q4_DIGITS[:2], 0, False) == (16, 0, 10, 1)
+    assert kernels._packed_passes(Q4_DIGITS[:1], 9, False) == (16, 0, 10, 1)
+    assert kernels._inner_groups(80, 1) == (8, 10, 0)
+    assert kernels._inner_groups(80, 11) == (1, 80, 0)
+    assert kernels._inner_groups(11, 1) == (6, 1, 5)
+    # what the body counts of the VMEM it asks for stays inside it
+    assert kernels._PACKED_VMEM_BYTES < kernels._PACKED_VMEM_LIMIT
+    codes = kernels._pass_codes(Q4_DIGITS, 2, 60, 1)
+    assert codes.shape == (1, 4800) and (codes >= 0).all()
+    assert len(set(codes.ravel().tolist())) == 4800
     codes = kernels._pass_codes(Q4_DIGITS, 2, 3, 20)
     assert codes.shape == (20, 240) and (codes >= 0).all()
     assert len(set(codes.ravel().tolist())) == 4800
-    # 7 rows a pass do not divide 60: the last pass's dead slots are -1
+    # 7 rows a walk do not divide 60: the last walk's dead slots are -1
     codes = kernels._pass_codes(Q4_DIGITS, 2, 7, 9)
     assert (codes >= 0).sum() == 4800 and (codes[-1] < 0).sum() == 3 * 80
 

@@ -64,7 +64,9 @@ ABLE = ((3, 6), (1, 2), (3, 5))      # edu, gen, dom: (bits, rows)
 ABLE_REG = ABLE + ((2, 4),)           # the 240-group form
 TAXI_Q4 = ((4, 10), (3, 8), (6, 60))
 # scoped VMEM a v5e kernel may use unless it asks for more
-# (CompilerParams(vmem_limit_bytes=), which groupby_fused does not)
+# (CompilerParams(vmem_limit_bytes=): the packed body of groupby_fused
+# asks for kernels._PACKED_VMEM_LIMIT on every call, the other kernels
+# for nothing)
 V5E_SCOPED_VMEM = 16 << 20
 
 
@@ -139,43 +141,77 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 # the able query's forms through the packed body (ISSUE 32): age is an
-# unsigned 7-bit int.  "vhist_1024" is the largest value histogram
-# that takes it (a 9-bit int: every one of 1,024 codes live, so the
-# mask tree is at its widest and the block at one vreg)
+# unsigned 7-bit int.  "vhist_1024" is a 9-bit int's value histogram:
+# every one of 1,024 codes live, 32 upper masks x 32 rows
+# (kernels.dense_digits; one vreg a block before PR 40)
 PACKED = {
     "able_sum": dict(digits=ABLE, depth=7),
     "able_count": dict(digits=ABLE, depth=0),
     "able_reg_sum": dict(digits=ABLE_REG, depth=7),
-    "vhist_1024": dict(digits=((1, 2),) * 10, depth=0),
+    "vhist_1024": dict(digits=kernels.dense_digits(10), depth=0),
     # taxi-1b's Q4 (passenger_count x pickup_year x dist_miles: 8,192
-    # codes, 4,800 groups): 20 passes of 240 groups, and with the
-    # 9-bit amount summed 60 passes of 80
+    # codes, 4,800 groups): one walk of 60 rows against 80 upper
+    # masks, and with the 9-bit amount summed 12 walks of 5 rows
     "taxi_q4_count": dict(digits=TAXI_Q4, depth=0),
     "taxi_q4_sum": dict(digits=TAXI_Q4, depth=9),
 }
 
 
+def _scoped_vmem(call):
+    """Bytes of scoped VMEM a compiled Pallas call uses."""
+    import re
+    return [int(n) for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', call)]
+
+
 @pytest.mark.parametrize("name", list(PACKED))
 def test_packed_body_fits_v5e_vmem(one_chip, name):
-    """The packed body compiles for the chip and the scoped VMEM it
-    asks for (accumulators, mask scratch, operand blocks, Mosaic's
-    own) stays under what a kernel gets by default."""
-    import re
-    fn, shapes = _fused(0, signed=False, **PACKED[name])
+    """The packed body compiles for the chip, every block 16 vregs
+    wide, and the scoped VMEM it uses (accumulators, upper masks,
+    operand blocks, Mosaic's own) stays under the limit it asks for
+    and within a MiB of what the body counted."""
+    case = PACKED[name]
+    fn, shapes = _fused(0, signed=False, **case)
     args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip)
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = _kernel_calls(text)
-    _body, passes = kernels.fused_plan(PACKED[name]["digits"],
-                                       PACKED[name]["depth"], False)
-    assert (passes > 1) == name.startswith("taxi_q4")
+    nv, _fi, _rp, passes = kernels._packed_passes(
+        case["digits"], case["depth"], False)
+    assert nv == 16
+    assert kernels.fused_plan(case["digits"], case["depth"], False) \
+        == ("packed", passes)
+    assert (passes > 1) == (name == "taxi_q4_sum")
     assert len(calls) == 1 and ("groupby_fused_passes" if passes > 1
                                 else "groupby_fused_sum") in calls[0]
-    asked = [int(n) for n in re.findall(
-        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
-        r'"offset":"0","size":"(\d+)"', calls[0])]
-    assert asked and 0 < asked[0] < V5E_SCOPED_VMEM, asked
+    asked = _scoped_vmem(calls[0])
+    assert asked and 0 < asked[0] < kernels._PACKED_VMEM_LIMIT, asked
     assert asked[0] <= kernels._PACKED_VMEM_BYTES + (1 << 20), asked
+    if name == "taxi_q4_count":
+        # one walk of 4,800 accumulators is what the raised limit is
+        # for: more than a kernel gets by default
+        assert asked[0] > V5E_SCOPED_VMEM
+
+
+def test_q4_kernel_traces_and_lowers_in_a_second(one_chip):
+    """Trace + lowering of taxi-1b's Q4 histogram (4,800 groups, 263
+    shards) stays under 2 s here — alone it reads 0.25-0.45 s; the
+    compiler's own time is not in it.  No loop over rows or groups
+    is unrolled: PR 32's unrolled body cost a served program 10 s of
+    Pallas lowering at its first asking."""
+    import time
+    fn, _ = _fused(0, signed=False, digits=TAXI_Q4, depth=0)
+    args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip)
+            for s in ((263, 13, W), (263, W))]
+    took = []
+    for _ in range(2):
+        # a new function object each time: nothing cached is reused
+        t0 = time.perf_counter()
+        lowered = jax.jit(lambda *a: fn(*a)).lower(*args)
+        took.append(time.perf_counter() - t0)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert min(took) < 2.0, took
 
 
 @pytest.mark.parametrize("case", ["fused", "xla", "fused_passes"])
@@ -329,18 +365,18 @@ SERVED_TAXI = {
                   "groupby_fused_sum"),
     "q3_year": (_T + ", filter=Row(pickup_month=3))", "groupby_fused_sum"),
     "q4_dist": (_T + ", Rows(dist_miles), filter=Row(pickup_month=3))",
-                "groupby_fused_passes"),
+                "groupby_fused_sum"),
     "q4_dist_range": (_T + ", Rows(dist_miles), filter=Intersect("
                       "Row(pickup_month=3), Row(total_amount_dollars > 9)))",
-                      "groupby_fused_passes"),
+                      "groupby_fused_sum"),
 }
 
 
 @pytest.mark.parametrize("template", list(SERVED_TAXI))
 def test_served_taxi_groupby_compiles_for_v5e(served, template):
     """taxi-1b's GroupBys as a lone caller sends them: one ragged
-    program each with exactly one kernel — Q4's 8,192 codes under the
-    passes' own name."""
+    program each with exactly one kernel — Q4's 8,192 codes in one
+    walk since PR 40, so under the one-walk name."""
     import re
     pql, kernel = SERVED_TAXI[template]
     texts = served(pql, index="taxi")
