@@ -202,8 +202,9 @@ class RaggedProgram:
                     continue
                 # per-page decode-to-dense boundary: the program
                 # concatenates homogeneous dense pages, so container-
-                # encoded pages (memory/encode.py) expand here — page
-                # identity and lane order unchanged
+                # encoded pages (memory/encode.py) are made dense here,
+                # from the block a resident page kept or by an
+                # expansion — page identity and lane order unchanged
                 lmap[i] = ("v", len(self.vleaves))
                 self.vleaves.append(
                     ((leaf.page_lanes, leaf.width_words),

@@ -141,7 +141,9 @@ class PageView:
     sparse device format carry a MIX of dense arrays and
     memory/encode.py EncodedPage payloads — consumers with no packed
     arm take ``dense_pages()`` (the per-page decode-to-dense
-    boundary, bit-exact by construction).
+    boundary, bit-exact by construction; a resident encoded page
+    keeps the block its first expansion made and hands it out
+    again).
 
     Under the serving mesh (memory/placement.py) the view also
     carries the entry's device layout: ``page_device[pi]`` the page's
@@ -175,7 +177,7 @@ class PageView:
 
     def dense_pages(self) -> list:
         """Every page as a dense (page_lanes, W) block (encoded pages
-        gather-expand; dense pages pass through)."""
+        give their kept block or expand; dense pages pass through)."""
         return [encode.to_dense(p) for p in self.pages]
 
 
@@ -221,6 +223,23 @@ def _same_lane_device(a, b) -> bool:
     if a is None or b is None:
         return a is None and b is None
     return np.array_equal(a, b)
+
+
+# set while a kept block's reservation runs on this thread: the stack
+# caches' reclaim then sheds kept blocks only, so keeping one never
+# costs a resident page
+_KEEP_TLS = threading.local()
+
+
+def _unseat(page) -> int:
+    """A page leaving its slot (evicted, dropped or replaced): its
+    resident bytes, a kept block included, which an encoded page then
+    forgets together with its keeper.  Under the cache's lock."""
+    nb = encode.page_nbytes(page)
+    if encode.is_encoded(page):
+        page.kept = None
+        page.keeper = None
+    return nb
 
 
 def _page_mix(pages) -> dict:
@@ -798,6 +817,7 @@ class TileStackCache:
             return False
         with self._lock:
             ps.pages[pi] = arr
+            self._seat_locked(key, pi, arr)
             ps.last_access = time.time()
             self._sync_entry_locked(key, ps)
         metrics.STACK_PAGES.inc(event="build",
@@ -888,9 +908,10 @@ class TileStackCache:
         with self._lock:
             was = ps.pages[pi]
             if was is not None:
-                nb_old = encode.page_nbytes(was)
+                nb_old = _unseat(was)
                 if nb_old == nb_new:
                     ps.pages[pi] = arr
+                    self._seat_locked(key, pi, arr)
                     ps.last_access = time.time()
                     return
                 ps.pages[pi] = None
@@ -899,6 +920,53 @@ class TileStackCache:
         if release:
             self._client.release(release, device=ps.device_of(pi))
         self._page_install(key, ps, pi, arr)
+
+    def _seat_locked(self, key, pi: int, page):
+        """A page taking slot `pi` of `key`'s stack: an encoded one
+        may keep the dense block its next expansion makes."""
+        if encode.is_encoded(page):
+            page.keeper = functools.partial(self._keep_dense, key, pi)
+
+    def _keep_dense(self, key, pi: int, page, block) -> bool:
+        """Keep `block`, the expansion of the encoded page in slot `pi`
+        of `key`'s stack, beside the page's payload while the page is
+        resident.  It is charged to this client on the page's device,
+        and never at the cost of a resident page (the reservation's
+        reclaim sheds kept blocks only).  False: the block serves this
+        access alone."""
+        nb = int(block.nbytes)
+        with self._lock:
+            ps = self._seated_locked(key, pi, page)
+        if ps is None:
+            return False
+        slot = ps.device_of(pi)
+        _KEEP_TLS.on = True
+        try:
+            if not self._client.reserve(nb, trigger="keep", device=slot):
+                return False
+        finally:
+            _KEEP_TLS.on = False
+        with self._lock:
+            kept = self._seated_locked(key, pi, page) is ps
+            if kept:
+                page.kept = block
+                self._sync_entry_locked(key, ps)
+                shed, shed_map = self._enforce_local_cap_locked()
+        if not kept:
+            self._client.release(nb, device=slot)
+        elif shed:
+            self._release_freed(shed, shed_map)
+        return kept
+
+    def _seated_locked(self, key, pi: int, page):
+        """`key`'s stack where `page` still holds its slot `pi` with no
+        block kept, else None."""
+        ent = self._entries.get(key)
+        ps = None if ent is None else ent[1]
+        if (isinstance(ps, PagedStack) and pi < ps.n_pages
+                and ps.pages[pi] is page and page.kept is None):
+            return ps
+        return None
 
     def _assemble(self, ps: PagedStack, arrs: list):
         ps.touch()
@@ -947,11 +1015,14 @@ class TileStackCache:
             return 0, {}
         return self._shed_locked(self._bytes - self.max_bytes)
 
-    def _shed_locked(self, need: int):
+    def _shed_locked(self, need: int, pages: bool = True):
         """Evict ~need bytes, ENTRY-concentrated: order entries by
         cost-aware score (memory/policy.py — age / rebuild-weight /
-        frequency), then drain the victim's pages coldest-first,
-        stopping mid-entry the moment enough is freed.  Concentration
+        frequency).  Kept dense blocks go first, in that order (one
+        device program remakes a block, where a page costs a host
+        rebuild); then, where ``pages``, drain the victim's pages
+        coldest-first, stopping mid-entry the moment enough is
+        freed.  Concentration
         keeps sibling operands complete (spreading page evictions
         across entries would break every operand at once — measured
         pathological); the page-granular STOP is the paged win: the
@@ -974,8 +1045,30 @@ class TileStackCache:
                               payload.hits, ("paged", k, payload)))
             elif ent[2]:
                 cands.append((ent[3], 1.0, 1, ("whole", k, None)))
-        for _la, _w, _h, (kind, k, ps) in policy.victim_order(cands,
-                                                             now):
+        order = policy.victim_order(cands, now)
+        for _la, _w, _h, (kind, k, ps) in order:
+            if freed >= need:
+                break
+            if kind == "whole":
+                continue
+            dropped = False
+            for pi, p in enumerate(ps.pages):
+                if freed >= need:
+                    break
+                if not encode.is_encoded(p) or p.kept is None:
+                    continue
+                nb_k = int(p.kept.nbytes)
+                p.kept = None
+                dropped = True
+                freed += nb_k
+                slot = ps.device_of(pi)
+                if slot is not None:
+                    dev_map[slot] = dev_map.get(slot, 0) + nb_k
+            if dropped:
+                self._sync_entry_locked(k, ps)
+        if not pages:
+            return freed, dev_map
+        for _la, _w, _h, (kind, k, ps) in order:
             if freed >= need:
                 break
             if kind == "whole":
@@ -990,7 +1083,7 @@ class TileStackCache:
                 if p is None:
                     continue
                 ps.pages[pi] = None
-                nb_p = encode.page_nbytes(p)
+                nb_p = _unseat(p)
                 freed += nb_p
                 slot = ps.device_of(pi)
                 if slot is not None:
@@ -1007,9 +1100,11 @@ class TileStackCache:
         return freed, dev_map
 
     def _reclaim(self, need: int) -> int:
-        """Ledger reclaim callback (cross-client pressure)."""
+        """Ledger reclaim callback (cross-client pressure); while a
+        kept block is being reserved, it sheds kept blocks only."""
+        pages = not getattr(_KEEP_TLS, "on", False)
         with self._lock:
-            freed, dev_map = self._shed_locked(int(need))
+            freed, dev_map = self._shed_locked(int(need), pages=pages)
         if freed:
             self._release_freed(freed, dev_map)
         return freed
@@ -1045,7 +1140,7 @@ class TileStackCache:
             for pi, p in enumerate(ps.pages):
                 if p is not None:
                     ps.pages[pi] = None
-                    nb_p = encode.page_nbytes(p)
+                    nb_p = _unseat(p)
                     freed += nb_p
                     slot = ps.device_of(pi)
                     if slot is not None:
@@ -2860,7 +2955,7 @@ class StackedEngine:
         total = 0
         enc_bytes = 0
         for p in leaf.pages:
-            enc_bytes += encode.page_nbytes(p)
+            enc_bytes += encode.payload_nbytes(p)
             if encode.is_encoded(p):
                 total += p.bit_count()
             else:
@@ -2883,7 +2978,7 @@ class StackedEngine:
         for p in leaf.pages:
             if not (encode.is_encoded(p) and p.kind == "packed"):
                 return None
-            nbytes += p.nbytes
+            nbytes += p.payload_nbytes
             pos = p.positions()
             parts.append(pos if off == 0 else pos + off)
             bits = p.page_lanes * p.width_words * 32
@@ -3150,7 +3245,7 @@ class StackedEngine:
         parts = []
         enc_bytes = 0
         for p in view.pages:
-            enc_bytes += encode.page_nbytes(p)
+            enc_bytes += encode.payload_nbytes(p)
             if encode.is_encoded(p):
                 parts.append(np.asarray(p.lane_counts,
                                         dtype=np.int64))

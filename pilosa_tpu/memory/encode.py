@@ -25,6 +25,13 @@ resident *byte size* changes — the TileStackCache accounts encoded
 pages at their true size, which is exactly the working-set
 multiplier the sparse format exists to buy.
 
+While the cache holds a page, the dense block its first expansion
+made is kept beside the payload (``EncodedPage.kept``): every later
+:func:`to_dense` of the page returns that block and runs no program.
+The kept block counts in the page's resident bytes and leaves with
+the page; the payload, ``lane_counts`` and ``host_positions`` stay as
+they are, so the packed arms read the page as before.
+
 Decision rule (per page block, from host words — no stats required):
 the cheapest sparse candidate must undercut the dense page by
 ``1/dense_frac`` (default: sparse must be <= 0.5x dense bytes) to
@@ -49,6 +56,8 @@ from __future__ import annotations
 import os
 
 import numpy as np
+
+from pilosa_tpu.obs import flight, metrics
 
 _FULL = np.uint32(0xFFFFFFFF)
 
@@ -130,11 +139,17 @@ class EncodedPage:
     allocate stays host-resident, like a dense host-fallback block).
     ``lane_counts`` stays on the host: it is the per-lane popcount
     computed for free at encode time, serving the engine's packed
-    Count/TopN arms without touching the device at all."""
+    Count/TopN arms without touching the device at all.
+
+    ``keeper`` is set by the stack cache while the page is resident:
+    ``keeper(page, block) -> bool`` offers it the block an expansion
+    made, and where the cache admits it the block becomes ``kept``.
+    The cache clears both when the page leaves its slot."""
 
     __slots__ = ("kind", "page_lanes", "width_words", "coords",
                  "run_starts", "run_lens", "lane_counts", "n_valid",
-                 "n_runs", "host_positions", "_nbytes")
+                 "n_runs", "host_positions", "_nbytes", "kept",
+                 "keeper")
 
     def __init__(self, kind: str, page_lanes: int, width_words: int,
                  coords, run_starts, run_lens,
@@ -154,6 +169,8 @@ class EncodedPage:
         # fetching coords back from the device
         self.host_positions: np.ndarray | None = None
         self._nbytes: int | None = None
+        self.kept = None
+        self.keeper = None
 
     def positions(self) -> "np.ndarray | None":
         """Sorted unique flat set-bit offsets, host int64 (packed
@@ -166,11 +183,11 @@ class EncodedPage:
         return self.host_positions
 
     @property
-    def nbytes(self) -> int:
-        """True resident bytes (what the HBM ledger accounts).
-        Payload sizes are fixed at construction (``to_device`` moves
-        the arrays but never resizes), so the walk over (possibly
-        device) array properties runs once."""
+    def payload_nbytes(self) -> int:
+        """Bytes of the encoded payload alone (what a packed arm
+        reads).  Payload sizes are fixed at construction
+        (``to_device`` moves the arrays but never resizes), so the
+        walk over (possibly device) array properties runs once."""
         if self._nbytes is None:
             n = int(self.coords.nbytes)
             if self.run_starts is not None:
@@ -178,6 +195,15 @@ class EncodedPage:
                 n += int(self.run_lens.nbytes)
             self._nbytes = n
         return self._nbytes
+
+    @property
+    def nbytes(self) -> int:
+        """True resident bytes (what the HBM ledger accounts): the
+        payload, plus the dense block kept beside it while there is
+        one."""
+        kept = self.kept
+        return self.payload_nbytes + (0 if kept is None
+                                      else int(kept.nbytes))
 
     @property
     def shape(self) -> tuple:
@@ -215,6 +241,26 @@ class EncodedPage:
                               self.coords, self.page_lanes,
                               self.width_words)
 
+    def dense(self):
+        """The page as a dense block for a consumer with no packed arm:
+        the block kept from an earlier expansion, else a fresh
+        expansion, offered to the cache that holds the page."""
+        block = self.kept
+        if block is not None:
+            _note_expand("kept")
+            return block
+        block = self.expand()
+        _note_expand("expanded")
+        keeper = self.keeper
+        if keeper is not None:
+            keeper(self, block)
+        return block
+
+
+def _note_expand(outcome: str) -> None:
+    metrics.STACK_EXPAND.inc(outcome=outcome)
+    flight.note_expand(outcome)
+
 
 _EXPAND_WARM: set = set()
 
@@ -248,14 +294,23 @@ def page_kind(page) -> str:
 
 
 def page_nbytes(page) -> int:
-    """True byte size of any page payload (dense array or encoded)."""
+    """True resident byte size of any page (dense array or encoded,
+    an encoded page's kept block included)."""
     return int(page.nbytes)
 
 
+def payload_nbytes(page) -> int:
+    """Bytes of a page's own payload: an encoded page's without the
+    dense block kept beside it."""
+    return int(page.payload_nbytes if isinstance(page, EncodedPage)
+               else page.nbytes)
+
+
 def to_dense(page):
-    """Decode-to-dense boundary: expand an encoded page, pass a dense
-    one through untouched."""
-    return page.expand() if isinstance(page, EncodedPage) else page
+    """Decode-to-dense boundary: an encoded page's dense block (kept
+    or freshly expanded, :meth:`EncodedPage.dense`), a dense one
+    passed through untouched."""
+    return page.dense() if isinstance(page, EncodedPage) else page
 
 
 def encode_block(block: np.ndarray, prev_kind: str | None = None,

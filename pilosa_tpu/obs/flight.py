@@ -107,8 +107,8 @@ class Acc:
     request)."""
 
     __slots__ = ("phases", "stack", "bytes_moved", "keys", "attempts",
-                 "t0", "node_spans", "ops", "pages", "spans", "cur",
-                 "depth", "root_s", "assembled_bytes", "compiles")
+                 "t0", "node_spans", "ops", "pages", "expands", "spans",
+                 "cur", "depth", "root_s", "assembled_bytes", "compiles")
 
     # per-record stack-key cap: a pathological query touching hundreds
     # of stacks must not bloat the ring
@@ -155,6 +155,9 @@ class Acc:
         # (encoding -> page count; memory/encode.py container kinds) —
         # how a record shows which arm served it, packed or dense
         self.pages: dict[str, int] = {}
+        # encoded pages handed out dense: "kept" (the block kept from
+        # an earlier expansion) or "expanded" (memory/encode.py)
+        self.expands: dict[str, int] = {}
         # bytes of the page leaves the ragged programs that served
         # this query were handed (executor/ragged.py)
         self.assembled_bytes = 0
@@ -233,6 +236,9 @@ class Acc:
     def add_pages(self, mix: dict):
         for k, v in mix.items():
             self.pages[k] = self.pages.get(k, 0) + int(v)
+
+    def add_expand(self, outcome: str):
+        self.expands[outcome] = self.expands.get(outcome, 0) + 1
 
     def add_phase(self, name: str, dt: float):
         """Seconds for `name` with no stage open over them (hand-timed
@@ -328,6 +334,8 @@ class Acc:
                 st[0] += b
                 st[1] += s
         self.add_pages(other.pages)
+        for k, v in other.expands.items():
+            self.expands[k] = self.expands.get(k, 0) + v
         self.assembled_bytes += other.assembled_bytes
         room = self._MAX_COMPILES - len(self.compiles)
         if room > 0 and other.compiles:
@@ -387,6 +395,10 @@ class _Fan:
     def add_pages(self, mix):
         for a in self.accs:
             a.add_pages(mix)
+
+    def add_expand(self, outcome):
+        for a in self.accs:
+            a.add_expand(outcome)
 
     def add_op(self, op, nbytes, dt):
         for a in self.accs:
@@ -619,6 +631,14 @@ def note_pages(mix: dict):
     acc = getattr(_tls, "acc", None)
     if acc is not None:
         acc.add_pages(mix)
+
+
+def note_expand(outcome: str):
+    """Record one encoded page handed out dense, kept or expanded
+    (memory/encode.py EncodedPage.dense), into the active record."""
+    acc = getattr(_tls, "acc", None)
+    if acc is not None:
+        acc.add_expand(outcome)
 
 
 def note_op(op: str, nbytes: int, dt: float):
@@ -923,6 +943,9 @@ def commit(rec: dict | None, duration_s: float, route: str = "solo",
         # page-encoding mix of the stack operands touched (sparse
         # device format, memory/encode.py): packed vs dense served
         rec["page_mix"] = dict(acc.pages)
+    if acc.expands:
+        # how its encoded pages were made dense: kept vs expanded
+        rec["expand"] = dict(acc.expands)
     if acc.assembled_bytes:
         rec["assembled_bytes"] = acc.assembled_bytes
     if acc.compiles:

@@ -423,6 +423,10 @@ STACK_REBUILD_SECONDS = registry.counter(
 STACK_REBUILD_TIMED = registry.counter(
     "pilosa_stack_rebuild_timed_total",
     "Stack accesses timed into pilosa_stack_rebuild_seconds_total")
+STACK_EXPAND = registry.counter(
+    "pilosa_stack_expand_total",
+    "Encoded pages handed out dense, by outcome (kept: the block kept "
+    "from an earlier expansion; expanded: a fresh expansion)")
 
 # -- HBM residency (memory/: budget ledger, paged stacks, OOM backstop) --
 MEM_BUDGET = registry.gauge(
