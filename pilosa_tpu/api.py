@@ -252,6 +252,7 @@ class API:
             for r in results:
                 if isinstance(r, list) and r and isinstance(r[0], GroupCount):
                     metrics.GROUPBY_REPLY_GROUPS.inc(len(r))
+                    flight.note_reply_groups(len(r))
         if profile and tracer.roots:
             resp["profile"] = [s.to_dict() for s in tracer.roots]
         self._record_history(index, pql, t0, tracer)

@@ -37,7 +37,7 @@ from pilosa_tpu.executor.results import (
 from pilosa_tpu.models.field import FALSE_ROW, TRUE_ROW
 from pilosa_tpu.models.schema import FieldType
 from pilosa_tpu.models.view import VIEW_STANDARD
-from pilosa_tpu.executor.stacked import Unstackable
+from pilosa_tpu.executor.stacked import Unstackable, product_combos
 from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.ops import bsi as bsi_ops
 from pilosa_tpu.pql.ast import Call, Condition
@@ -294,13 +294,16 @@ class AdvancedOps:
                                   else None)
 
         # combo enumeration: the full cartesian product as one (C, nf)
-        # index matrix in product order — the same matrix maps 1:1
-        # onto the one-pass engine's dense group-code space (each
-        # column is a digit, stacked.py/_combo_codes composes the
-        # power-of-two strides), so no per-combo Python exists on any
-        # path between here and the histogram gather.
-        combos = np.indices([len(rl) for rl in row_lists]) \
-            .reshape(len(row_lists), -1).T.astype(np.int64)
+        # index matrix in product order (stacked.product_combos: the
+        # same matrix maps 1:1 onto the one-pass engine's dense
+        # group-code space), so no per-combo Python exists on any path
+        # between here and the histogram gather.  Unpaged, the stacked
+        # engine enumerates it itself, where it enumerates at all: a
+        # filtered GroupBy may walk only the rows the filter leaves
+        # (stacked._groupby_pruned), and its full product is never
+        # formed.
+        lens = [len(rl) for rl in row_lists]
+        combos = None
         shard_list = self._shard_list(idx, shards)
 
         # previous= paging (executor.go:8617 groupByIterator seek):
@@ -325,6 +328,7 @@ class AdvancedOps:
                         raise self._err(f"previous= key not found: {p!r}")
                     p = found[p]
                 prev_ids.append(int(p))
+            combos = product_combos(lens)
             gt = np.zeros(len(combos), dtype=bool)
             eq = np.ones(len(combos), dtype=bool)
             for fi, (rl, pv) in enumerate(zip(row_lists, prev_ids)):
@@ -338,7 +342,7 @@ class AdvancedOps:
         counts = agg_nn = agg_pos = agg_neg = agg_vals = None
         if getattr(self, "use_stacked", False) and distinct_field is None:
             try:
-                counts, agg = self.stacked.groupby(
+                combos, counts, agg = self.stacked.groupby(
                     idx, list(zip(fields, row_lists)), filter_call,
                     agg_field, shard_list, pre, combos, agg_op=agg_op)
                 if agg is not None and agg_op in ("min", "max"):
@@ -347,6 +351,8 @@ class AdvancedOps:
                     agg_nn, agg_pos, agg_neg = agg
             except Unstackable:
                 counts = None
+        if combos is None:
+            combos = product_combos(lens)
         if counts is None:
             if agg_op in ("min", "max"):
                 counts, agg_nn, agg_vals = self._groupby_minmax_loop(

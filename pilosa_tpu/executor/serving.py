@@ -1331,6 +1331,9 @@ class ServingLayer:
             _count_onepass,
             _onepass_plan,
             _onepass_unpack,
+            _prunes,
+            _walked,
+            product_combos,
         )
 
         ex = self.executor
@@ -1355,8 +1358,11 @@ class ServingLayer:
                      if agg_call is not None else None)
         depth = agg_field.bit_depth if agg_field is not None else 0
         fields_rows = list(zip(fields, row_lists))
-        combos = np.indices([len(rl) for rl in row_lists]) \
-            .reshape(len(row_lists), -1).T.astype(np.int64)
+        if call.arg("filter") is not None and _prunes(fields_rows, depth,
+                                                      eng._selects()):
+            # the prepass must run before the walk's shape is known
+            raise Unstackable("a pruned GroupBy runs solo")
+        combos = product_combos([len(rl) for rl in row_lists])
         skey = tuple(shards)
         if not eng._groupby_onepass_ok(
                 idx, fields_rows, len(combos), depth,
@@ -1392,7 +1398,8 @@ class ServingLayer:
         cg_i = b._groupcode_leaf(fields_rows)
         planes_i = (b._planes_leaf(agg_field)
                     if agg_field is not None else None)
-        _count_onepass((arm, body, passes), "batched")
+        _count_onepass((arm, body, passes), "batched", depth,
+                       _walked(arm, body, digits, n_codes))
         has_planes = agg_field is not None
 
         def demux_groupby(out):

@@ -931,9 +931,11 @@ class TileStackCache:
         """Keep `block`, the expansion of the encoded page in slot `pi`
         of `key`'s stack, beside the page's payload while the page is
         resident.  It is charged to this client on the page's device,
-        and never at the cost of a resident page (the reservation's
-        reclaim sheds kept blocks only).  False: the block serves this
-        access alone."""
+        as spare bytes: never at the cost of a resident page or of
+        another client's bytes (the reservation's reclaim sheds this
+        cache's kept blocks only), and only while the device keeps
+        room for its programs.  False: the block serves this access
+        alone."""
         nb = int(block.nbytes)
         with self._lock:
             ps = self._seated_locked(key, pi, page)
@@ -942,7 +944,8 @@ class TileStackCache:
         slot = ps.device_of(pi)
         _KEEP_TLS.on = True
         try:
-            if not self._client.reserve(nb, trigger="keep", device=slot):
+            if not self._client.reserve(nb, trigger="keep", device=slot,
+                                        spare=True):
                 return False
         finally:
             _KEEP_TLS.on = False
@@ -1348,10 +1351,23 @@ def _gb_jit_put(key, fn):
 # 2^sum(ceil(log2 R_f)) — the host/XLA histogram tolerates up to 2^20
 # codes (a few MB of accumulator), the Pallas kernel's one-hot lane
 # axis and unrolled payload stay within VMEM/compile budgets below
-# 4096 codes x depth 16
+# 4096 codes x depth 16; the packed body has neither bound (its
+# accumulators are planned in walks, kernels._packed_passes)
 _ONEPASS_MAX_CODES = 1 << 20
 _ONEPASS_KERNEL_MAX_CODES = kernels.ONEHOT_MAX_CODES
 _ONEPASS_KERNEL_MAX_DEPTH = 16
+# a filtered one-pass Sum or count that the kernel walks over a
+# selection first counts each field's rows under the filter
+# (_groupby_pruned) where its walk is at least _PRUNE_MIN_UNITS (group,
+# payload row) units: a prepass then costs at most a tenth of the walk
+# it may shorten where the filter prunes nothing.  Measured on a v5e
+# chip (ssb-sf100 at 144 shards, 32 vregs of words a shard): the
+# prepass 6.46 ms at its median, 1.4 us a vreg; the packed walk 1.0 ns
+# a unit a vreg.  A prepass is worth _PRUNE_UNITS units, at any shard
+# count.  taxi-1b's Q4 (4,800 groups, count-only) and able-1b's 60
+# groups (a 7-bit Sum, 540 units) walk their whole product
+_PRUNE_UNITS = 1400
+_PRUNE_MIN_UNITS = 10 * _PRUNE_UNITS
 
 
 def _code_space(fields_rows):
@@ -1434,7 +1450,8 @@ def _onepass_arm(n_codes: int, depth: int, mesh_minmax: bool = False,
       the scatter shards under GSPMD)
 
     _forced_arm() stands in for the backend and lifts no bound."""
-    if mesh_minmax or depth > _ONEPASS_KERNEL_MAX_DEPTH:
+    if mesh_minmax or (depth > _ONEPASS_KERNEL_MAX_DEPTH
+                       and body != "packed"):
         return "xla"
     if n_codes > _ONEPASS_KERNEL_MAX_CODES and body != "packed":
         return "xla"
@@ -1451,22 +1468,36 @@ def _onepass_plan(n_codes: int, depth: int, digits, signed: bool,
     return _onepass_arm(n_codes, depth, mesh_minmax, body), body, passes
 
 
-def _count_onepass(plan, path: str) -> None:
+def _count_onepass(plan, path: str, depth: int, walked: int) -> None:
     """One one-pass dispatch in the counters, where its arm is known:
-    pilosa_groupby_onepass_total{arm} always, and for the kernel the
-    body its shapes take and the walks it makes over its operands.
-    `plan` is _onepass_plan's; the host histogram counts as
-    ("host", None, 0)."""
+    pilosa_groupby_onepass_total{arm}, the groups it accumulates
+    (`walked`, into the flight record too) and its payload's depth
+    (0 without an aggregate) always, and for the kernel the body its
+    shapes take and the walks it makes over its operands.  `plan` is
+    _onepass_plan's; the host histogram counts as ("host", None, 0)."""
     from pilosa_tpu.obs.metrics import (
         GROUPBY_FUSED,
         GROUPBY_ONEPASS,
         GROUPBY_PASSES,
+        GROUPBY_PAYLOAD,
+        GROUPBY_WALKED,
     )
     arm, body, passes = plan
     GROUPBY_ONEPASS.inc(arm=arm)
+    GROUPBY_WALKED.inc(walked)
+    GROUPBY_PAYLOAD.inc(arm=arm, depth="gt16" if depth > 16 else "le16")
+    flight.note_walked(walked)
     if arm == "fused":
         GROUPBY_FUSED.inc(path=path, body=body)
         GROUPBY_PASSES.inc(passes)
+
+
+def _walked(arm: str, body, digits, n_codes: int) -> int:
+    """Groups a one-pass dispatch accumulates: the packed body visits
+    the product of the fields' rows, the other arms the code space."""
+    if arm == "fused" and body == "packed":
+        return int(np.prod([rows for _b, rows in digits], dtype=np.int64))
+    return n_codes
 
 
 def _onepass_gb(arm: str, digits=None):
@@ -1518,6 +1549,107 @@ def _groupby_onepass_jit(arm: str, has_planes: bool,
             return jnp.concatenate(
                 [c, n, p.ravel(), g.ravel(), mm.ravel()])
         c, n, p, g = gb(cp, valid, planes, n_codes, signed)
+        if not has_planes:
+            return c
+        return jnp.concatenate([c, n, p.ravel(), g.ravel()])
+
+    fn = jax.jit(run)
+    _gb_jit_put(key, fn)
+    return fn
+
+
+def product_combos(lens) -> np.ndarray:
+    """Every combination of the fields' row positions, (C, nf), in
+    product order (first field slowest): the matrix that maps 1:1 onto
+    the dense group-code space (_combo_codes)."""
+    return np.indices(lens).reshape(len(lens), -1).T.astype(np.int64)
+
+
+def _prunes(fields_rows, depth: int, selects: bool) -> bool:
+    """Whether a filtered one-pass GroupBy over these fields first
+    drops the rows the filter leaves no column in (_groupby_pruned):
+    where its dense code space is past _ONEPASS_MAX_CODES (it cannot be
+    formed at all), or where the kernel walks a selection of the cached
+    group-code stack (`selects`, StackedEngine._selects) and the walk
+    is at least _PRUNE_MIN_UNITS.  The other arms would follow the
+    prepass with a group-code stack of the rows kept, a new one for
+    each filter: inside the bound they walk the whole code space."""
+    _bits, _shifts, n_codes = _code_space(fields_rows)
+    if n_codes > _ONEPASS_MAX_CODES:
+        return True
+    n_combos = int(np.prod([len(rl) for _f, rl in fields_rows],
+                           dtype=np.int64))
+    return selects and (n_combos * kernels._payload_rows(depth, False)
+                        >= _PRUNE_MIN_UNITS)
+
+
+def _pack_bits(mask: np.ndarray) -> np.ndarray:
+    """A column mask (bool, one a column) as packed uint32 words."""
+    return np.packbits(mask, bitorder="little").view(np.uint32)
+
+
+def _bucket(n: int) -> int:
+    """Rows a pruned field is walked with: its live rows rounded up to
+    a power of two, so that the programs are a small family."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _groupby_marginals_jit(arm: str, digits, has_filter: bool):
+    """The pruning prepass: per field, the count of each of its rows
+    under `valid` (and the filter), read off the group-code stack's
+    digit planes as a one-field histogram — two-level in the packed
+    body (kernels.dense_digits).  One flat array out, the fields'
+    rows in order."""
+    key = ("marginals", arm, digits, has_filter)
+    fn = _gb_jit_get(key)
+    if fn is not None:
+        return fn
+
+    @bm.named("groupby_prune")
+    def run(cg, filt):
+        valid = cg[:, -1]
+        if has_filter:
+            valid = jnp.bitwise_and(valid, filt)
+        out, at = [], 0
+        for bits, rows in digits:
+            if not bits:        # a one-row field: every valid column
+                out.append(jnp.sum(bm.count(valid)).astype(jnp.int32)[None])
+            else:
+                planes = cg[:, at:at + bits]
+                if arm == "fused":
+                    c = kernels.groupby_fused(
+                        planes, valid, None, 1 << bits, False,
+                        digits=kernels.dense_digits(bits))[0]
+                else:
+                    c = kernels.groupby_codes_xla(planes, valid, None,
+                                                  1 << bits, False)[0]
+                out.append(c[:rows])
+            at += bits
+        return jnp.concatenate(out)
+
+    fn = jax.jit(run)
+    _gb_jit_put(key, fn)
+    return fn
+
+
+def _groupby_selected_jit(has_planes: bool, has_filter: bool,
+                          signed: bool, digits):
+    """The packed kernel over selected rows of the group-code stack
+    (kernels.groupby_fused's `sel`): one flat histogram over the
+    selection's codes out, as _groupby_onepass_jit's."""
+    key = ("selected", has_planes, has_filter, signed, digits)
+    fn = _gb_jit_get(key)
+    if fn is not None:
+        return fn
+    n_codes = int(np.prod([rows for _b, rows in digits], dtype=np.int64))
+
+    @bm.named("groupby_onepass")
+    def run(cg, filt, planes, sel):
+        cp, valid = cg[:, :-1], cg[:, -1]
+        if has_filter:
+            valid = jnp.bitwise_and(valid, filt)
+        c, n, p, g = kernels.groupby_fused(cp, valid, planes, n_codes,
+                                           signed, digits=digits, sel=sel)
         if not has_planes:
             return c
         return jnp.concatenate([c, n, p.ravel(), g.ravel()])
@@ -3366,8 +3498,19 @@ class StackedEngine:
             for (f, rl), frags, sh in zip(fields_rows, per_field,
                                           shifts):
                 union = np.zeros((len(shards), w), np.uint32)
+                nb = bm.digit_bits(len(rl))
                 for si, fr in enumerate(frags):
                     if fr is None:
+                        continue
+                    pos = fr.row_positions(rl)
+                    if pos is not None:
+                        # a code-held fragment: every digit plane from
+                        # one lookup a column, not a row at a time
+                        has = pos >= 0
+                        union[si] = _pack_bits(has)
+                        for b in range(nb):
+                            out[si, sh + b] = _pack_bits(
+                                has & ((pos >> b) & 1 == 1))
                         continue
                     for di, r in enumerate(rl):
                         wds = fr.row_words(int(r))
@@ -3505,16 +3648,18 @@ class StackedEngine:
 
     def _groupby_onepass_ok(self, idx, fields_rows, n_combos: int,
                             depth: int, has_agg: bool,
-                            skey: tuple) -> bool:
+                            skey: tuple, pruned: bool = False) -> bool:
         """Gate + cost model for the one-pass histogram.
         PILOSA_TPU_GROUPBY_ONEPASS=0 disables, =1 forces (still
-        requires disjoint rows — correctness, not cost)."""
+        requires disjoint rows — correctness, not cost).  `pruned`: the
+        walk is over the rows the filter leaves (_groupby_pruned), so
+        the full code space is never formed and has no bound."""
         import os
         flag = os.environ.get("PILOSA_TPU_GROUPBY_ONEPASS", "")
         if flag == "0":
             return False
         bits, _shifts, n_codes = _code_space(fields_rows)
-        if n_codes > _ONEPASS_MAX_CODES:
+        if n_codes > _ONEPASS_MAX_CODES and not pruned:
             return False
         # device paths accumulate the histogram in int32 in-program;
         # the host path sums in int64 and has no shard bound
@@ -3540,14 +3685,16 @@ class StackedEngine:
 
     def _groupby_onepass_path(self, idx, fields_rows, agg_field, skey,
                               combos, depth: int, signed: bool,
-                              filter_call, pre, agg_op: str = "sum"):
+                              filter_call, pre, agg_op: str = "sum",
+                              filt=None):
         """Run the one-pass histogram and gather the requested combos
         out of the dense code space.  Returns the same (counts, agg)
         shape as the per-combo paths — bit-exact partials included.
         ``agg_op`` "min"/"max" additionally pulls the per-group
         magnitude Min/Max table out of the SAME tile walk (fused
         kernel masked reduce / XLA scatter / numpy twin) and returns
-        (counts, (nn, values)) instead of Sum partials."""
+        (counts, (nn, values)) instead of Sum partials.  `filt`: the
+        filter's words where the caller evaluated them already."""
         minmax = agg_op in ("min", "max")
         bits, shifts, n_codes = _code_space(fields_rows)
         digits = _code_digits(fields_rows)
@@ -3555,8 +3702,7 @@ class StackedEngine:
             len(combos), len(fields_rows))
         codes = _combo_codes(shifts, combos_arr)
         has_planes = agg_field is not None
-        filt = None
-        if filter_call is not None:
+        if filt is None and filter_call is not None:
             b0 = PlanBuilder(self, idx, list(skey), pre)
             tree0 = self._build_timed(b0, filter_call)
             if tree0 == ("zeros",):
@@ -3581,7 +3727,7 @@ class StackedEngine:
         mm = None
         kdepth = depth if has_planes else 0
         if host:
-            _count_onepass(("host", None, 0), "onepass")
+            _count_onepass(("host", None, 0), "onepass", kdepth, n_codes)
             out = self._groupby_onepass_host(
                 idx, fields_rows, agg_field, skey, n_codes, depth,
                 signed, filt, minmax=minmax, op_bytes=op_bytes)
@@ -3591,7 +3737,8 @@ class StackedEngine:
         elif multi and not minmax:
             plan = _onepass_plan(n_codes, kdepth, digits, signed)
             arm = plan[0]
-            _count_onepass(plan, "onepass_mesh")
+            _count_onepass(plan, "onepass_mesh", kdepth,
+                           _walked(arm, plan[1], digits, n_codes))
             cg = self.groupcode_stack(idx, fields_rows, skey,
                                       flat=True)
             planes = (self.plane_stack_flat(idx, agg_field, skey)
@@ -3626,7 +3773,8 @@ class StackedEngine:
             plan = _onepass_plan(n_codes, kdepth, digits, signed, minmax,
                                  mesh_minmax=multi and minmax)
             arm = plan[0]
-            _count_onepass(plan, "onepass")
+            _count_onepass(plan, "onepass", kdepth,
+                           _walked(arm, plan[1], digits, n_codes))
             cg = self.groupcode_stack(idx, fields_rows, skey)
             planes = (self.plane_stack(idx, agg_field, skey)
                       if has_planes else None)
@@ -3653,6 +3801,126 @@ class StackedEngine:
             vals, _has = kernels.minmax_from_table(mm, depth, agg_op)
             return sel_counts, (nn[codes], vals[codes])
         return sel_counts, (nn[codes], pos[codes], neg[codes])
+
+    def _selects(self) -> bool:
+        """Whether a pruned GroupBy walks a selection of the cached
+        group-code stack (the packed kernel's `sel`): on one device,
+        where the fused arm serves."""
+        return (self._n_total_devices() == 1
+                and not self._onepass_host(False)
+                and _onepass_arm(1, 0, body="packed") == "fused")
+
+    def _groupby_live_rows(self, cg, filt, digits, host: bool) -> list:
+        """The pruning prepass (stage ``groupby.prune``): per field,
+        the positions in its row list of the rows that the filter
+        leaves a column in, every other field having one too, read off
+        the group-code stack `cg` (numpy where `host`).  A row outside
+        this set is in no combination with a count, so the walk drops
+        it."""
+        if host:
+            cg = np.asarray(cg)
+            valid = cg[:, -1] & np.asarray(filt)[:cg.shape[0]]
+            hit = bm.code_from_planes_np(valid[None]) > 0
+            counts, at = [], 0
+            for bits, rows in digits:
+                if bits:
+                    code = bm.code_from_planes_np(
+                        np.moveaxis(cg[:, at:at + bits], 1, 0))
+                    counts.append(np.bincount(
+                        code[hit], minlength=1 << bits)[:rows])
+                else:
+                    counts.append(np.array([hit.sum()]))
+                at += bits
+            counts = np.concatenate(counts)
+        else:
+            # the Pallas call would replicate a mesh-placed stack
+            arm = ("xla" if self._n_total_devices() > 1
+                   else _onepass_arm(1, 0))
+            fn = _groupby_marginals_jit(arm, digits, True)
+            kind = _dispatch_kind(("marginals", arm, digits), (cg, filt), ())
+            out, _dt = pressure.guarded(
+                lambda: timed_call(kind, fn, cg, filt))
+            counts = np.asarray(out)
+        live, at = [], 0
+        for _bits, rows in digits:
+            live.append(np.flatnonzero(counts[at:at + rows]))
+            at += rows
+        return live
+
+    def _groupby_pruned(self, idx, fields_rows, agg_field, skey,
+                        depth: int, signed: bool, filter_call, pre):
+        """A filtered one-pass GroupBy over the rows the filter leaves
+        a column in (executor.go's groupByIterator skips a prefix
+        that does not meet the filter; here each field's rows are
+        dropped by their count under it, _groupby_live_rows, before
+        any code space is formed).  On the packed kernel the walk
+        selects the surviving rows of the cached group-code stack,
+        each field's count rounded up to a power of two with dead
+        slots (kernels.selection).  Where no selection is walked, a
+        code space past _ONEPASS_MAX_CODES is histogrammed over a
+        group-code stack of the surviving rows, and inside it this
+        returns None: the caller walks the whole code space.  Returns
+        (combos, counts, agg) over the product of the surviving rows
+        in product order, the combos indexing the full row lists."""
+        nf = len(fields_rows)
+        kdepth = depth if agg_field is not None else 0
+        none = np.zeros((0, nf), np.int64)
+        b0 = PlanBuilder(self, idx, list(skey), pre)
+        tree0 = self._build_timed(b0, filter_call)
+        if tree0 == ("zeros",):
+            return (none,) + _zero_groupby_result(0, depth, agg_field)
+        filt = self._run(("words", tree0), b0)
+        digits = _code_digits(fields_rows)
+        host = self._onepass_host(self._n_total_devices() > 1)
+        cg = self.groupcode_stack(idx, fields_rows, skey, as_np=host)
+        with flight.stage("groupby.prune"):
+            live = self._groupby_live_rows(cg, filt, digits, host)
+        if any(rows.size == 0 for rows in live):
+            return (none,) + _zero_groupby_result(0, depth, agg_field)
+        local = product_combos([rows.size for rows in live])
+        combos = np.stack([rows[local[:, i]] for i, rows in enumerate(live)],
+                          axis=1)
+        sel_digits = tuple((bits, _bucket(rows.size))
+                           for (bits, _r), rows in zip(digits, live))
+        n_sel = int(np.prod([b for _x, b in sel_digits], dtype=np.int64))
+        sel = (kernels.selection(sel_digits, kdepth, signed, live)
+               if self._selects() else None)
+        if sel is None:
+            if _code_space(fields_rows)[2] <= _ONEPASS_MAX_CODES:
+                return None
+            pruned = [(f, [rl[i] for i in rows])
+                      for (f, rl), rows in zip(fields_rows, live)]
+            counts, agg = self._groupby_onepass_path(
+                idx, pruned, agg_field, skey, local, depth, signed,
+                filter_call, pre, filt=filt)
+            return combos, counts, agg
+        has_planes = agg_field is not None
+        n_pass = kernels._packed_passes(sel_digits, kdepth, signed, True)[3]
+        _count_onepass(("fused", "packed", n_pass), "onepass", kdepth, n_sel)
+        planes = (self.plane_stack(idx, agg_field, skey)
+                  if has_planes else None)
+        fn = _groupby_selected_jit(has_planes, True, signed, sel_digits)
+        sel = jnp.asarray(sel)
+        sig = ("selected", has_planes, signed, sel_digits)
+        args = [a for a in (cg, filt, planes, sel) if a is not None]
+        kind = _dispatch_kind(sig, args, ())
+        # a pruned walk runs beside a stack cache that may be at its
+        # budget: an OOM sheds and retries once (memory/pressure.py)
+        out, dt = pressure.guarded(
+            lambda: timed_call(kind, fn, cg, filt, planes, sel))
+        if kind == "execute":
+            roofline.note("groupby", kernels.groupby_onepass_hbm_bytes(
+                len(skey), idx.width // 32, sum(b for b, _r in digits),
+                kdepth, True), dt)
+        counts, nn, pos, neg = _onepass_unpack(out, n_sel, depth,
+                                               has_planes)
+        shifts = np.cumsum([0] + [b for b, _r in
+                                  kernels.selected_digits(sel_digits)])
+        codes = np.zeros(len(local), np.int64)
+        for i in range(nf):
+            codes |= local[:, i] << shifts[i]
+        agg = (nn[codes], pos[codes], neg[codes]) if has_planes else None
+        return combos, counts[codes], agg
 
     def _groupby_onepass_host(self, idx, fields_rows, agg_field, skey,
                               n_codes: int, depth: int, signed: bool,
@@ -3855,25 +4123,29 @@ class StackedEngine:
         return counts, (nn, pos, neg)
 
     def groupby(self, idx, fields_rows, filter_call, agg_field,
-                shards: list[int], pre, combos,
+                shards: list[int], pre, combos=None,
                 combo_chunk: int = 8, agg_op: str = "sum"):
         """GroupBy on the stacked engine: the given combos (index
-        tuples into each field's row list — the caller enumerates and
-        pages them) evaluated as chunked device programs over gathered
-        (R, S, W) row stacks (executor.go:3918 + 8617 groupByIterator,
-        re-expressed as fixed-shape gathers + one scan over the BSI
-        planes for the Sum aggregate).
+        tuples into each field's row list — the caller pages them; None
+        for every combination in product order) evaluated as chunked
+        device programs over gathered (R, S, W) row stacks
+        (executor.go:3918 + 8617 groupByIterator, re-expressed as
+        fixed-shape gathers + one scan over the BSI planes for the Sum
+        aggregate).
 
-        fields_rows: [(field, row_ids), ...].  Returns (counts (C,)
-        int64, None | (nn (C,), pos (C, P), neg (C, P)) int64 arrays)
-        aligned with `combos`.  ``agg_op`` "min"/"max" (per-group BSI
-        Min/Max — served ONLY by the one-pass tile walk, whose Min/Max
-        table falls out of the same single pass) returns
-        (counts, (nn (C,), values (C,))) instead;
-        shapes the one-pass gate refuses raise Unstackable so the
-        caller's host loop keeps full generality."""
+        fields_rows: [(field, row_ids), ...].  Returns (combos, counts
+        (C,) int64, None | (nn (C,), pos (C, P), neg (C, P)) int64
+        arrays), `combos` the given ones or, where none were given, the
+        combinations answered: all of them, or those of the rows a
+        filter leaves (_groupby_pruned) — a combination left out has
+        no column.  ``agg_op`` "min"/"max" (per-group BSI Min/Max —
+        served ONLY by the one-pass tile walk, whose Min/Max table
+        falls out of the same single pass) gives (counts, (nn (C,),
+        values (C,))) instead; shapes the one-pass gate refuses raise
+        Unstackable so the caller's host loop keeps full generality."""
         skey = tuple(shards)
-        n_combos = len(combos)
+        n_combos = (len(combos) if combos is not None else int(np.prod(
+            [len(rl) for _f, rl in fields_rows], dtype=np.int64)))
         depth = agg_field.bit_depth if agg_field is not None else 0
         # when no fragment holds any sign-plane bit (row_ids is cached
         # per fragment version, so this is a dict sweep, not a scan),
@@ -3887,6 +4159,33 @@ class StackedEngine:
                                 list(skey))
             signed = any(fr is not None and 1 in fr.row_ids
                          for fr in frags)
+        if (combos is None and filter_call is not None and agg_op == "sum"
+                and n_combos and _prunes(fields_rows, depth, self._selects())
+                and self._groupby_onepass_ok(
+                    idx, fields_rows, n_combos, depth,
+                    agg_field is not None, skey, pruned=True)):
+            t_arm = time.perf_counter()
+            out = self._groupby_pruned(idx, fields_rows, agg_field, skey,
+                                       depth, signed, filter_call, pre)
+            if out is not None:
+                stats.note_gate(
+                    "groupby_onepass",
+                    self._groupby_unit_model(idx, fields_rows, len(out[0]),
+                                             depth, agg_field is not None,
+                                             skey)[0],
+                    time.perf_counter() - t_arm)
+                return out
+        if combos is None:
+            combos = product_combos([len(rl) for _f, rl in fields_rows])
+        return (combos,) + self._groupby_combos(
+            idx, fields_rows, filter_call, agg_field, skey, pre, combos,
+            depth, signed, combo_chunk, agg_op)
+
+    def _groupby_combos(self, idx, fields_rows, filter_call, agg_field,
+                        skey, pre, combos, depth: int, signed: bool,
+                        combo_chunk: int, agg_op: str):
+        """groupby's (counts, agg) for the given combos."""
+        n_combos = len(combos)
         # Min/Max aggregates only exist on the one-pass fused walk
         # (the per-combo kernels and XLA scan have no Min/Max table);
         # anything the gate refuses goes back to the caller's loop

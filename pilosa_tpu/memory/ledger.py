@@ -69,9 +69,9 @@ class Client:
         return self._bytes
 
     def reserve(self, nbytes: int, trigger: str = "reserve",
-                device: int | None = None) -> bool:
+                device: int | None = None, spare: bool = False) -> bool:
         return self._ledger.reserve(self, nbytes, trigger=trigger,
-                                    device=device)
+                                    device=device, spare=spare)
 
     def release(self, nbytes: int, device: int | None = None):
         self._ledger.release(self, nbytes, device=device)
@@ -102,6 +102,12 @@ class Ledger:
         # 1 = no per-device split, every device check degenerates to
         # the global one
         self._n_devices = 1
+        # what programs have needed beyond the resident bytes, by the
+        # device's own numbers: the largest reservation it has made,
+        # and the most that its peak passed what was in use (spare
+        # reservations leave room for both)
+        self._programs_max = 0
+        self._transient_max = 0
 
     # -- registration ---------------------------------------------------
 
@@ -226,12 +232,20 @@ class Ledger:
 
     def reserve(self, client: Client, nbytes: int,
                 trigger: str = "reserve",
-                device: int | None = None) -> bool:
+                device: int | None = None, spare: bool = False) -> bool:
         """Account ``nbytes`` to ``client`` iff they fit the budget,
         reclaiming cold bytes across clients first.  False = denied —
         the caller must not retain the allocation.  ``device`` labels
         the bytes with their mesh slot and additionally enforces that
-        slot's per-device share."""
+        slot's per-device share.
+
+        ``spare``: the bytes are already on the device and are a copy
+        the client can remake, kept only on memory nothing else needs.
+        Such a reservation reclaims from ``client`` alone (its callback
+        knows what it holds that is as expendable), and it needs the
+        device, by its allocator's own numbers, to keep room for what
+        the budget does not see: the headroom, and what its programs
+        have needed beyond the resident bytes (``_device_short``)."""
         nbytes = int(nbytes)
         if nbytes <= 0:
             return True
@@ -245,9 +259,10 @@ class Ledger:
             metrics.MEM_DENIED.inc(client=client.name)
             return False
         for attempt in range(_RECLAIM_ATTEMPTS):
+            short = self._device_short(device) if spare else 0
             with self._lock:
                 total = sum(c._bytes for c in self._live_locked())
-                need = max(total + nbytes - budget, 0)
+                need = max(total + nbytes - budget, short)
                 if need == 0 and dev_budget is not None:
                     dtot = self._dev_total_locked(device)
                     need = max(dtot + nbytes - dev_budget, 0)
@@ -259,11 +274,47 @@ class Ledger:
                     self._export_locked()
                     return True
             freed = self._reclaim(need, requester=client,
-                                  trigger=trigger)
+                                  trigger=trigger, own_only=spare)
             if freed <= 0:
                 break
         metrics.MEM_DENIED.inc(client=client.name)
         return False
+
+    def _device_stats(self, device: int | None) -> dict:
+        """The allocator's numbers of mesh slot `device` (the first
+        device where None); {} on a backend that reports none."""
+        import jax
+        devs = jax.local_devices()
+        dev = devs[device if device is not None
+                   and 0 <= device < len(devs) else 0]
+        return dev.memory_stats() or {}
+
+    def _device_short(self, device: int | None) -> int:
+        """Bytes the device lacks, by its allocator's numbers, to keep
+        what it holds now and still have room for the ledger's headroom
+        and for what its programs have needed beyond it: the largest
+        program reservation it has reported (``bytes_reserved``: the
+        TPU runtime reserves a program's temporaries when it loads it,
+        and cannot load one while memory is full), and the most its
+        ``peak_bytes_in_use`` has been seen above ``bytes_in_use``
+        (what a program allocates while it runs: its outputs, an
+        assembled operand).  0 on a backend that reports no limit."""
+        stats = self._device_stats(device)
+        limit = int(stats.get("bytes_limit") or 0)
+        if not limit:
+            return 0
+        held = int(stats.get("bytes_reserved") or 0)
+        in_use = int(stats.get("bytes_in_use") or 0)
+        with self._lock:
+            self._programs_max = max(
+                self._programs_max, held,
+                int(stats.get("peak_bytes_reserved") or 0))
+            self._transient_max = max(
+                self._transient_max,
+                int(stats.get("peak_bytes_in_use") or 0) - in_use)
+            room = (int(limit * self.headroom_frac) + self._programs_max
+                    - held + self._transient_max)
+        return max(in_use + held + room - limit, 0)
 
     def release(self, client: Client, nbytes: int,
                 device: int | None = None):
@@ -283,16 +334,17 @@ class Ledger:
     # -- reclaim --------------------------------------------------------
 
     def _reclaim(self, need: int, requester: Client | None,
-                 trigger: str) -> int:
+                 trigger: str, own_only: bool = False) -> int:
         """Ask clients to shed ``need`` bytes: coldest clients first,
         the requester LAST — pressure in one cache evicts cold bytes
-        in another before eating its own.  Callbacks run without the
-        ledger lock (they call release() as they evict)."""
+        in another before eating its own (``own_only``: the requester
+        alone).  Callbacks run without the ledger lock (they call
+        release() as they evict)."""
         metrics.MEM_RECLAIMS.inc(trigger=trigger)
         with self._lock:
             others = [c for c in self._live_locked()
                       if c is not requester and c._reclaim_cb is not None
-                      and c._bytes > 0]
+                      and c._bytes > 0 and not own_only]
             me = (requester if requester is not None
                   and requester._reclaim_cb is not None else None)
         others.sort(key=lambda c: c.cold_ts())

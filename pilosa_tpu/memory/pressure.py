@@ -62,8 +62,10 @@ def _take_injection() -> bool:
 def is_oom(e: BaseException) -> bool:
     if isinstance(e, InjectedOOM):
         return True
+    # a TPU runtime that cannot load a program for want of HBM raises
+    # ValueError("RESOURCE_EXHAUSTED: Error loading program ...")
     if type(e).__name__ != "XlaRuntimeError" and not isinstance(
-            e, (RuntimeError, MemoryError)):
+            e, (RuntimeError, MemoryError, ValueError)):
         return False
     msg = str(e)
     return any(m in msg for m in _OOM_MARKERS)

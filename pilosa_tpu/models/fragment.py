@@ -822,6 +822,19 @@ class Fragment:
                     int(arr.size))
         return None
 
+    def row_positions(self, rows) -> np.ndarray | None:
+        """Each column's position in `rows` (int32, -1 where its row is
+        not listed or it has none) where the codes hold the fragment,
+        one lookup a column; None where they do not."""
+        counts, codes = self._code_counts, self._codes
+        if counts is None or codes is None:
+            return None
+        lut = np.full(np.iinfo(codes.dtype).max + 1, -1, np.int32)
+        for i, r in enumerate(rows):
+            if 0 <= r < counts.size:
+                lut[r] = i
+        return lut[codes]
+
     def row_count(self, row: int) -> int:
         counts = self._code_counts
         if counts is not None:

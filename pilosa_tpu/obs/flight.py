@@ -108,7 +108,8 @@ class Acc:
 
     __slots__ = ("phases", "stack", "bytes_moved", "keys", "attempts",
                  "t0", "node_spans", "ops", "pages", "expands", "spans",
-                 "cur", "depth", "root_s", "assembled_bytes", "compiles")
+                 "cur", "depth", "root_s", "assembled_bytes", "compiles",
+                 "walked")
 
     # per-record stack-key cap: a pathological query touching hundreds
     # of stacks must not bloat the ring
@@ -174,6 +175,9 @@ class Acc:
         self.root_s = 0.0
         # [fun_name, ms] per JAX compile this request's thread paid for
         self.compiles: list[list] = []
+        # groups the one-pass GroupBy dispatches serving this query
+        # accumulated (stacked._count_onepass)
+        self.walked = 0
 
     def open_span(self, name: str, t_start: float):
         """Begin a stage: returns (span index or -1 past the cap, the
@@ -285,6 +289,9 @@ class Acc:
         if len(self.compiles) < self._MAX_COMPILES:
             self.compiles.append([fun_name, round(secs * 1e3, 4)])
 
+    def add_walked(self, n: int):
+        self.walked += int(n)
+
     def add_gc(self, dt: float):
         """A collection's pause: a sum in `phases`, no span (one
         request can trigger dozens; the span cap keeps the stages)."""
@@ -337,6 +344,7 @@ class Acc:
         for k, v in other.expands.items():
             self.expands[k] = self.expands.get(k, 0) + v
         self.assembled_bytes += other.assembled_bytes
+        self.walked += other.walked
         room = self._MAX_COMPILES - len(self.compiles)
         if room > 0 and other.compiles:
             self.compiles.extend(other.compiles[:room])
@@ -411,6 +419,10 @@ class _Fan:
     def add_gc(self, dt):
         for a in self.accs:
             a.add_gc(dt)
+
+    def add_walked(self, n):
+        for a in self.accs:
+            a.add_walked(n)
 
 
 _annotation = None
@@ -639,6 +651,23 @@ def note_expand(outcome: str):
     acc = getattr(_tls, "acc", None)
     if acc is not None:
         acc.add_expand(outcome)
+
+
+def note_walked(n: int):
+    """Groups a one-pass GroupBy dispatch accumulated, into the active
+    record."""
+    acc = getattr(_tls, "acc", None)
+    if acc is not None:
+        acc.add_walked(n)
+
+
+def note_reply_groups(n: int):
+    """The groups of a GroupBy reply, into the record this request
+    committed (the encode runs after the commit)."""
+    env = getattr(_tls, "env", None)
+    rec = env.rec if env is not None else None
+    if rec is not None:
+        rec["reply_groups"] = rec.get("reply_groups", 0) + int(n)
 
 
 def note_op(op: str, nbytes: int, dt: float):
@@ -948,6 +977,9 @@ def commit(rec: dict | None, duration_s: float, route: str = "solo",
         rec["expand"] = dict(acc.expands)
     if acc.assembled_bytes:
         rec["assembled_bytes"] = acc.assembled_bytes
+    if acc.walked:
+        # beside `reply_groups`, which the reply's encode adds
+        rec["walked_groups"] = acc.walked
     if acc.compiles:
         # the programs this request waited for a compile of
         rec["compiles"] = list(acc.compiles)

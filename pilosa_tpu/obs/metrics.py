@@ -392,6 +392,14 @@ GROUPBY_PASSES = registry.counter(
 GROUPBY_REPLY_GROUPS = registry.counter(
     "pilosa_groupby_reply_groups_total",
     "Groups returned in GroupBy replies")
+GROUPBY_WALKED = registry.counter(
+    "pilosa_groupby_walked_groups_total",
+    "Groups the one-pass GroupBy dispatches accumulated, the dead "
+    "padding of a pruned walk included")
+GROUPBY_PAYLOAD = registry.counter(
+    "pilosa_groupby_payload_total",
+    "One-pass GroupBy dispatches by arm and by the depth of the summed "
+    "field (le16: none or up to 16 bits / gt16)")
 
 # -- tile-stack maintenance (executor/stacked.py TileStackCache) --
 # Outcomes: hit (fresh entry), miss (any non-hit), patch (stale entry

@@ -404,6 +404,8 @@ _VREG_WORDS = 8 * _LANES      # one (8, 128) vreg of packed words
 _PACKED_VMEM_LIMIT = 32 << 20
 _PACKED_VMEM_BYTES = 29 << 20
 _PACKED_BLOCK_VREGS = 16      # vregs of each plane per grid step, at most
+_PACKED_WALK_MIN_VREGS = 8    # ... and at least, where a selection walks
+                              # in slices (_packed_passes)
 # upper masks an inner turn takes: that many independent
 # AND -> popcount -> add chains overlap, their partials in registers
 # (Q4 alone: 8 a turn 0.0297 s, 4 a turn 0.0321, 16 a turn 0.0308)
@@ -463,7 +465,7 @@ def _packed_block_vregs(digits, fi: int, rp: int, depth: int,
 
 
 @functools.lru_cache(maxsize=256)
-def _packed_passes(digits, depth: int, signed: bool):
+def _packed_passes(digits, depth: int, signed: bool, selected: bool = False):
     """How the packed body walks the live groups of `digits`:
     (block vregs, last field, rows a walk, walks).  The last field is
     the widest; one walk takes all its rows where the accumulators
@@ -473,7 +475,11 @@ def _packed_passes(digits, depth: int, signed: bool):
     width: every walk forms the upper masks again, and narrow blocks
     under many walks lose to the scatter (three fields of 60 rows,
     count-only, 263 shards: 60 walks of 1-vreg blocks 8.92 s, the XLA
-    scatter 1.91; chip, PR 40)."""
+    scatter 1.91; chip, PR 40).  A walk over a `selected` set of rows
+    (selection) keeps the block at the width one row allows down to
+    _PACKED_WALK_MIN_VREGS: the deep Sums of a pruned GroupBy (26
+    payload rows) fit no row at 16, and their scatter would first
+    build a group-code stack of the rows kept."""
     fi = _last_field(digits)
     rows = digits[fi][1]
 
@@ -483,13 +489,15 @@ def _packed_passes(digits, depth: int, signed: bool):
     nv = block(rows)
     if nv:
         return nv, fi, rows, 1
-    if block(1) < _PACKED_BLOCK_VREGS:
+    nv = block(1)
+    least = _PACKED_WALK_MIN_VREGS if selected else _PACKED_BLOCK_VREGS
+    if not nv or nv < min(least, _PACKED_BLOCK_VREGS):
         return 0, fi, 0, 1
     rp = 1
-    while block(rp + 1) == _PACKED_BLOCK_VREGS:
+    while block(rp + 1) == nv:
         rp += 1
     n_pass = -(-rows // rp)
-    return _PACKED_BLOCK_VREGS, fi, -(-rows // n_pass), n_pass
+    return nv, fi, -(-rows // n_pass), n_pass
 
 
 def _pass_codes(digits, fi: int, rp: int, n_pass: int) -> np.ndarray:
@@ -538,6 +546,31 @@ def fused_body(digits, depth: int, signed: bool = True,
     return fused_plan(digits, depth, signed, minmax)[0]
 
 
+def selected_digits(digits) -> tuple:
+    """The code layout of a selection's groups, `digits` being ((bits
+    of the operand's digit planes, rows selected), ...) with a power
+    of two of rows a field: the selected rows' positions are the
+    digits, so the codes are dense over their product."""
+    return tuple(((rows - 1).bit_length(), rows) for _bits, rows in digits)
+
+
+def selection(digits, depth: int, signed: bool, live) -> np.ndarray | None:
+    """groupby_fused's `sel` for the rows `live` (per field, the
+    digits selected, at most that field's rows of `digits`): one
+    segment a field, -1 in the slots past its selection and past the
+    last walk's rows.  None where the packed body cannot walk the
+    selection (one row of the widest field does not fit)."""
+    digits = tuple(digits)
+    nv, _fi, rp, n_pass = _packed_passes(digits, depth, signed, True)
+    if not nv:
+        return None
+    n = max(max(rows for _, rows in digits), n_pass * rp)
+    sel = np.full((len(digits), n), -1, np.int32)
+    for i, rows in enumerate(live):
+        sel[i, :len(rows)] = rows
+    return sel.ravel()
+
+
 def _inner_groups(n_upper: int, k: int) -> tuple:
     """(upper masks an inner iteration, whole iterations, masks left
     over): at most _PACKED_INNER x k partials stay in registers, and
@@ -548,10 +581,16 @@ def _inner_groups(n_upper: int, k: int) -> tuple:
 
 
 def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
-                      fi: int, rp: int, n_pass: int):
+                      fi: int, rp: int, n_pass: int, sel_len: int = 0):
     """Packed body factory (see the block comment above): a walk takes
     rows [walk * rp, (walk + 1) * rp) of field `fi`; with more walks
-    than one the grid leads with the walk axis."""
+    than one the grid leads with the walk axis.
+
+    `sel_len` > 0: the rows are a selection (groupby_fused's `sel`, the
+    first operand, in SMEM): row r of field i is the digit
+    ``sel[i * sel_len + r]``, or no column where that is negative, so
+    each row's literal is formed from a scalar read at run time, not
+    from its static index."""
     cb = sum(bits for bits, _ in digits)
     # lax primitives, not jnp's jitted wrappers (each a nested pjit to
     # trace and lower), and `step` vregs of words to an operation:
@@ -566,8 +605,27 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
     n_upper = _n_upper(digits, fi)
     inner, whole, left = _inner_groups(n_upper, k)
 
-    def kernel(cp_ref, va_ref, *refs):
+    def kernel(*refs):
+        sel_ref = None
+        if sel_len:
+            sel_ref, refs = refs[0], refs[1:]
+        cp_ref, va_ref, refs = refs[0], refs[1], refs[2:]
         pl_ref = refs[0] if depth else None
+
+        def digit_lit(rs, start, bits, row):
+            """A selection's literal of digit `row` (a scalar read from
+            `sel`) over the planes [start, start + bits): each plane
+            kept where the row's bit is set, flipped where not; no
+            column where the row is negative (a dead slot)."""
+            ones = jnp.uint32(0xFFFFFFFF)
+            lit = None
+            for b in range(bits):
+                x = dense_ref[pl.ds(start + b, 1), rs, :]
+                flip = jnp.where((row >> b) & 1 == 1, jnp.uint32(0), ones)
+                t = jax.lax.bitwise_xor(x, jnp.full_like(x, flip))
+                lit = t if lit is None else _and(lit, t)
+            alive = jnp.where(row >= 0, ones, jnp.uint32(0))
+            return _and(lit, jnp.full_like(lit, alive))
         (out_ref, acc_ref, masks_ref, lit_ref,
          dense_ref) = refs[1 if depth else 0:]
         s, wi = pl.program_id(lead), pl.program_id(lead + 1)
@@ -598,10 +656,17 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
             for i, (bits, rows) in enumerate(digits):
                 if i == fi or not bits:
                     continue
+                level = []
+                if sel_len:
+                    for r in range(rows):
+                        lit = digit_lit(rs, starts[i], bits,
+                                        sel_ref[i * sel_len + r])
+                        level.append(_and(masks, lit))
+                    masks = jax.lax.concatenate(level, 0)
+                    continue
                 one = [dense_ref[pl.ds(starts[i] + b, 1), rs, :]
                        for b in range(bits)]
                 zero = [_not(x) for x in one]
-                level = []
                 for r in range(rows):
                     lit = None
                     for b in range(bits):
@@ -658,7 +723,12 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
                     acc_ref[slot + base + u, r] += hist[u][r]
 
         def row(j, carry):
-            if last_bits:
+            if last_bits and sel_len:
+                digit = sel_ref[fi * sel_len + first + j]
+                for rs in chunks:
+                    lit_ref[rs, :] = digit_lit(rs, starts[fi], last_bits,
+                                               digit)[0]
+            elif last_bits:
                 # bit b of the row keeps plane b or flips it
                 flips = [jnp.where(((first + j) >> b) & 1 == 1,
                                    jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
@@ -694,12 +764,19 @@ def _gb_packed_kernel(digits, depth: int, signed: bool, k: int, nv: int,
 
 
 def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
-                     signed: bool, n_codes: int):
-    """groupby_fused's packed body: returns the dense (K, G) table."""
+                     signed: bool, n_codes: int, sel=None):
+    """groupby_fused's packed body: returns the dense (K, G) table —
+    with `sel`, over the selection's code space (selected_digits)."""
     s_dim, _cb, w_dim = code_planes.shape
     k = _payload_rows(depth, signed)
-    nv, fi, rp, n_pass = _packed_passes(digits, depth, signed)
-    codes = _pass_codes(digits, fi, rp, n_pass)
+    nv, fi, rp, n_pass = _packed_passes(digits, depth, signed,
+                                        sel is not None)
+    sel_len = 0
+    if sel is None:
+        codes = _pass_codes(digits, fi, rp, n_pass)
+    else:
+        sel_len = sel.shape[0] // len(digits)
+        codes = _pass_codes(selected_digits(digits), fi, rp, n_pass)
     nv = min(nv, -(-w_dim // _VREG_WORDS))
     bw = nv * _VREG_WORDS
     # zero padding is neutral: valid is ANDed into every mask
@@ -711,24 +788,31 @@ def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
     n_slots = codes.shape[1]
     table = (n_slots, k, 8, _LANES)
     grid = (s_dim, arrays[0].shape[2] // bw)
-    at = lambda s, w: (s, 0, w)
+    at = lambda s, w, *_sel: (s, 0, w)
     if n_pass > 1:
         grid = (n_pass,) + grid
-        at = lambda p, s, w: (s, 0, w)
+        at = lambda p, s, w, *_sel: (s, 0, w)
+    in_specs = [pl.BlockSpec((1, x.shape[1], bw), at) for x in arrays]
+    out_specs = pl.BlockSpec(memory_space=pl.ANY)
+    scratch = [
+        pltpu.VMEM(table, jnp.int32),
+        pltpu.VMEM((_n_upper(digits, fi), 8 * nv, _LANES), jnp.uint32),
+        pltpu.VMEM((8 * nv, _LANES), jnp.uint32),
+        pltpu.VMEM((sum(x.shape[1] for x in arrays), 8 * nv, _LANES),
+                   jnp.uint32)]
+    if sel is None:
+        spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
+                    scratch_shapes=scratch)
+    else:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch))
+        arrays = [jnp.asarray(sel, jnp.int32)] + arrays
     out = pl.pallas_call(
-        _gb_packed_kernel(digits, depth, signed, k, nv, fi, rp, n_pass),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, x.shape[1], bw), at) for x in arrays],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        _gb_packed_kernel(digits, depth, signed, k, nv, fi, rp, n_pass,
+                          sel_len),
         out_shape=jax.ShapeDtypeStruct(
             table if n_pass == 1 else (n_pass,) + table, jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM(table, jnp.int32),
-            pltpu.VMEM((_n_upper(digits, fi), 8 * nv, _LANES),
-                       jnp.uint32),
-            pltpu.VMEM((8 * nv, _LANES), jnp.uint32),
-            pltpu.VMEM((sum(x.shape[1] for x in arrays), 8 * nv,
-                        _LANES), jnp.uint32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_PACKED_VMEM_LIMIT),
         # one factory, two schedules, a name each on the device plane:
@@ -736,6 +820,7 @@ def _gb_fused_packed(code_planes, valid, planes, digits, depth: int,
         # operands, as it did before there were passes
         name="groupby_fused_sum" if n_pass == 1 else "groupby_fused_passes",
         interpret=_interpret(),
+        **spec,
     )(*arrays)
     sums = jnp.sum(out, axis=(-2, -1)).reshape(-1, k)
     live = np.flatnonzero(codes.ravel() >= 0)
@@ -878,7 +963,7 @@ def _gb_fused_onehot(code_planes, valid, planes, depth: int,
 
 def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
                   signed: bool = True, minmax: bool = False,
-                  digits=None):
+                  digits=None, sel=None):
     """Fused single-pass GroupBy histogram on packed words.
 
     Same contract as :func:`groupby_codes_xla` (bit-exact against it
@@ -896,6 +981,13 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
     anyway when `valid` is the AND of the field unions.  Without it
     every code of the CB planes is live (bsi_value_hist:
     dense_digits).
+
+    ``sel`` (:func:`selection`) walks chosen rows only: ``digits``
+    then gives each field's bits and the rows selected, a power of
+    two, and the table is over the selection's codes
+    (:func:`selected_digits`; ``n_codes`` their product), a row's
+    position in its field's selection being its digit.  The packed
+    body alone walks a selection.
 
     Schedule: grid (S, W/BW) with NO combo axis — every code plane,
     valid word, and BSI plane word streams through VMEM exactly once
@@ -916,9 +1008,10 @@ def groupby_fused(code_planes, valid, planes=None, n_codes: int = 1,
     assert sum(b for b, _ in digits) == cb, (digits, cb)
     if cb == 0:                        # all fields single-row: code 0
         code_planes = jnp.zeros((s_dim, 1, w_dim), dtype=jnp.uint32)
-    if fused_body(digits, depth, signed, minmax) == "packed":
+    if sel is not None or fused_body(digits, depth, signed,
+                                     minmax) == "packed":
         hist, mm = _gb_fused_packed(code_planes, valid, planes, digits,
-                                    depth, signed, n_codes), None
+                                    depth, signed, n_codes, sel), None
     else:
         hist, mm = _gb_fused_onehot(code_planes, valid, planes, depth,
                                     signed, minmax, n_codes)

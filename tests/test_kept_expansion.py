@@ -249,6 +249,104 @@ def test_a_denied_block_still_serves_and_is_not_kept(monkeypatch):
     assert _outcomes() == (k0, e0 + 4)
 
 
+def test_keeping_a_block_never_sheds_another_clients_bytes():
+    """Another client's cold bytes (a jit cache's executables: shed
+    one, and its program is compiled and loaded again) are never given
+    up for a kept block; with the budget full of them, the block serves
+    its access alone."""
+    cache, led = _cache(budget=1 << 20)
+    host = _host("packed", seed=70)
+    _fetch(cache, "e", host)
+    asked = []
+    other = led.register("programs", cold_ts=lambda: 0.0,
+                         reclaim=lambda need: asked.append(need) or 0)
+    assert other.reserve(led.budget() - led.total_bytes - PAGE_BYTES // 2)
+    assert np.array_equal(_dense(_fetch(cache, "e", host)), host)
+    assert asked == []
+    assert _kept_bytes(_stack(cache, "e")) == 0
+    assert not _stack(cache, "e").missing()
+
+
+def _device(monkeypatch, led, cache, limit: int, base: int):
+    """The device's allocator numbers: `base` bytes besides what the
+    cache holds; a test sets `reserved`, `peak_reserved` and `peak` (of
+    the bytes in use) in the dict."""
+    stats = {"base": base, "reserved": 0, "peak_reserved": 0, "peak": 0}
+
+    def read(_device):
+        return {"bytes_limit": limit,
+                "bytes_in_use": stats["base"] + cache.nbytes,
+                "peak_bytes_in_use": stats["peak"],
+                "bytes_reserved": stats["reserved"],
+                "peak_bytes_reserved": stats["peak_reserved"]}
+    monkeypatch.setattr(led, "_device_stats", read)
+    return stats
+
+
+def test_kept_blocks_leave_the_device_room_for_its_programs(monkeypatch):
+    """By the allocator's own numbers, a block is kept only while the
+    device keeps the ledger's headroom and room for the largest program
+    reservation it has reported, held now or not; short of that, this
+    cache's older kept blocks go first, and never a page."""
+    cache, led = _cache()
+    limit = 100 * PAGE_BYTES                   # headroom: 10 pages
+    dev = _device(monkeypatch, led, cache, limit, base=0)
+    a, b = _host("packed", seed=71), _host("packed", seed=72)
+    _fetch(cache, "a", a)
+    _fetch(cache, "b", b)
+    pages = cache.nbytes
+
+    def kept():
+        return tuple(_kept_bytes(_stack(cache, k)) // PAGE_BYTES
+                     for k in ("a", "b"))
+    # 80 pages in use: room for a's two blocks
+    dev["base"] = 80 * PAGE_BYTES - pages
+    assert np.array_equal(_dense(_fetch(cache, "a", a)), a)
+    assert kept() == (2, 0)
+    # a program reserved 8 pages, since released: 18 pages of room,
+    # so b's second block takes one of a's
+    dev["peak_reserved"] = 8 * PAGE_BYTES
+    assert np.array_equal(_dense(_fetch(cache, "b", b)), b)
+    assert kept() == (1, 2)
+    assert led._programs_max == 8 * PAGE_BYTES
+    # the device reports it no longer: the room is remembered, so a's
+    # block takes one of b's (with 10 pages of room it would be kept
+    # beside them)
+    dev["peak_reserved"] = 0
+    assert np.array_equal(_dense(_fetch(cache, "a", a)), a)
+    assert kept() == (2, 1)
+    # no room at all: every kept block goes, the block serves alone,
+    # and every page stays
+    dev["base"] += 10 * PAGE_BYTES
+    assert np.array_equal(_dense(_fetch(cache, "a", a)), a)
+    assert np.array_equal(_dense(_fetch(cache, "b", b)), b)
+    assert kept() == (0, 0)
+    assert cache.nbytes == pages == led.total_bytes
+    assert not _stack(cache, "a").missing()
+    assert not _stack(cache, "b").missing()
+
+
+def test_kept_blocks_leave_room_for_what_programs_allocate(monkeypatch):
+    """What a program allocates while it runs (its outputs, an assembled
+    operand) comes on top of the bytes in use: the most the device's
+    peak has been seen above them is left free as well, and
+    remembered."""
+    cache, led = _cache()
+    limit = 100 * PAGE_BYTES                   # headroom: 10 pages
+    dev = _device(monkeypatch, led, cache, limit, base=0)
+    a = _host("packed", seed=73)
+    _fetch(cache, "a", a)
+    dev["base"] = 80 * PAGE_BYTES - cache.nbytes
+    dev["peak"] = 91 * PAGE_BYTES              # 11 pages past the 80
+    assert np.array_equal(_dense(_fetch(cache, "a", a)), a)
+    assert _kept_bytes(_stack(cache, "a")) == 0
+    assert led._transient_max == 11 * PAGE_BYTES
+    dev["peak"] = 0
+    dev["base"] -= 2 * PAGE_BYTES              # 78 + 21 of room: two fit
+    assert np.array_equal(_dense(_fetch(cache, "a", a)), a)
+    assert _kept_bytes(_stack(cache, "a")) == 2 * PAGE_BYTES
+
+
 def test_only_a_page_still_in_its_slot_keeps_a_block_once():
     """A view taken before its entry left the cache gets the right
     block and keeps nothing; a block offered twice is kept once."""

@@ -490,6 +490,25 @@ def test_is_oom_matches_xla_shapes():
     assert pressure.is_oom(MemoryError("Out of memory"))
     assert not pressure.is_oom(RuntimeError("INVALID_ARGUMENT: nope"))
     assert not pressure.is_oom(ValueError("unrelated"))
+    # how a v5e's runtime refuses to load a program for want of HBM
+    assert pressure.is_oom(ValueError(
+        "RESOURCE_EXHAUSTED: Error loading program 'jit_plan_ragged_extras'"
+        ": Attempting to reserve 2.45G at the bottom of memory. That was "
+        "not possible. There are 2.29G free, 0B reserved, and 2.29G "
+        "reservable."))
+
+
+def test_a_program_refused_for_want_of_hbm_is_retried_after_relief():
+    calls = []
+
+    def run():
+        calls.append(1)
+        if len(calls) == 1:
+            raise ValueError("RESOURCE_EXHAUSTED: Error loading program "
+                             "'jit_plan_ragged_extras': Attempting to "
+                             "reserve 2.45G at the bottom of memory.")
+        return "ok"
+    assert pressure.guarded(run) == "ok" and len(calls) == 2
 
 
 # ---------------------------------------------------------------------------

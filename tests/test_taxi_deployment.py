@@ -197,6 +197,31 @@ def test_a_groupby_past_4096_codes_takes_the_packed_kernel(taxi, path):
         "passes": 1, "groups": len(want)}
 
 
+@pytest.mark.parametrize("path", ["solo", "served"])
+def test_q4_walks_its_4800_groups_with_no_prepass(taxi, path):
+    """Q4 counts 4,800 groups of one payload row: under
+    stacked._PRUNE_MIN_UNITS, so no prepass drops rows first — it
+    walks its fields' whole product, as before pruning existed, and
+    the record names no ``groupby.prune`` stage."""
+    from pilosa_tpu.obs import flight
+    apis, columns, _hot, _params = taxi
+    q = QUERIES["q4_dist"].replace("{F}", FILTERS["month_time"])
+    assert not stacked._prunes([(None, range(n)) for n in (10, 8, 60)], 0,
+                               True)
+    walked = metrics.GROUPBY_WALKED.total()
+    payload = metrics.GROUPBY_PAYLOAD.value(arm="fused", depth="le16")
+    got = apis[path].query("taxi", q)["results"][0]
+    assert _canonical("GroupBy", got) == reference_taxi.answer(
+        columns, pql.parse(q))
+    assert metrics.GROUPBY_WALKED.total() - walked == 4800
+    assert metrics.GROUPBY_PAYLOAD.value(arm="fused", depth="le16") \
+        == payload + 1
+    rec = flight.recorder.recent(1)
+    if path == "served" and rec:
+        assert rec[0]["walked_groups"] == 4800
+        assert "groupby.prune" not in {s[0] for s in rec[0]["spans"]}
+
+
 def test_a_small_groupby_is_one_pass_and_a_topn_counts_no_groups(taxi):
     apis, _columns, _hot, _params = taxi
     passes = metrics.GROUPBY_PASSES.total()
@@ -340,7 +365,8 @@ ARMS = [
     ("tpu", 8192, 0, None, False, False, "xla"),       # a value histogram
     ("tpu", 8192, 9, Q4_DIGITS, False, True, "xla"),   # Min/Max: one-hot
     ("tpu", 1 << 18, 0, ((6, 60), (6, 60), (6, 60)), False, False, "xla"),
-    ("tpu", 8192, 17, Q4_DIGITS, False, False, "xla"),
+    # past 16 bits the packed body serves (the one-hot keeps its bound)
+    ("tpu", 8192, 17, Q4_DIGITS, False, False, "fused"),
     ("cpu", 8192, 0, Q4_DIGITS, False, False, "xla"),
     ("tpu", 64, 7, ((3, 6), (1, 2), (3, 5)), False, False, "fused"),
 ]

@@ -194,6 +194,44 @@ def test_packed_body_fits_v5e_vmem(one_chip, name):
         assert asked[0] > V5E_SCOPED_VMEM
 
 
+# SSB's pruned GroupBys: the packed body over a selection of
+# the group-code stack's rows (kernels.selection, in SMEM), summing a
+# 24-bit measure — Q4.3's 2 x 16 x 64 groups in 16 walks, Q3.2's
+# 16 x 16 x 8 in 16 walks of 8-vreg blocks, Q2.1's 8 x 64 — and the
+# prepass that finds the rows (stacked._groupby_marginals_jit: one
+# two-level count-only walk a field)
+SELECTED = {
+    # name: (the selection's digits, the fields' whole digits)
+    "ssb_q4_3": (((3, 2), (8, 16), (10, 64)),
+                 ((3, 7), (8, 250), (10, 1000))),
+    "ssb_q3_2": (((8, 16), (8, 16), (3, 8)), ((8, 250), (8, 250), (3, 7))),
+    "ssb_q2_1": (((3, 8), (10, 64)), ((3, 7), (10, 1000))),
+}
+
+
+@pytest.mark.parametrize("name", list(SELECTED))
+def test_ssb_selection_compiles_for_v5e(one_chip, name):
+    (digits, whole), depth = SELECTED[name], 24
+    cb = sum(b for b, _ in digits)
+    sel = kernels.selection(digits, depth, False, [[0]] * len(digits))
+    fn = stacked._groupby_selected_jit(True, True, False, digits)
+
+    def sds(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = fn.lower(sds((S, cb + 1, W)), sds((S, W)),
+                    sds((S, 2 + depth, W)),
+                    sds(sel.shape, jnp.int32)).compile().as_text()
+    calls = _kernel_calls(text)
+    passes = kernels._packed_passes(digits, depth, False, True)[3]
+    assert len(calls) == 1 and ("groupby_fused_passes" if passes > 1
+                                else "groupby_fused_sum") in calls[0]
+    asked = _scoped_vmem(calls[0])
+    assert asked and 0 < asked[0] < kernels._PACKED_VMEM_LIMIT, asked
+    prepass = stacked._groupby_marginals_jit("fused", whole, True)
+    text = prepass.lower(sds((S, cb + 1, W)), sds((S, W))).compile().as_text()
+    assert len(_kernel_calls(text)) == len(digits)
+
+
 def test_q4_kernel_traces_and_lowers_in_a_second(one_chip):
     """Trace + lowering of taxi-1b's Q4 histogram (4,800 groups, 263
     shards) stays under 2 s here — alone it reads 0.25-0.45 s; the
