@@ -74,9 +74,12 @@ def is_oom(e: BaseException) -> bool:
 def relieve(frac: float = 0.5) -> int:
     """Pressure-relief sweep: shed ``frac`` of the ledger-accounted
     resident bytes across every client, then collect so the freed
-    device buffers actually return to the allocator."""
+    device buffers actually return to the allocator.  The collection
+    walks the whole heap: a cycle that a served thread's full
+    collection froze (obs/flight.py) may hold device buffers."""
     from pilosa_tpu import memory
     need = memory.ledger().reclaim_frac(frac, trigger="oom")
+    gc.unfreeze()
     gc.collect()
     return need
 

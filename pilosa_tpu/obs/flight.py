@@ -42,7 +42,10 @@ runs), and each JAX compile, counted on the thread that paid for it
 (``pilosa_jit_compiles_total``, the record's ``compiles``).  Every
 sample carries the thread's ``path``: ``served`` while it serves a
 request, ``audit`` on a thread that marked itself
-(:func:`mark_thread`), ``background`` otherwise.
+(:func:`mark_thread`), ``background`` otherwise.  The gc hook also
+freezes what a served thread's full collection leaves alive, so the
+next one does not walk the long-lived heap again (:func:`_on_gc`,
+``pilosa_gc_full_total{path,walk}``).
 """
 
 from __future__ import annotations
@@ -52,6 +55,7 @@ import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -1028,7 +1032,7 @@ def commit(rec: dict | None, duration_s: float, route: str = "solo",
 
 _FLUSH_N = 64
 _pending: list = []           # (phase s, labels, trace id)
-_gc_pending: list = []        # (generation, path, pause s)
+_gc_pending: list = []        # (generation, path, pause s, walk)
 _flush_lock = threading.Lock()
 
 
@@ -1052,15 +1056,20 @@ def flush_metrics():
     if phases:
         metrics.PHASE_DURATION.observe_batch(phases)
     pause: dict[tuple, list] = {}
-    for gen, path, dt in gcs:
+    full: dict[tuple, int] = {}
+    for gen, path, dt, walk in gcs:
         acc = pause.get((gen, path))
         if acc is None:
             acc = pause[(gen, path)] = [0.0, 0]
         acc[0] += dt
         acc[1] += 1
+        if walk is not None:
+            full[(path, walk)] = full.get((path, walk), 0) + 1
     for (gen, path), (secs, n) in pause.items():
         metrics.GC_PAUSE_SECONDS.inc(secs, generation=str(gen), path=path)
         metrics.GC_COLLECTIONS.inc(n, generation=str(gen), path=path)
+    for (path, walk), n in full.items():
+        metrics.GC_FULL.inc(n, path=path, walk=walk)
 
 
 def _buffer_phase_samples(acc: Acc, trace_id: str):
@@ -1075,14 +1084,39 @@ def _buffer_phase_samples(acc: Acc, trace_id: str):
 
 _gc_t0 = 0.0        # collections never overlap: one start at a time
 _gc_ann = None
+_gc_walk = None     # "tenured" / "whole": the full collection under way
+_frozen = False     # survivors of a full collection are frozen
+_whole_blocks = 0   # sys.getallocatedblocks() after the last whole walk
+# the paths whose full collections leave their survivors frozen
+_TENURE_PATHS = frozenset(("served", "audit"))
 
 
 def _on_gc(phase: str, info: dict):
     """gc.callbacks hook: a collection timed on the thread that
     triggered it — into the active record's ``phases["gc"]``, the gc
-    counters, and a ``gc`` annotation while a profiler runs."""
-    global _gc_t0, _gc_ann
+    counters, and a ``gc`` annotation while a profiler runs.
+
+    It also decides what a full (generation-2) collection walks.  One
+    on a thread serving a request (or on the audit plane's) leaves its
+    survivors frozen (``gc.freeze()`` at ``stop``: a splice of lists),
+    so the next walks only what was promoted since — the ``tenured``
+    walk.  Elsewhere —
+    set-up, the load's pool threads, an explicit ``gc.collect()``
+    outside a request — and wherever the heap has doubled since the
+    last ``whole`` walk, ``gc.unfreeze()`` at ``start`` gives the
+    collection the whole heap: frozen cyclic garbage is reclaimed
+    there, and can never hold more than the live heap did.  A full
+    collection with nothing frozen is a whole walk too."""
+    global _gc_t0, _gc_ann, _gc_walk, _frozen, _whole_blocks
     if phase == "start":
+        if info.get("generation") == 2:
+            if (_frozen and _path(_tls) in _TENURE_PATHS
+                    and sys.getallocatedblocks() < 2 * _whole_blocks):
+                _gc_walk = "tenured"
+            else:
+                gc.unfreeze()
+                _frozen = False
+                _gc_walk = "whole"
         ann = _annotation
         if ann is not None and ann.is_enabled():
             _gc_ann = ann("gc")
@@ -1097,7 +1131,15 @@ def _on_gc(phase: str, info: dict):
     acc = tls.acc
     if acc is not None:
         acc.add_gc(dt)
-    _gc_pending.append((info.get("generation", -1), _path(tls), dt))
+    gen, path, walk = info.get("generation", -1), _path(tls), None
+    if gen == 2:
+        walk, _gc_walk = _gc_walk, None
+        if walk == "whole":
+            _whole_blocks = sys.getallocatedblocks()
+        if path in _TENURE_PATHS:
+            gc.freeze()
+            _frozen = True
+    _gc_pending.append((gen, path, dt, walk))
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -669,6 +669,11 @@ GC_PAUSE_SECONDS = registry.counter(
 GC_COLLECTIONS = registry.counter(
     "pilosa_gc_collections_total",
     "Python garbage collections by generation and path")
+GC_FULL = registry.counter(
+    "pilosa_gc_full_total",
+    "Full (generation-2) collections by path and walk (tenured: the "
+    "objects promoted since the last full collection froze the rest; "
+    "whole: the whole heap, unfrozen first)")
 JIT_COMPILES = registry.counter(
     "pilosa_jit_compiles_total",
     "JAX backend compiles by event (compile: every one, a persistent-"
