@@ -1,10 +1,10 @@
 """The system under test, as an operator gets it, and the harness's view of it.
 
 Copied from ``chip_smoke.py`` (which ran on the chip in PR 21): device
-check, compile-cache placing, native build, ``build_server`` with the
-default config, schema over HTTP, bulk load through the fragments'
-own imports, a small HTTP client, one ``/metrics.json`` scrape as an
-object, and the compile counter.
+check, compile-cache placing, native build, ``build_server`` laid out
+over the cell's chips, schema over HTTP, bulk load through the
+fragments' own imports, a small HTTP client, one ``/metrics.json``
+scrape as an object, and the compile counter.
 
 Only this module (and ``run.py``, which calls it) touches JAX and the
 program.  The load generator is a child process that imports neither.
@@ -14,7 +14,11 @@ configuration file names its ``generator`` (``generators/<name>.py``)
 and carries ``params``.  Of ``params`` the harness reads ``index``,
 ``shards``, ``rehearsal_shards`` and the field list
 (``harness/fields.py``: ``name``, ``type``, ``options``, ``rows``);
-everything else there is the generator's.  The generator exports
+everything else there is the generator's.  The layout is the cell's:
+``start`` asks the server for a serving mesh as wide as the cell's
+``chips`` (``cluster_mesh_devices``; one chip, no mesh, the default
+config) and ends the run unless the server then has that width
+(``memory/placement.py`` ``mesh_devices``).  The generator exports
 
 - ``make_shard(params, seed, shard) -> (rows, tables)``: one shard's
   data and its additive part of the reference's tables.  ``rows[field]``
@@ -214,13 +218,23 @@ class Http:
 # start and load
 # ---------------------------------------------------------------------------
 
-def start(config: dict):
-    """The server `pilosa-tpu server` starts, default config, on an
-    ephemeral port of 127.0.0.1; the configuration's schema over HTTP."""
+def start(config: dict, chips: int):
+    """The server `pilosa-tpu server` starts on an ephemeral port of
+    127.0.0.1, with a serving mesh over the cell's `chips` where there
+    are more than one; the configuration's schema over HTTP."""
     from pilosa_tpu import config as cfgmod
     from pilosa_tpu.cli.main import build_server
-    cfg = cfgmod.load(None, overrides={"bind": "127.0.0.1", "port": 0})
-    srv = build_server(cfg).start()
+    from pilosa_tpu.memory import placement
+    cfg = cfgmod.load(None, overrides={
+        "bind": "127.0.0.1", "port": 0,
+        "cluster_mesh_devices": chips if chips > 1 else None})
+    srv = build_server(cfg)
+    width = placement.mesh_devices()
+    if width != chips:
+        srv.close()
+        raise BenchError(f"the cell has {chips} chips and the server a "
+                         f"serving mesh of {width}")
+    srv.start()
     params = config["params"]
     http_ = Http(srv.port, params["index"])
     want = fields.schema_fields(params)

@@ -1,13 +1,14 @@
 """Per-layer metrics read from the device trace of the traced window.
 
 args: {"what": "idle_share"} the share of the traced window in which
-no operation ran on the device; {"what": "mfu"} the necessary bytes
-(harness/bytes_model.py) of the queries that the device served in the
-traced window, over the chip's peak bytes/s, over the traced window's
-seconds.  A query counts by the share of its execute phase that lies
-inside the traced window, so one that straddles an edge is counted on
-both sides in proportion.  Nothing to read (no device operations, no
-device-served query) gives None, never 0.
+no operation ran on the device, averaged over the cell's chips;
+{"what": "mfu"} the necessary bytes (harness/bytes_model.py) of the
+queries that the device served in the traced window, over the peak
+bytes/s of the cell's chips together (``hbm_peak``), over the traced
+window's seconds.  A query counts by the share of its execute phase
+that lies inside the traced window, so one that straddles an edge is
+counted on both sides in proportion.  Nothing to read (no device
+operations, no device-served query) gives None, never 0.
 
 Which query a flight record is: the server stamps a record's ``start``
 with the wall clock when it begins to serve, the child stamps ``send``
@@ -22,6 +23,13 @@ from __future__ import annotations
 import bisect
 
 from harness import bytes_model, pql
+
+
+def hbm_peak(ctx: dict) -> float:
+    """Bytes a second the cell's chips can move together: one chip's
+    peak times the chips.  The bytes a share counts are those of every
+    chip, and its seconds are averaged over the chips."""
+    return ctx["peaks"]["hbm_bytes_per_s"] * ctx["cell"]["chips"]
 
 
 def served_bytes(ctx: dict) -> float:
@@ -57,5 +65,4 @@ def read(ctx: dict, args: dict):
     nbytes = served_bytes(ctx)
     if not nbytes:
         return None
-    return (100.0 * nbytes / ctx["peaks"]["hbm_bytes_per_s"]
-            / trace["window_s"])
+    return 100.0 * nbytes / hbm_peak(ctx) / trace["window_s"]

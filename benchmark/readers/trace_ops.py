@@ -6,8 +6,9 @@ Pallas kernels carry on the device plane's ``XLA Ops`` line (a kernel
 is one ``custom-call`` named for its ``pl.pallas_call(name=...)``).
 The share is the necessary bytes (harness/bytes_model.py) of the
 queries that the device served inside the trace (readers/trace.py
-``served_bytes``), over the chip's peak bytes/s, over the union
-seconds of the matching operations (harness/trace_reduce.py
+``served_bytes``), over the peak bytes/s of the cell's chips together
+(readers/trace.py ``hbm_peak``), over the union seconds of the
+matching operations averaged over the chips (harness/trace_reduce.py
 ``op_seconds``), in %.  It names kernels by the stage's own names, so
 a later kernel under the same names is read the same way.  Nothing
 matching (a program whose kernels have no names, a cell that runs
@@ -27,7 +28,8 @@ def read(ctx: dict, args: dict):
     seconds = trace_reduce.op_seconds(ops, args["ops"])
     if seconds <= 0:
         return None
-    nbytes = server.load_module("readers", "trace").served_bytes(ctx)
+    trace = server.load_module("readers", "trace")
+    nbytes = trace.served_bytes(ctx)
     if not nbytes:
         return None
-    return 100.0 * nbytes / ctx["peaks"]["hbm_bytes_per_s"] / seconds
+    return 100.0 * nbytes / trace.hbm_peak(ctx) / seconds

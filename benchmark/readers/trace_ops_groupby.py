@@ -9,17 +9,18 @@ requests that the device served inside the trace, each counted by the
 share of its execute phase inside the traced window (the rule of
 ``readers/trace.py``'s ``served_bytes``, which counts every request:
 a TopN's or a Sum's bytes are not the GroupBy kernels' to read), over
-the chip's peak bytes/s, over the union seconds of the matching
-operations (harness/trace_reduce.py ``op_seconds``), in %.  Nothing
-matching, no peak for the device, or no GroupBy the device served
-gives None, never 0.
+the peak bytes/s of the cell's chips together (readers/trace.py
+``hbm_peak``), over the union seconds of the matching operations
+averaged over the chips (harness/trace_reduce.py ``op_seconds``), in
+%.  Nothing matching, no peak for the device, or no GroupBy the
+device served gives None, never 0.
 """
 
 from __future__ import annotations
 
 import bisect
 
-from harness import bytes_model, pql, trace_reduce
+from harness import bytes_model, pql, server, trace_reduce
 
 
 def groupby_bytes(ctx: dict) -> float:
@@ -56,4 +57,5 @@ def read(ctx: dict, args: dict):
     nbytes = groupby_bytes(ctx)
     if not nbytes:
         return None
-    return 100.0 * nbytes / ctx["peaks"]["hbm_bytes_per_s"] / seconds
+    return (100.0 * nbytes
+            / server.load_module("readers", "trace").hbm_peak(ctx) / seconds)

@@ -5,7 +5,8 @@
 
 A new process each time.  It starts the server an operator gets
 (``build_server``, default config, serving plane on, existence
-tracked) inside this process, generates and loads the configuration's
+tracked, a serving mesh over the cell's chips where it has more than
+one) inside this process, generates and loads the configuration's
 index from ``--seed``, warms every program shape the cell's traffic
 uses, lets a child process (which imports no JAX) send the seed's
 traffic over HTTP for ``--seconds``, checks every response of the
@@ -19,11 +20,11 @@ entry in BENCHMARK.json.
 
 Without a TPU the run ends with code 2 and no result.
 ``--rehearse-cpu`` forces the CPU at the configuration's tiny
-rehearsal size, runs the kernels in interpret mode and can never
-report ``correct: true``.  ``--control stale-shard`` keeps the last
-shard from the server while the reference counts it (the broken
-guarantee that ``correct`` has to catch); it is never run by the
-driver.
+rehearsal size, with one host device for each chip of the cell, runs
+the kernels in interpret mode and can never report ``correct: true``.
+``--control stale-shard`` keeps the last shard from the server while
+the reference counts it (the broken guarantee that ``correct`` has to
+catch); it is never part of a measured run.
 """
 
 from __future__ import annotations
@@ -163,14 +164,18 @@ def run_cell(args, rehearsal_counts: bool = False) -> dict:
 
 
 def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
+    bench, cell = read_cell(args.workload)
     if args.rehearse_cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
+        # one host device for each chip of the cell, before JAX starts
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={cell['chips']}")
         # what a TPU picks by itself: the kernels, here in interpret mode
         os.environ.setdefault("PILOSA_TPU_GROUPBY_ONEPASS_ARM", "fused")
         os.environ.setdefault("PILOSA_TPU_GROUPBY_KERNEL", "1")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-    bench, cell = read_cell(args.workload)
     from harness import server
     config = server.load_json("configs", f"{cell['config']}.json")
     traffic = server.load_json("traffic", f"{cell['traffic']}.json")
@@ -184,7 +189,7 @@ def _run_cell(args, rehearsal_counts: bool, cleanup) -> dict:
     device = server.device(cell["chips"], args.rehearse_cpu)
     server.build_native()
     comp = server.Compiles()
-    srv, http_ = server.start(config)
+    srv, http_ = server.start(config, cell["chips"])
     gen = None
     trace_dir = None
     try:

@@ -36,7 +36,7 @@ def fingerprint() -> dict:
 
     server.Http.call = call
     try:
-        srv, http_ = server.start(config)
+        srv, http_ = server.start(config, 1)
     finally:
         server.Http.call = real
     try:

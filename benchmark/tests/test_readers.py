@@ -18,7 +18,7 @@ def _ctx(flights, records, traced=(100.0, 105.0)):
         "ok": [True] * len(records),
         "plans": [[{"t": "g", "q": GROUPBY}] * 8, [{"t": "g", "q": GROUPBY}] * 8],
         "config": CONFIG, "shards": SHARDS,
-        "peaks": {"hbm_bytes_per_s": 819e9},
+        "peaks": {"hbm_bytes_per_s": 819e9}, "cell": {"chips": 1},
         "trace": {"ops": {0: [("%k", 0, 1)]}, "busy_s": 4.0, "window_s": 5.0},
     }
 
@@ -54,7 +54,37 @@ def test_a_query_counts_by_the_share_of_its_execute_inside_the_trace():
     value = trace.read(ctx, {"what": "mfu"})
     assert value == pytest.approx(
         100 * ONE * (2 / 3 + 1 + 1 / 2) / 819e9 / 5.0, rel=1e-6)
+    # one chip: the one-chip formula, to the last digit
+    assert value == 100.0 * trace.served_bytes(ctx) / 819e9 / 5.0
     assert trace.read(ctx, {"what": "idle_share"}) == pytest.approx(20.0)
+
+
+def test_four_chips_read_a_quarter_of_the_one_chip_formula():
+    """A cell of four chips: the bytes all four served, over what four
+    chips move in the time, which the trace averages over the chips."""
+    from harness import trace_reduce
+    trace = server.load_module("readers", "trace")
+    records = [_record(0, 0, 100.5, 101.7)]
+    flights = [_flight(100.5005, 1.1995, 0.6)]
+    events = [(f"/device:TPU:{c}", "XLA Ops", "%groupby_fused_sum.1",
+               10 ** 9 * c, 10 ** 9) for c in range(4)]
+    ctx = _ctx(flights, records)
+    ctx["trace"] = trace_reduce.reduce_events(events, 4, 5.0)
+    ctx["cell"] = {"chips": 4}
+    nbytes = trace.served_bytes(ctx)
+    assert nbytes == pytest.approx(ONE)
+    assert ctx["trace"]["busy_s"] == pytest.approx(1.0)
+    assert trace.read(ctx, {"what": "mfu"}) == pytest.approx(
+        100 * nbytes / 819e9 / 5.0 / 4)
+    assert trace.read(ctx, {"what": "idle_share"}) == pytest.approx(80.0)
+    kernels = {"ops": "groupby_fused_sum"}
+    for reader in ("trace_ops", "trace_ops_groupby"):
+        share = server.load_module("readers", reader).read(ctx, kernels)
+        assert share == pytest.approx(100 * nbytes / 819e9 / 1.0 / 4)
+        ctx["cell"] = {"chips": 1}
+        assert server.load_module("readers", reader).read(ctx, kernels) \
+            == pytest.approx(4 * share)
+        ctx["cell"] = {"chips": 4}
 
 
 def test_nothing_served_reads_nothing_never_zero():

@@ -7,7 +7,8 @@
   correct;
 - with the look for a chip skipped (``rehearsal_counts``), a clean run
   is correct and a run whose answers are altered where they are
-  produced (one group's count, one Count) is not.
+  produced (one group's count, one Count, one Sum: the window's first
+  answer and every seventh after it) is not.
 """
 
 import json
@@ -75,20 +76,23 @@ import run
 from harness import server
 
 fault = {fault!r}
-real_start = server.start
+real_start, real_start_run = server.start, run.LoadGen.start_run
+state = {{"n": 0}}
 
-def start(config):
-    srv, http_ = real_start(config)
+def start(config, chips):
+    srv, http_ = real_start(config, chips)
     inner = srv.api.query
-    state = {{"n": 0}}
 
     def query(index, pql, *a, **kw):
         resp = inner(index, pql, *a, **kw)
         state["n"] += 1
-        if fault == "altered" and state["n"] % 7 == 0 and resp.get("results"):
+        if fault == "altered" and state["n"] % 7 == 1 and resp.get("results"):
             first = resp["results"][0]
             if isinstance(first, int):
                 resp = {{**resp, "results": [first + 1]}}
+            elif isinstance(first, dict) and "value" in first:
+                bent = dict(first, value=first["value"] + 1)
+                resp = {{**resp, "results": [bent]}}
             elif isinstance(first, list) and first and "count" in first[0]:
                 bent = [dict(first[0], count=first[0]["count"] + 1)]
                 resp = {{**resp, "results": [bent + list(first[1:])]}}
@@ -96,7 +100,14 @@ def start(config):
     srv.api.query = query
     return srv, http_
 
+def start_run(self, i):
+    # the window's first answer, and every seventh after it, is altered:
+    # a window of a few slow requests holds one too
+    state["n"] = 0
+    real_start_run(self, i)
+
 server.start = start
+run.LoadGen.start_run = start_run
 args = run.parse(["--workload", {cell!r}, "--seed", "2147483778",
                   "--seconds", "3", "--trace", "0", "--rehearse-cpu"])
 result = run.run_cell(args, rehearsal_counts=True)

@@ -86,3 +86,18 @@ def test_zipf_ranking_belongs_to_the_seed_not_the_stream():
     counts = collections.Counter(r["q"] for r in other)
     # s = 1 over 20 values: the first rank draws about 28%
     assert 0.2 < counts.most_common(1)[0][1] / 4000 < 0.36
+
+
+def test_the_points_mix_keeps_its_first_6000_requests_at_400_a_second():
+    """Raising the points mix's limit from 120 to 400 requests a second
+    a client only lengthens the schedule: the 6,000 requests that 120
+    gave a 50 s window are the same, byte for byte, for every seed."""
+    mix = _mix(os.path.join(BENCH, "traffic", "points-zipf-solo.json"))
+    assert mix["max_requests_per_client_per_s"] == 400
+    old = dict(mix, max_requests_per_client_per_s=120)
+    for seed in (1, 2147483659, 2 ** 31 + 12345):
+        was = schedule.build(old, seed, schedule.WINDOW, 50.0)
+        now = schedule.build(mix, seed, schedule.WINDOW, 50.0)
+        assert len(was[0]) == 6000 and len(now[0]) == 20000
+        assert json.dumps(now[0][:6000]).encode() \
+            == json.dumps(was[0]).encode()

@@ -116,7 +116,7 @@ def _trace_ctx(ops):
         "records": records, "ok": [True],
         "plans": [[{"t": "g", "q": GROUPBY}] * 2],
         "config": CONFIG, "shards": SHARDS,
-        "peaks": {"hbm_bytes_per_s": 819e9},
+        "peaks": {"hbm_bytes_per_s": 819e9}, "cell": {"chips": 1},
         "trace": {"ops": ops, "busy_s": 1.0, "window_s": 5.0},
     }
 

@@ -135,6 +135,7 @@ def test_the_groupby_reader_prices_the_groupby_requests_alone(world):
            "records": records, "ok": [True] * 3,
            "plans": [[{"t": "t", "q": q} for q in qs]], "config": config,
            "shards": 287, "peaks": {"hbm_bytes_per_s": 819e9},
+           "cell": {"chips": 1},
            "trace": {"ops": {0: [("%groupby_fused_sum.3", 0, 10 ** 8),
                                  ("%fusion.1", 0, 10 ** 9)]},
                      "busy_s": 1.0, "window_s": 5.0}}

@@ -32,7 +32,7 @@ def world(request):
     if request.param == "missing":
         config["params"]["missing_one_in"] = 16
     skip = frozenset([1]) if request.param == "stale" else frozenset()
-    srv, http_ = server.start(config)
+    srv, http_ = server.start(config, 1)
     try:
         tables = server.load(srv, config, three_forms, SEED, 2, skip)
         yield (request.param, srv, http_, tables,
@@ -119,7 +119,7 @@ def test_start_refuses_a_field_that_came_back_different(monkeypatch):
     monkeypatch.setattr(server.Http, "call", call)
     try:
         with pytest.raises(server.BenchError):
-            server.start(three_forms.CONFIG)
+            server.start(three_forms.CONFIG, 1)
     finally:
         for srv in started:
             srv.close()
